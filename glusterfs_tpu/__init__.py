@@ -7,7 +7,20 @@ coding, self-heal, management plane and client APIs — with all GF(256)
 erasure-coding compute batched onto TPU via JAX/XLA/Pallas.
 """
 
+import os as _os
+
 __version__ = "0.1.0"
+
+
+def pin_cpu() -> None:
+    """Keep this process off the accelerator.  A TPU belongs to one
+    process at a time, and that process is a data door (a gfapi
+    application, ``gftpu-fuse``, ``gftpu-gateway``): every management
+    and service entry point calls this first in ``main()``, before
+    anything can import jax, so a client graph it mounts (``volume
+    heal``, replace-brick, the CLI) codes on the CPU ladder instead of
+    taking the chip from the door that serves with it."""
+    _os.environ["JAX_PLATFORMS"] = "cpu"
 
 # This build's management op-version (xlator.h:758 / GD_OP_VERSION):
 # peers advertise theirs at probe time and the cluster operates at the

@@ -138,7 +138,7 @@ def record(kind: str, /, **fields) -> None:
     """Append one notable record to the ring (cheap, never raises).
     ``kind`` is positional-only so a field literally named "kind"
     (e.g. an alert's rule kind) cannot raise a TypeError; the ring's
-    taxonomy key always wins the collision."""
+    classification key always wins the collision."""
     if not ENABLED:
         return
     try:

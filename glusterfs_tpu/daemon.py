@@ -19,6 +19,7 @@ import os
 import signal
 import sys
 
+from . import pin_cpu
 from .core.graph import Graph
 from .protocol.server import BrickServer
 from .core import gflog
@@ -194,6 +195,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-daemon")
     p.add_argument("--volfile", required=True)
     p.add_argument("--top", default="",

@@ -31,6 +31,7 @@ import struct
 import sys
 import time
 
+from .. import pin_cpu
 from ..core.fops import FopError
 from ..core.iatt import IAType
 from ..core.layer import Layer, Loc
@@ -273,6 +274,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-bitd")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--bricks", required=True,

@@ -32,6 +32,7 @@ import json
 import sys
 from typing import Any
 
+from .. import pin_cpu
 from ..protocol.server import STATUS_KINDS
 from .glusterd import MgmtClient, mount_volume
 
@@ -629,6 +630,7 @@ def _shell(server: str, flags: list[str]) -> int:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu")
     p.add_argument("--server", default="127.0.0.1:24007")
     p.add_argument("--json", action="store_true")

@@ -23,6 +23,7 @@ import sys
 from collections import deque
 from urllib.parse import urlparse
 
+from .. import pin_cpu
 from ..core.fops import FopError
 from ..core import gflog
 from ..core import metrics as _metrics
@@ -251,6 +252,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-eventsd")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--udp-port", type=int, default=24009)

@@ -35,6 +35,7 @@ import os
 import signal
 import sys
 
+from .. import pin_cpu
 from ..core.fops import FopError
 from ..core import gflog
 
@@ -761,6 +762,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-gsyncd")
     p.add_argument("--primary", required=True, help="host:port:volume")
     p.add_argument("--secondary", required=True, help="host:port:volume")

@@ -24,6 +24,7 @@ import json
 import os
 import sys
 
+from .. import pin_cpu
 from ..core import gflog
 from ..rpc import wire
 
@@ -123,6 +124,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-quotad")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--bricks", required=True,

@@ -58,6 +58,7 @@ import signal
 import sys
 import time
 
+from .. import pin_cpu
 from ..core import gflog
 from ..core.events import gf_event
 from ..core.fops import FopError
@@ -690,6 +691,7 @@ async def _amain(args) -> int:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-rebalanced")
     p.add_argument("--glusterd", required=True, help="host:port")
     p.add_argument("--volname", required=True)

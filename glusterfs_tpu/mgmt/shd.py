@@ -30,6 +30,7 @@ import os
 import signal
 import sys
 
+from .. import pin_cpu
 from ..core.fops import FopError
 from ..core.iatt import IAType
 from ..core.layer import Loc
@@ -360,6 +361,7 @@ async def _amain(args) -> None:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-shd")
     p.add_argument("--glusterd", required=True, help="host:port")
     p.add_argument("--volname", required=True)

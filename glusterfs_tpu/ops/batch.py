@@ -29,10 +29,15 @@ coalesced into HBM-resident batches" — is a batching window:
   Until calibration completes, flushes run on the CPU ladder — a served
   volume is never slower than the native path while the device warms up.
   Production flush timings keep updating the models (EMA), so a drifting
-  transfer latency (e.g. a congested tunnel) re-routes automatically.
+  transfer latency re-routes automatically.  A calibration that fails
+  (a kernel the compiler refuses, a lost device) is logged once at
+  ERROR with its reason kept for ``dump_stats()``: ``auto`` then stays
+  on the CPU ladder, an explicitly requested device backend sends the
+  flush to the device anyway so the fop fails instead of being served
+  somewhere the operator did not ask for.
 
 * the **mesh tier** (ISSUE 8, ``cluster.mesh-codec``): when the volume
-  key is on and the wedge-safe device probe saw >1 jax device, flushes
+  key is on and the warm-up saw >1 jax device, flushes
   at/above ``stripe-cache-min-batch`` skip the single-device ladder and
   land in ONE pjit'd ``NamedSharding(Mesh(dp, frag))`` launch
   (parallel/mesh_codec) — many concurrent fops' stripes sharded over
@@ -63,10 +68,13 @@ import time
 
 import numpy as np
 
+from ..core import gflog as _gflog
 from ..core import metrics as _metrics
 from ..core import tracing as _tracing
 from . import gf256
 from .codec import Codec
+
+_log = _gflog.get_logger("ec")
 
 _DEVICE_BACKENDS = ("pallas-xor", "pallas-mxu", "xla", "xla-xor", "mesh")
 
@@ -151,9 +159,11 @@ class BatchingCodec(Codec):
     The sync ``encode``/``decode`` API stays available (heal tooling,
     tests); the data path awaits ``encode_async``/``decode_async``.
 
-    Stats: ``launches`` counts device batch launches, ``cpu_launches``
-    counts flushes routed to the CPU ladder, ``batched_fops`` total fops
-    served, ``max_batch`` the largest coalesced batch in fops.
+    Stats: ``flushes`` counts coalesced batches handed to a path,
+    ``launches`` counts device batch launches (sync calls included),
+    ``cpu_launches`` counts flushes routed to the CPU ladder,
+    ``batched_fops`` total fops served, ``max_batch`` the largest
+    coalesced batch in fops.
 
     ``min_batch`` is a hard floor below which flushes never go to the
     device; ``min_batch=0`` disables routing entirely (every flush takes
@@ -189,6 +199,7 @@ class BatchingCodec(Codec):
         # base init) so _small()'s lazy build is the only
         # cross-context write left — and that one is lock-serialized
         self._cpu = None if self.backend in _DEVICE_BACKENDS else self
+        self.flushes = 0
         self.launches = 0
         self.cpu_launches = 0
         self.batched_fops = 0
@@ -201,10 +212,11 @@ class BatchingCodec(Codec):
         self._dev = _PathModel()
         self._nat = _PathModel()
         self._cal_state = "idle"  # idle -> running -> done/failed
+        self._cal_error = ""      # why, when failed
         # mesh data plane (ISSUE 8, cluster.mesh-codec): when the key is
         # on AND >1 device is visible, flushes at/above min_batch land
-        # in ONE pjit'd NamedSharding(Mesh(dp, frag)) launch.  The
-        # device-count probe can block 45 s on a wedged transport, so it
+        # in ONE pjit'd NamedSharding(Mesh(dp, frag)) launch.  Counting
+        # devices is a backend init (about 10 s on a TPU host), so it
         # warms OFF the event loop; until it answers "ready", flushes
         # take the existing ladder unchanged.  Systematic volumes ride
         # the tier too (ISSUE 12): encodes take the parity-rows-only
@@ -213,16 +225,15 @@ class BatchingCodec(Codec):
         self.mesh_requested = mesh
         self._mesh = None
         self._mesh_state = "off"  # off -> warming -> ready/unavailable
-        self._mesh_stop = False   # close() retires a retrying warm loop
+        self._mesh_error = ""     # why, when the warm-up raised
         self.mesh_launches: dict[tuple[str, str], int] = {}
         self.mesh_stripes: dict[tuple[str, str], int] = {}
         if mesh:
             self._mesh_state = "warming"
-            # a dedicated daemon thread, NOT the flush pool: on a
-            # wedged transport the probe join holds its thread for the
-            # full 45 s deadline, and with calibration on the other
-            # pool worker that would queue production flushes behind
-            # it — exactly the stall the ladder fallback promises away
+            # a dedicated daemon thread, NOT the flush pool: backend
+            # init holds its thread for seconds, and with calibration
+            # on the other pool worker that would queue production
+            # flushes behind it — the stall the ladder promises away
             threading.Thread(target=self._mesh_warm, daemon=True,
                              name=f"gftpu-mesh-warm-{k}+{r}").start()
         _LIVE_BATCHERS.add(self)  # unified-registry scrape target
@@ -269,45 +280,35 @@ class BatchingCodec(Codec):
                     try:
                         self._cpu = Codec(self.k, self.r, "native",
                                           systematic=self.systematic)
-                    except RuntimeError:
+                    except RuntimeError as e:
+                        _log.warning(43, "%s: small flushes take the "
+                                     "NumPy oracle, not native: %s",
+                                     self.name, e)
                         self._cpu = Codec(self.k, self.r, "ref",
                                           systematic=self.systematic)
         return self._cpu
 
     # -- mesh data plane ---------------------------------------------------
 
-    _MESH_WARM_RETRIES = 2
-
     def _mesh_warm(self) -> None:
         """Runs on its own daemon thread (NEVER the flush pool — see
-        the spawn site in __init__): deadline device probe, then build
+        the spawn site in __init__): count the devices, then build
         (cache) the process mesh.  A single device parks the codec on
-        the existing ladder; a RETRYABLE 0 (probe timeout / transient
-        jax error, the window device_count caches for _COUNT_RETRY_S)
-        re-probes up to _MESH_WARM_RETRIES times after the window —
-        without this, a startup plugin-registration race would disable
-        the mesh for the codec's whole lifetime despite the probe's
-        own retry window."""
+        the existing ladder; a warm-up that raises does too, after one
+        ERROR line, with the reason kept for ``dump_stats()``."""
         try:
             from ..parallel import mesh_codec
 
-            for attempt in range(1 + self._MESH_WARM_RETRIES):
-                n = mesh_codec.device_count()
-                if n > 1:
-                    self._mesh = mesh_codec.default_mesh()
-                    self._mesh_state = "ready"
-                    return
-                if not (n == 0 and mesh_codec.device_count_transient()
-                        and attempt < self._MESH_WARM_RETRIES):
-                    break
-                wake = time.monotonic() + mesh_codec._COUNT_RETRY_S + 1.0
-                while time.monotonic() < wake and not self._mesh_stop:
-                    time.sleep(1.0)
-                if self._mesh_stop:  # codec replaced/closed: stand down
-                    break
-            self._mesh_state = "unavailable"
-        except Exception:
-            self._mesh_state = "unavailable"
+            if mesh_codec.device_count() > 1:
+                self._mesh = mesh_codec.default_mesh()
+                self._mesh_state = "ready"
+                return
+        except Exception as e:
+            self._mesh_error = f"{type(e).__name__}: {e}"
+            _log.error(42, "%s: mesh tier unavailable, flushes stay on "
+                       "the %s ladder: %s", self.name, self.backend,
+                       self._mesh_error)
+        self._mesh_state = "unavailable"
 
     async def ensure_mesh(self) -> bool:
         """Await the mesh warm probe (tests/benches/dryrun — daemons
@@ -416,9 +417,14 @@ class BatchingCodec(Codec):
                 self._dev.fit_two_points(*pts_dev[0], *pts_dev[1])
                 self._nat.fit_two_points(*pts_nat[0], *pts_nat[1])
                 self._cal_state = "done"
-        except Exception:  # device unusable -> stay on the CPU ladder
+        except Exception as e:  # device unusable: say so, once
             with self._lock:
                 self._cal_state = "failed"
+                self._cal_error = f"{type(e).__name__}: {e}"
+            _log.error(41, "%s: %s calibration failed, flushes %s: %s",
+                       self.name, self.backend,
+                       "stay on the CPU ladder" if self._auto
+                       else "go to the device and fail", self._cal_error)
 
     def _maybe_start_calibration(self) -> None:
         with self._lock:
@@ -491,6 +497,10 @@ class BatchingCodec(Codec):
             return small, "cpu"
         with self._lock:
             st, dev, nat = self._cal_state, self._dev, self._nat
+            if st == "failed" and not self._auto:
+                # the operator named this device backend: a device that
+                # cannot code fails the fop, it is not served elsewhere
+                return self, "device"
             if st != "done":
                 pass
             elif dev.predict(self._padded(total)) <= nat.predict(total):
@@ -593,6 +603,7 @@ class BatchingCodec(Codec):
         if not batch:
             return
         self._last_flush = time.monotonic()
+        self.flushes += 1
         self.batched_fops += len(batch)
         self.max_batch = max(self.max_batch, len(batch))
         total = sum(d.size for d, *_ in batch)
@@ -692,6 +703,7 @@ class BatchingCodec(Codec):
         if not batch:
             return
         self._last_flush = time.monotonic()
+        self.flushes += 1
         self.batched_fops += len(batch)
         self.max_batch = max(self.max_batch, len(batch))
         total = sum(d.size for d, *_ in batch)
@@ -760,6 +772,7 @@ class BatchingCodec(Codec):
         self._last_flush = time.monotonic()
         loop = asyncio.get_running_loop()
         for rows, batch in queues.items():
+            self.flushes += 1
             self.batched_fops += len(batch)
             self.max_batch = max(self.max_batch, len(batch))
             total = sum(f.size for f, *_ in batch)
@@ -811,7 +824,6 @@ class BatchingCodec(Codec):
         if self._cal_timer is not None:
             self._cal_timer.cancel()
             self._cal_timer = None
-        self._mesh_stop = True  # a retrying warm loop stands down
         self._pool.shutdown(wait=False)
 
     def dump_stats(self) -> dict:
@@ -823,9 +835,10 @@ class BatchingCodec(Codec):
             nat = {"overhead_s": round(self._nat.overhead, 6),
                    "rate_MiB_s": round(self._nat.rate / 2**20, 1),
                    "samples": self._nat.samples} if self._nat.ready else None
-            cal = self._cal_state
+            cal, cal_err = self._cal_state, self._cal_error
         return {
             "backend": self.backend,
+            "flushes": self.flushes,
             "launches": self.launches,
             "cpu_launches": self.cpu_launches,
             "batched_fops": self.batched_fops,
@@ -833,12 +846,14 @@ class BatchingCodec(Codec):
             "window_s": self.window,
             "min_batch_bytes": self.min_batch,
             "calibration": cal,
+            "calibration_error": cal_err,
             "device_model": dev,
             "native_model": nat,
             "break_even_bytes": self.break_even_bytes(),
             "mesh": {
                 "requested": self.mesh_requested,
                 "state": self._mesh_state,
+                "error": self._mesh_error,
                 "launches": {f"{op}:{o}": v for (op, o), v
                              in self.mesh_launches.items()},
                 "stripes": {f"{op}:{o}": v for (op, o), v

@@ -8,8 +8,8 @@ analog of the reference's socket fan-out, ec-common.c:816-900):
   fragment dimension over ``frag``; the encode IS the scatter-to-bricks
   step).
 * :func:`device_count` / :func:`device_count_cached` /
-  :func:`local_device_count` — wedge-safe device discovery (deadline
-  probe; the cached form never blocks and is what serving-path routing
+  :func:`local_device_count` — device discovery (asked once; the
+  cached form never touches jax and is what serving-path routing
   reads).  Under a ``cluster.mesh-distributed`` job (``meshd``) the
   global count spans every member process; ``local_device_count`` is
   this process's share.

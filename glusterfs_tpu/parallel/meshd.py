@@ -19,10 +19,8 @@ Wiring (mgmt/glusterd.py ``_mesh_env``): the brick spawner exports
     GFTPU_MESH_RANK        = <brick index>
 
 and the brick daemon calls :func:`maybe_initialize` at startup.  The
-init runs on a BACKGROUND daemon thread with a hard deadline — the
-wedge-safety rule every jax touchpoint in this tree follows
-(ops/codec.probe_with_deadline): glusterd spawns bricks one at a time
-awaiting each port, so a rank that blocked startup waiting for its
+init runs on a BACKGROUND daemon thread with a hard deadline:
+glusterd spawns bricks one at a time awaiting each port, so a rank that blocked startup waiting for its
 siblings would deadlock the whole volume start.  A rank that cannot
 join within the deadline logs, stays single-process, and serves —
 degraded to the PR-8 one-runtime plane, never wedged.
@@ -172,15 +170,13 @@ def maybe_initialize(coordinator: str = "", num_processes: int = 0,
 def settle_before_backend_init(max_wait_s: float = 75.0) -> None:
     """Block THIS thread until a configured background join reaches a
     terminal state.  ``jax.distributed.initialize`` must run before
-    the process's FIRST jax backend init — but the wedge-safe device
-    probes (mesh_codec.device_count, codec._tpu_present) run on their
-    own abandonable threads and may win that race, initializing a
-    single-process backend and making the join fail forever.  Every
-    backend-touching probe calls this first: a no-op outside a
-    distributed job (and after the join settles), a bounded wait on
-    the probe's OWN thread otherwise — the probe's abandon deadline
-    still caps the caller.  If the join was configured but not yet
-    started (import-order corner), it is started here (idempotent)."""
+    the process's FIRST jax backend init — but the device probes
+    (mesh_codec.device_count, codec.tpu_devices) may win that race,
+    initializing a single-process backend and making the join fail
+    forever.  Both call this first: a no-op outside a distributed job
+    (and after the join settles), a bounded wait on the caller's own
+    thread otherwise.  If the join was configured but not yet started
+    (import-order corner), it is started here (idempotent)."""
     if configured() is None:
         return
     if state()["status"] == "off":

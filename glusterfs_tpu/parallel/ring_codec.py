@@ -52,11 +52,6 @@ def _ring_decode_fn(k: int, rows: tuple[int, ...], mesh: Mesh):
     Output: reconstructed planes (S, k*8, 64) sharded over ``frag`` on
     the STRIPE axis (stripe block j on device j).
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     p = mesh.devices.shape[mesh.axis_names.index("frag")]
     if (k * 8) % p:
         raise ValueError(f"k*8={k * 8} planes must divide over {p} "
@@ -109,13 +104,10 @@ def _ring_decode_fn(k: int, rows: tuple[int, ...], mesh: Mesh):
     # stripes shard over dp as well: each dp row runs its own
     # independent ring over its stripe slice (specs naming only frag
     # would replicate the whole problem dp times)
-    kwargs = dict(mesh=mesh,
-                  in_specs=(P("frag", "dp", None), P(None, "frag")),
-                  out_specs=P(("dp", "frag"), None, None))
-    try:  # jax>=0.8 renamed the replication-check knob
-        fn = shard_map(shard_body, check_vma=False, **kwargs)
-    except TypeError:
-        fn = shard_map(shard_body, check_rep=False, **kwargs)
+    fn = jax.shard_map(
+        shard_body, mesh=mesh,
+        in_specs=(P("frag", "dp", None), P(None, "frag")),
+        out_specs=P(("dp", "frag"), None, None), check_vma=False)
 
     @jax.jit
     def run(planes):

@@ -433,8 +433,13 @@ if not os.environ.get("GFTPU_NO_WIREC"):
             lambda v: FdHandle(v[0], v[1], v[2]),
             lambda v: FopError(v[0], v[1], v[2] if len(v) > 2 else None),
             WireError, blob_stats)
-    except Exception:  # no toolchain: pure-Python codec serves
+    except Exception as _e:  # no toolchain: pure-Python codec serves
         _wirec = None
+        from ..core import gflog as _gflog
+
+        _gflog.get_logger("protocol").warning(
+            40, "wire codec: C extension unavailable, the pure-Python "
+            "codec serves every frame of this process: %s", _e)
 
 
 def _encode_body(payload: Any, blobs: list | None) -> bytes:

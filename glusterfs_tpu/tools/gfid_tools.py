@@ -31,6 +31,7 @@ import asyncio
 import os
 import sys
 
+from .. import pin_cpu
 from ..core.iatt import gfid_new
 from ..storage.posix import META_DIR, split_gfid_record
 
@@ -166,6 +167,7 @@ async def gfind_missing(root: str, server: str, volume: str,
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-gfid-tool")
     sp = p.add_subparsers(dest="cmd", required=True)
 

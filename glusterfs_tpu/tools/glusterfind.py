@@ -34,6 +34,8 @@ import os
 import sys
 import time
 
+from .. import pin_cpu
+
 DEFAULT_SESSION_DIR = os.path.expanduser("~/.gftpu/glusterfind")
 
 # ops -> emitted change class (the reference's NEW/MODIFY/DELETE split)
@@ -389,6 +391,7 @@ async def cmd_delete(args) -> dict:
 
 
 def main(argv=None) -> int:
+    pin_cpu()
     p = argparse.ArgumentParser(prog="gftpu-find")
     p.add_argument("--server", default="127.0.0.1:24007")
     p.add_argument("--session-dir", default=DEFAULT_SESSION_DIR)

@@ -82,7 +82,7 @@ def test_sequential_calls_do_not_starve():
 
 
 class _SlowDeviceCodec(BatchingCodec):
-    """Device launches take a fixed wall time (a slow-tunnel stand-in)."""
+    """Device launches take a fixed wall time (a slow-device stand-in)."""
 
     DELAY = 0.25
 
@@ -136,6 +136,42 @@ def test_flushes_pipeline_do_not_serialize():
     want = gf256.ref_encode(_rand(STRIPE * 4, 1), K, K + R)
     for o in outs:
         assert np.array_equal(o, want)
+
+
+def test_failed_calibration_is_loud_and_never_reroutes_a_named_backend(
+        monkeypatch):
+    """A calibration that raises is logged once at ERROR with its
+    reason kept in dump_stats.  Under ``auto`` flushes stay on the CPU
+    ladder; under an explicitly named device backend they go to the
+    device and the fop fails — served nowhere the operator did not ask
+    for."""
+    from glusterfs_tpu.core import gflog
+    from glusterfs_tpu.ops import gf256_xla
+
+    def refuse(*_a, **_kw):  # a compile the chip rejects
+        raise RuntimeError("Mosaic says no")
+
+    monkeypatch.setattr(gf256_xla, "encode", refuse)
+    codec = BatchingCodec(K, R, "xla", window=0.001, min_batch=1)
+    d = _rand(STRIPE * 2, 9)
+
+    async def run():
+        assert not await codec.ensure_calibrated()
+        with pytest.raises(RuntimeError, match="Mosaic says no"):
+            await codec.encode_async(d)
+        codec._auto = True  # what backend="auto" would have set
+        return await codec.encode_async(d)
+
+    out = asyncio.run(run())
+    assert np.array_equal(out, gf256.ref_encode(d, K, K + R))
+    st = codec.dump_stats()
+    assert st["calibration"] == "failed"
+    assert "Mosaic says no" in st["calibration_error"]
+    assert st["flushes"] == 2 and st["cpu_launches"] == 1
+    logged = [m for m in gflog.recent_messages(1000)
+              if "MSGID: 110041" in m and "Mosaic says no" in m]
+    assert len(logged) == 1 and logged[0].startswith("ERROR")
+    codec.close()
 
 
 def test_measured_break_even_routing():

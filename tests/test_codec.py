@@ -80,6 +80,36 @@ def test_detect_and_validation():
         codec.Codec(17, 2)
 
 
+def test_backend_init_failure_is_loud(monkeypatch):
+    """A backend that cannot initialize (the chip is held by another
+    process) is never read as "no chip": ``auto`` takes the CPU ladder
+    after ONE error line with the probe state ``error``; any backend
+    that runs through jax refuses to resolve."""
+    import jax
+
+    from glusterfs_tpu.core import gflog
+
+    def held():
+        raise RuntimeError("Unable to initialize backend 'tpu': ABORTED")
+
+    monkeypatch.setattr(jax, "devices", held)
+    monkeypatch.setattr(codec, "_probe",
+                        {"state": "unprobed", "devices": (), "error": ""})
+    before = len([m for m in gflog.recent_messages(1000)
+                  if "MSGID: 110040" in m])
+    assert codec.detect("auto") in ("native", "xla")
+    assert codec.detect("auto") in ("native", "xla")  # asked once
+    st = codec.probe_state()
+    assert st["state"] == "error" and "ABORTED" in st["error"]
+    assert ({"state": "error"}, 1) in codec._probe_samples()
+    for backend in ("pallas-xor", "xla", "mesh"):
+        with pytest.raises(RuntimeError, match="ABORTED"):
+            codec.detect(backend)
+    assert codec.detect("ref") == "ref"
+    after = [m for m in gflog.recent_messages(1000) if "MSGID: 110040" in m]
+    assert len(after) == before + 1 and after[-1].startswith("ERROR")
+
+
 def test_native_apply_bitmatrix_parity():
     from glusterfs_tpu import native
 

@@ -302,8 +302,8 @@ OWNERSHIP: dict[str, tuple[str, str]] = {
         "single-writer state machine (off -> warming -> ready/"
         "unavailable) advanced only by the warm thread via GIL-atomic "
         "str assignment; loop reads tolerate staleness BY DESIGN — "
-        "'warming' routes flushes to the measured ladder fallback, "
-        "which is the codec's whole wedge-safety story"),
+        "'warming' routes flushes to the measured ladder, so a "
+        "seconds-long backend init never stalls a fop"),
 }
 
 # --------------------------------------------------------------------------
