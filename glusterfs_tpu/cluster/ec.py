@@ -86,8 +86,6 @@ from ..core import tracing as _tracing
 from ..ops import codec as codec_mod
 from ..rpc import wire
 
-import time as _time
-
 log = gflog.get_logger("ec")
 
 
@@ -344,6 +342,10 @@ class DisperseLayer(Layer):
         # operator can see migration I/O riding the delta plane
         self.delta_origin = {"serve": 0}
         self.delta_saved = {"read": 0, "write": 0}
+        # sink one of this layer's phases (core/tracing.py ``phase``):
+        # count, seconds, slowest of ec.lock, ec.fanout, ... as
+        # dump_private()["phases"] shows them
+        self.phases: dict = {}
         # live-downgrade memory: a parity brick answering EOPNOTSUPP to
         # xorv parks the WHOLE layer on the RMW path (parity rows are
         # fixed brick indices — one refusing brick breaks every delta)
@@ -606,6 +608,10 @@ class DisperseLayer(Layer):
             self.owner = _g()
 
         async def __aenter__(self):
+            with _tracing.phase(self.ec.name, "ec.lock", self.ec.phases):
+                return await self._enter()
+
+        async def _enter(self):
             if self.local:
                 await self.ec._lock(self.gfid).acquire()
                 # Flush any eager window NOW, while holding the local
@@ -631,15 +637,49 @@ class DisperseLayer(Layer):
             return self
 
         async def __aexit__(self, *exc):
-            await self.ec._inodelk_unwind(self.loc, self.locked,
-                                          self.owner, self.start,
-                                          self.end)
+            with _tracing.phase(self.ec.name, "ec.unlock", self.ec.phases):
+                await self.ec._inodelk_unwind(self.loc, self.locked,
+                                              self.owner, self.start,
+                                              self.end)
             if self.local:
                 self.ec._lock(self.gfid).release()
             return False
 
     # -- eager lock window (ec-common.c:2176 ec_lock_reuse + delayed
     # post-op ec-common.c:2377) ---------------------------------------------
+
+    class _LockedWindow:
+        """``async with``: the local gfid lock held and the eager
+        window open or joined (yields its state).  Where there is
+        something to wait for — the local lock is taken, or this is a
+        window's first fop and pays the inodelk and metadata wave —
+        getting there is one ``ec.lock`` span; joining a held window
+        with the lock free costs nothing and has none."""
+
+        __slots__ = ("ec", "loc", "gfid", "lock")
+
+        def __init__(self, ec: "DisperseLayer", loc: Loc, gfid: bytes):
+            self.ec, self.loc, self.gfid = ec, loc, gfid
+            self.lock = ec._lock(gfid)
+
+        async def __aenter__(self) -> _EagerState:
+            ec = self.ec
+            if self.gfid in ec._eager and not self.lock.locked():
+                return await self._begin()
+            with _tracing.phase(ec.name, "ec.lock", ec.phases):
+                return await self._begin()
+
+        async def _begin(self) -> _EagerState:
+            await self.lock.acquire()
+            try:
+                return await self.ec._eager_begin(self.loc, self.gfid)
+            except BaseException:
+                self.lock.release()
+                raise
+
+        async def __aexit__(self, *exc) -> bool:
+            self.lock.release()
+            return False
 
     async def _eager_begin(self, loc: Loc, gfid: bytes) -> _EagerState:
         """Open (or join) the eager window.  Caller holds the local gfid
@@ -687,7 +727,10 @@ class DisperseLayer(Layer):
                 else self.opts["eager-lock-timeout"]
         if timeout <= 0 or \
                 loop.time() - st.opened >= self.opts["eager-lock-max-hold"]:
-            await self._eager_flush(loc, gfid)
+            # the window closes under this fop: its ``ec.unlock`` span
+            # (leaving a window that stays held arms a timer, no span)
+            with _tracing.phase(self.name, "ec.unlock", self.phases):
+                await self._eager_flush(loc, gfid)
             return
         if st.timer is not None:
             st.timer.cancel()
@@ -762,11 +805,12 @@ class DisperseLayer(Layer):
                 lockset = set(st.locked)
                 xd = {"unlock-inodelk": ["ec.transaction", "wr", 0, -1,
                                          st.owner]}
-                res = await self._dispatch(
-                    targets, "xattrop",
-                    lambda i: ((loc, "mixed", dict(post)),
-                               {"xdata": dict(xd)}
-                               if i in lockset else {}))
+                with _tracing.phase(self.name, "ec.xattrop", self.phases):
+                    res = await self._dispatch(
+                        targets, "xattrop",
+                        lambda i: ((loc, "mixed", dict(post)),
+                                   {"xdata": dict(xd)}
+                                   if i in lockset else {}))
                 unlocked = {i for i, r in res.items()
                             if i in lockset
                             and not isinstance(r, BaseException)}
@@ -812,9 +856,13 @@ class DisperseLayer(Layer):
 
     async def _dispatch(self, idxs: list[int], op: str, argfn):
         """Run fop on children idxs concurrently; returns {idx: result or
-        exception}.  argfn(i) -> (args, kwargs) per child."""
-        return await self._dispatch_multi(
-            {i: (op, *argfn(i)) for i in idxs}, order=idxs)
+        exception}.  argfn(i) -> (args, kwargs) per child.  One
+        ``ec.fanout`` span: building every child's arguments (a write's
+        ``tobytes()``) and the gather; the children's fop spans are its
+        children."""
+        with _tracing.phase(self.name, "ec.fanout", self.phases, op=op):
+            return await self._dispatch_multi(
+                {i: (op, *argfn(i)) for i in idxs}, order=idxs)
 
     async def _dispatch_multi(self, wave: dict[int, tuple],
                               order: list[int] | None = None):
@@ -879,8 +927,10 @@ class DisperseLayer(Layer):
                 for i, r in res.items()}
 
     async def _xattrop(self, idxs, loc: Loc, deltas: dict[str, bytes]):
-        return await self._dispatch(
-            idxs, "xattrop", lambda i: ((loc, "add64", dict(deltas)), {}))
+        with _tracing.phase(self.name, "ec.xattrop", self.phases):
+            return await self._dispatch(
+                idxs, "xattrop",
+                lambda i: ((loc, "add64", dict(deltas)), {}))
 
     # -- size helpers ------------------------------------------------------
 
@@ -1292,23 +1342,24 @@ class DisperseLayer(Layer):
                                 if isinstance(r, BaseException))
                 continue
             rows_sorted = sorted(good)
-            bufs = [wire.as_single_buffer(good[i]) for i in rows_sorted]
-            # healthy systematic fan-out: the fragment buffers (wire
-            # blob-lane memoryviews) land DIRECTLY in the codec's
-            # reassembly — no per-fragment staging copy (ISSUE 3; the
-            # reference's ec_readv answer iobrefs feed dispatch the
-            # same way)
-            fast = self.codec.reassemble(bufs, rows_sorted, f_len)
-            if fast is not None:
-                self.read_fanout["fast"] += 1
-                return fast
-            self.read_fanout["staged"] += 1
-            frags = np.zeros((self.k, f_len), dtype=np.uint8)
-            for j, buf in enumerate(bufs):
-                arr = np.frombuffer(buf, dtype=np.uint8)
-                frags[j, : arr.size] = arr
-            data = await self._codec_decode(frags, rows_sorted)
-            return data
+            with _tracing.phase(self.name, "ec.reassemble", self.phases):
+                bufs = [wire.as_single_buffer(good[i])
+                        for i in rows_sorted]
+                # healthy systematic fan-out: the fragment buffers
+                # (wire blob-lane memoryviews) land DIRECTLY in the
+                # codec's reassembly — no per-fragment staging copy
+                # (ISSUE 3; the reference's ec_readv answer iobrefs
+                # feed dispatch the same way)
+                fast = self.codec.reassemble(bufs, rows_sorted, f_len)
+                if fast is not None:
+                    self.read_fanout["fast"] += 1
+                    return fast
+                self.read_fanout["staged"] += 1
+                frags = np.zeros((self.k, f_len), dtype=np.uint8)
+                for j, buf in enumerate(bufs):
+                    arr = np.frombuffer(buf, dtype=np.uint8)
+                    frags[j, : arr.size] = arr
+            return await self._codec_decode(frags, rows_sorted)
         raise last_err or FopError(errno.EIO, "read failed")
 
     async def _readv_window(self, fd: FdObj, size: int, offset: int,
@@ -1340,8 +1391,7 @@ class DisperseLayer(Layer):
             # ops serialize on the local gfid lock (the reference
             # chains same-inode fops on the lock owner too).
             while True:
-                async with self._lock(fd.gfid):
-                    st = await self._eager_begin(loc, fd.gfid)
+                async with self._LockedWindow(self, loc, fd.gfid) as st:
                     # a parallel write mid-dispatch over our range could
                     # hand us a torn stripe (half old, half new
                     # fragments) — wait it out like a conflicting write
@@ -1355,7 +1405,8 @@ class DisperseLayer(Layer):
                                 fd, size, offset, st.candidates, st.size)
                         finally:
                             await self._eager_end(loc, fd.gfid)
-                await blocker
+                with _tracing.phase(self.name, "ec.lock", self.phases):
+                    await blocker
         async with self._Txn(self, loc, fd.gfid, "rd",
                              fetch=True) as txn:
             candidates, true_size = await self._txn_meta(txn)
@@ -1588,11 +1639,7 @@ class DisperseLayer(Layer):
             if hi - lo != sum(uhi - ulo for _f, ulo, uhi in ps):
                 raise _DeltaFallback()  # non-contiguous (cannot happen)
             intervals[j] = (lo, hi)
-        span = _tracing.enter(self.name, "delta-write") \
-            if _tracing.ENABLED else None
-        t0 = _time.perf_counter()
-        failed = True
-        try:
+        with _tracing.phase(self.name, "delta-write", self.phases):
             # old bytes: one ranged readv per touched data fragment —
             # internal write reads, never subject to the read mask
             res = await self._dispatch(
@@ -1647,7 +1694,9 @@ class DisperseLayer(Layer):
             unsupported: set[int] = set()
             res = {}
             try:
-                res = await self._dispatch_multi(wave)
+                with _tracing.phase(self.name, "ec.fanout", self.phases,
+                                    op="delta"):
+                    res = await self._dispatch_multi(wave)
                 unsupported = {i for i, r in res.items()
                                if isinstance(r, FopError)
                                and r.err == errno.EOPNOTSUPP
@@ -1711,12 +1760,7 @@ class DisperseLayer(Layer):
             ia = Iatt(**{**ia.__dict__})
             st.size = max(st.size, end)
             ia.size = st.size
-            failed = False
             return ia
-        finally:
-            if span is not None:
-                _tracing.exit_span(span, _time.perf_counter() - t0,
-                                   failed)
 
     async def _writev_in_window(self, fd: FdObj, loc: Loc, st: _EagerState,
                                 data: bytes, offset: int,
@@ -1739,8 +1783,9 @@ class DisperseLayer(Layer):
             have_end = min(a_end, self._frag_len(true_size) * self.k)
             if have_end > a_off:
                 self.write_path["rmw"] += 1
-                old = await self._read_aligned(
-                    fd, a_off, have_end - a_off, list(st.candidates))
+                with _tracing.phase(self.name, "ec.rmw_read", self.phases):
+                    old = await self._read_aligned(
+                        fd, a_off, have_end - a_off, list(st.candidates))
                 buf[: old.size] = old
                 # trim stale bytes beyond true size (padding zeros)
                 if true_size - a_off < old.size:
@@ -1775,8 +1820,7 @@ class DisperseLayer(Layer):
         bookkeeping, not the RMW/encode/write wave itself."""
         loc = Loc(fd.path, gfid=fd.gfid)
         if not self.opts["parallel-writes"]:
-            async with self._lock(fd.gfid):
-                st = await self._eager_begin(loc, fd.gfid)
+            async with self._LockedWindow(self, loc, fd.gfid) as st:
                 # waves registered before a live parallel-writes->off
                 # reconfigure may still be dispatching: settle them
                 await self._quiesce_writes(st)
@@ -1789,8 +1833,7 @@ class DisperseLayer(Layer):
         a_off = offset // self.stripe * self.stripe
         a_end = (end + self.stripe - 1) // self.stripe * self.stripe
         while True:
-            async with self._lock(fd.gfid):
-                st = await self._eager_begin(loc, fd.gfid)
+            async with self._LockedWindow(self, loc, fd.gfid) as st:
                 if not st.pre_landed.is_set():
                     # the window's first write runs solo under the lock:
                     # it carries the compound pre-op, and dirty+1 must
@@ -1804,7 +1847,9 @@ class DisperseLayer(Layer):
                 if blocker is None:
                     token = st.add_range(a_off, a_end)
                     break
-            await blocker  # overlapping write in flight: wait, retry
+            # overlapping write in flight: wait, retry
+            with _tracing.phase(self.name, "ec.lock", self.phases):
+                await blocker
         try:
             return await self._writev_in_window(fd, loc, st, data, offset)
         finally:
@@ -2166,26 +2211,33 @@ class DisperseLayer(Layer):
             return {"healed": healed, "skipped": False,
                     "size": rep2["size"], "stable": stable}
 
+    # each codec call is one ``ec.codec_wait`` span: the whole await of
+    # the batcher as the fop saw it; its queue, flush and resume spans
+    # (ops/batch.py) are that span's children
+
     async def _codec_encode(self, buf, origin: str | None = None):
-        if self._batching:
-            return await self.codec.encode_async(
-                buf, origin=origin or self.traffic_origin)
-        return self.codec.encode(buf)
+        with _tracing.phase(self.name, "ec.codec_wait", self.phases):
+            if self._batching:
+                return await self.codec.encode_async(
+                    buf, origin=origin or self.traffic_origin)
+            return self.codec.encode(buf)
 
     async def _codec_delta(self, buf, origin: str | None = None):
         """Parity-rows-only delta encode through the batching window
         (coalesced delta flushes ride the same measured ladder)."""
-        if self._batching:
-            return await self.codec.encode_delta_async(
-                buf, origin=origin or self.traffic_origin)
-        return self.codec.encode_delta(buf)
+        with _tracing.phase(self.name, "ec.codec_wait", self.phases):
+            if self._batching:
+                return await self.codec.encode_delta_async(
+                    buf, origin=origin or self.traffic_origin)
+            return self.codec.encode_delta(buf)
 
     async def _codec_decode(self, frags, rows,
                             origin: str | None = None):
-        if self._batching:
-            return await self.codec.decode_async(
-                frags, rows, origin=origin or self.traffic_origin)
-        return self.codec.decode(frags, rows)
+        with _tracing.phase(self.name, "ec.codec_wait", self.phases):
+            if self._batching:
+                return await self.codec.decode_async(
+                    frags, rows, origin=origin or self.traffic_origin)
+            return self.codec.decode(frags, rows)
 
     async def fini(self):
         for gfid in list(self._eager):
@@ -2208,5 +2260,8 @@ class DisperseLayer(Layer):
             "delta_saved": dict(self.delta_saved),
             "xorv_ok": self._xorv_ok,
             "eager_windows": len(self._eager),
+            # per phase of a transaction: count, seconds, slowest (the
+            # eager-lock wait, the xattrops; core/tracing.py sink one)
+            "phases": _tracing.phase_sums(self.phases),
             "stripe_cache": self.codec.dump_stats(),
         }

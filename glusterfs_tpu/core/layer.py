@@ -100,9 +100,14 @@ class FdObj:
 
 
 class _FopStats:
-    __slots__ = ("count", "errors", "latency_sum", "latency_max", "hist")
+    __slots__ = ("count", "errors", "latency_sum", "latency_max", "hist",
+                 "annot")
 
-    def __init__(self):
+    def __init__(self, annot: str | None = None):
+        # the span's name in the profiler's trace, built once per
+        # (layer, fop): by layer TYPE, so that it is the same in every
+        # volume (core/tracing.py, sink three)
+        self.annot = annot
         self.count = 0
         self.errors = 0
         self.latency_sum = 0.0
@@ -130,12 +135,15 @@ def _timed(op_name: str, fn: Callable) -> Callable:
     """Wrap a fop coroutine with per-layer count/latency accounting."""
 
     async def wrapper(self, *args, **kwargs):
-        st = self.stats.setdefault(op_name, _FopStats())
+        st = self.stats.get(op_name)
+        if st is None:
+            st = self.stats[op_name] = _FopStats(
+                f"gftpu:{self.type_name}.{op_name}")
         # span bracket: the outermost timed call on a graph mints the
         # trace id, nested layers join it (core/tracing.py); one gate
         # check keeps the dark path at a single global read
-        span = tracing.enter(self.name, op_name) if tracing.ENABLED \
-            else None
+        span = tracing.enter(self.name, op_name, st.annot) \
+            if tracing.ENABLED else None
         err = False
         t0 = time.perf_counter()
         try:

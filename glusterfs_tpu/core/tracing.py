@@ -7,7 +7,8 @@ client readv cannot say whether the time went to the client graph, the
 transport, the brick graph or the disk.  Here every OUTERMOST fop call
 on a graph mints a 16-hex-char trace id; each timed layer method
 (``core.layer._timed``) records a span ``(trace, depth, layer, op,
-start, duration, err)`` into a bounded per-process ring; and
+start, duration, err, span_id, parent_id, start_ns)`` into a bounded
+per-process ring; and
 protocol/client ships the id as a trailing wire-frame field that
 protocol/server re-arms before dispatching into the brick graph — so
 the brick's spans carry the CLIENT's trace id and the two statedumps
@@ -23,13 +24,36 @@ metrics-off bench runs / ``GFTPU_NO_OBSERVABILITY``).
 
 A root span exceeding ``SLOW_FOP_THRESHOLD`` logs the full span tree —
 a slow wire readv finally says WHERE the time went.
+
+Below the fop boundary the same primitive times a PHASE: ``with
+phase(layer, "ec.lock"):`` around a piece of one fop's work, on the
+loop or in a pool thread.  Every span (fop or phase) has a small
+integer id and its parent's id, so siblings under one ``gather`` are
+told from nested calls and self time can be computed, and a start on
+``time.perf_counter_ns()``.  Three sinks:
+
+* always, per phase: count, seconds, maximum, in a dict that the
+  owning codec or layer hands in and shows in its dump
+  (:func:`phase_sums`);
+* while ``ENABLED``: the ring, so a slow fop's tree shows its phases
+  and the codec flush it waited for;
+* while ``ANNOTATE`` is set (ops/batch sets it to
+  ``jax.profiler.TraceAnnotation`` in the process that owns the chip;
+  this package imports no jax): one annotation per span, named
+  ``gftpu:<layer type>.<fop>`` or ``gftpu:<phase>``, with ``trace``,
+  ``span`` and ``parent`` as metadata.  It is inert until somebody
+  starts ``jax.profiler``; then the program's spans sit on the host
+  planes of the trace that also holds the device ops (whose planes
+  have a clock of their own: docs/observability.md).
 """
 
 from __future__ import annotations
 
 import collections
 import contextvars
+import itertools
 import os
+import threading
 import time
 
 from . import gflog
@@ -46,22 +70,46 @@ DARK = os.environ.get("GFTPU_NO_OBSERVABILITY", "") == "1"
 
 #: master gate: False skips ALL span work in the fop hot path (set by
 #: bench metrics-off passes and the GFTPU_NO_OBSERVABILITY env, which
-#: brick subprocesses inherit so a whole served volume can run dark)
+#: brick subprocesses inherit so a whole served volume can run dark);
+#: a phase then still feeds its sums and nothing else
 ENABLED = not DARK
 
 #: root spans slower than this (seconds) log their full tree; 0 = off
 #: (diagnostics.slow-fop-threshold)
 SLOW_FOP_THRESHOLD = 0.0
 
+#: sink three: a context-manager class called as ``ANNOTATE(name,
+#: **metadata)`` once per span while ``ANNOTATE.is_enabled()``.
+#: ``None`` here; ops/batch sets ``jax.profiler.TraceAnnotation`` when
+#: a codec is built on a jax backend.  Such an annotation records its
+#: own start and end (it is closed by whichever thread ends the span,
+#: and interleaved tasks need no nesting); while no profiler session
+#: runs, ``is_enabled()`` is one C call and no annotation is made
+ANNOTATE = None
+
 _RING_DEFAULT = 4096
 
 #: the bounded per-process span ring (circ-buff.c event-history analog);
-#: span = (trace_id, depth, layer, op, start_ts, duration_s, err)
+#: span = (trace_id, depth, layer, op, start_ts, duration_s, err,
+#: span_id, parent_id, start_ns): ``start_ts`` is wall clock, derived
+#: from ``start_ns`` (``time.perf_counter_ns()``) by one offset taken
+#: at import; ``parent_id`` 0 = no parent in this process
 SPANS: collections.deque = collections.deque(maxlen=_RING_DEFAULT)
 
-#: (trace_id, depth) of the span currently open in this context
+#: (trace_id, depth, span_id, layer) of the span currently open in
+#: this context
 CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "gftpu_trace", default=None)
+
+#: span ids: small integers, one sequence per process.  ``next`` on a
+#: count is a single C call, so the loop and the codec's pool threads
+#: draw from it without a lock
+_IDS = itertools.count(1)
+
+#: per thread: the ``(layer name, sums)`` it works for (:func:`adopt`)
+_THREAD = threading.local()
+
+_WALL_NS = time.time_ns() - time.perf_counter_ns()
 
 #: per-(layer, op) slow-fop counts — the {layer,op} labels say WHICH
 #: door and verb keeps blowing the threshold, not just that one did
@@ -97,28 +145,55 @@ def arm(trace_id: str) -> None:
     """Adopt a wire-carried trace id for the rest of this context (the
     protocol/server re-arm: brick-graph spans join the client's trace
     instead of minting their own)."""
-    CURRENT.set((str(trace_id), 0))
+    CURRENT.set((str(trace_id), 0, 0, ""))
 
 
-def enter(layer_name: str, op: str):
-    """Open a span: mint a trace at the outermost call, else nest.
-    Returns the token tuple ``exit_span`` needs."""
-    cur = CURRENT.get()
+def enter(layer_name: str, op: str, annot: str | None = None,
+          parent: tuple | None = None, push: bool = True,
+          fop: bool = True, meta: dict | None = None):
+    """Open a span: mint a trace at the outermost call, else nest
+    under ``parent`` (a ``CURRENT`` tuple handed over from another
+    thread) or under this context's open span.  ``push`` False leaves
+    ``CURRENT`` alone: the span is then ended elsewhere, and nothing
+    nests under it by context.  Only a ``fop`` span can be a root
+    whose slowness is logged; it brings its annotation's name
+    (``annot``), a phase is named by :func:`_phase_annot`.  Returns
+    the token tuple ``exit_span`` needs."""
+    cur = parent if parent is not None else CURRENT.get()
     if cur is None:
-        tid, depth, root = new_trace_id(), 0, True
+        tid, depth, pid, root = new_trace_id(), 0, 0, fop
     else:
-        tid, depth, root = cur[0], cur[1] + 1, False
-    tok = CURRENT.set((tid, depth))
-    return (tid, depth, root, tok, layer_name, op, time.time())
+        tid, depth, pid, root = cur[0], cur[1] + 1, cur[2], False
+    sid = next(_IDS)
+    tok = CURRENT.set((tid, depth, sid, layer_name)) if push else None
+    ann = None
+    if ANNOTATE is not None and ANNOTATE.is_enabled():
+        ann = ANNOTATE(annot or _phase_annot(layer_name, op), trace=tid,
+                       span=sid, parent=pid, **(meta or {}))
+        ann.__enter__()
+    return (tid, depth, root, tok, layer_name, op,
+            time.perf_counter_ns(), sid, pid, ann)
+
+
+def _phase_annot(layer_name: str, name: str) -> str:
+    """A dotted name is a phase of the table in docs/observability.md
+    and stands alone; the two older labels (``mesh-codec`` + op, layer
+    + ``delta-write``) keep theirs."""
+    return "gftpu:" + name if "." in name \
+        else f"gftpu:{layer_name}.{name}"
 
 
 def exit_span(span, duration: float, err: bool) -> None:
-    tid, depth, root, tok, layer_name, op, start = span
-    try:
-        CURRENT.reset(tok)
-    except ValueError:
-        pass  # context migrated (sync facade thread hop): root-only
-    SPANS.append((tid, depth, layer_name, op, start, duration, err))
+    tid, depth, root, tok, layer_name, op, start_ns, sid, pid, ann = span
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    if tok is not None:
+        try:
+            CURRENT.reset(tok)
+        except ValueError:
+            pass  # context migrated (sync facade thread hop): root-only
+    SPANS.append((tid, depth, layer_name, op, (start_ns + _WALL_NS) * 1e-9,
+                  duration, err, sid, pid, start_ns))
     if not root:
         return
     if SLOW_FOP_THRESHOLD and duration >= SLOW_FOP_THRESHOLD:
@@ -138,6 +213,99 @@ def exit_span(span, duration: float, err: bool) -> None:
                          tree=render_tree(tid))
 
 
+class phase:
+    """A piece of one fop's work below the fop boundary, as a span.
+
+    ``with phase(layer, "ec.fanout", sums):`` on the loop (across
+    ``await`` in one task) or in a pool thread.  ``layer`` labels the
+    ring entry (the owning layer's instance name).  ``sums`` is sink
+    one, a dict the owner keeps on itself: ``(phase, thread id) ->
+    [count, seconds, max seconds]``.  The loop and a codec's pool
+    threads all end phases; each updates only the rows of its own
+    thread id, so no update needs a lock (inserting a key is atomic
+    under the GIL), and :func:`phase_sums` adds the threads up.
+    ``layer`` ``None`` is for code that does not know who called it
+    (a device entry): the thread's owner (:func:`adopt`), else the
+    enclosing span's layer and no sums.  ``parent`` is the ``origin``
+    of a phase of another thread, for work whose cause is not this
+    thread's context.  A phase that begins on one thread and ends on
+    another is opened with ``start(push=False)`` and closed with
+    ``stop()``: nothing nests under it by context.  Keyword arguments
+    are metadata of the profiler annotation."""
+
+    __slots__ = ("layer", "name", "sums", "parent", "meta", "_span", "_t0")
+
+    def __init__(self, layer: str | None, name: str,
+                 sums: dict | None = None, parent: tuple | None = None,
+                 **meta):
+        self.layer, self.name, self.sums = layer, name, sums
+        self.parent, self.meta = parent, meta
+        self._span = None
+
+    def start(self, push: bool = True) -> "phase":
+        if self.layer is None:
+            owner = getattr(_THREAD, "owner", None)
+            if owner is not None:
+                self.layer, self.sums = owner
+            else:
+                cur = CURRENT.get()
+                self.layer = cur[3] if cur is not None else ""
+        if ENABLED:
+            self._span = enter(self.layer, self.name, None, self.parent,
+                               push, False, self.meta)
+            self._t0 = self._span[6]
+        else:
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    __enter__ = start
+
+    @property
+    def origin(self) -> tuple | None:
+        """The ``CURRENT`` tuple this phase was opened under: what a
+        sibling on another thread passes as ``parent``."""
+        s = self._span
+        return None if s is None else (s[0], s[1] - 1, s[8], s[4])
+
+    def __exit__(self, et=None, ev=None, tb=None) -> bool:
+        dt = (time.perf_counter_ns() - self._t0) * 1e-9
+        sums = self.sums
+        if sums is not None:
+            key = (self.name, threading.get_ident())
+            s = sums.get(key)
+            if s is None:
+                s = sums[key] = [0, 0.0, 0.0]
+            s[0] += 1
+            s[1] += dt
+            if dt > s[2]:
+                s[2] = dt
+        if self._span is not None:
+            exit_span(self._span, dt, et is not None)
+        return False
+
+    def stop(self, err: bool = False) -> None:
+        self.__exit__(err or None)
+
+
+def adopt(layer: str, sums: dict) -> None:
+    """This thread works for one owner from now on (a codec's pool
+    thread, as the pool's initializer): a ``phase(None, ...)`` opened
+    on it takes that layer name and feeds those sums."""
+    _THREAD.owner = (layer, sums)
+
+
+def phase_sums(sums: dict) -> dict[str, dict]:
+    """Sink one as a dump shows it: ``{phase: {count, seconds,
+    max_ms}}``, the threads' rows added up."""
+    out: dict[str, list] = {}
+    for (name, _thread), (c, secs, mx) in list(sums.items()):
+        cur = out.setdefault(name, [0, 0.0, 0.0])
+        cur[:] = cur[0] + c, cur[1] + secs, max(cur[2], mx)
+    return {name: {"count": c, "seconds": round(secs, 6),
+                   "max_ms": round(mx * 1e3, 3)}
+            for name, (c, secs, mx) in sorted(out.items())}
+
+
 def _flight():
     """Late import: flight imports tracing at module top (for the span
     ring in its snapshot) — this side of the cycle resolves lazily."""
@@ -152,27 +320,42 @@ def spans_for(trace_id: str) -> list[tuple]:
 def recent_spans(limit: int = 200) -> list[dict]:
     """Newest spans as dicts (statedump's trace_spans section)."""
     out = []
-    for tid, depth, layer_name, op, start, dur, err in \
-            list(SPANS)[-limit:]:
+    for s in list(SPANS)[-limit:]:
+        tid, depth, layer_name, op, start, dur, err = s[:7]
+        sid, pid = s[7:9] if len(s) > 8 else (0, 0)
         out.append({"trace": tid, "depth": depth, "layer": layer_name,
                     "op": op, "start": round(start, 6),
-                    "ms": round(dur * 1e3, 3), "err": err})
+                    "ms": round(dur * 1e3, 3), "err": err,
+                    "span": sid, "parent": pid})
     return out
 
 
 def render_tree(trace_id: str) -> str:
     """The trace's spans as an indented tree (slow-fop log format:
-    one line per span, two spaces per depth, duration in ms)."""
+    one line per span, two spaces per depth, duration in ms).  Each
+    span follows its parent, siblings by start; a span whose parent is
+    not in the ring (the other side of the wire, or evicted) stands
+    where its start puts it among the roots."""
     spans = sorted(spans_for(trace_id), key=lambda s: (s[4], s[1]))
+    ids = {s[7] for s in spans if len(s) > 8}
+    kids: dict[int, list] = {}
+    for s in spans:
+        pid = s[8] if len(s) > 8 and s[8] in ids else 0
+        kids.setdefault(pid, []).append(s)
     lines = []
-    for _tid, depth, layer_name, op, _start, dur, err in spans:
-        mark = " !!" if err else ""
-        lines.append(f"{'  ' * depth}{layer_name}.{op} "
-                     f"{dur * 1e3:.2f}ms{mark}")
+    stack = kids.get(0, [])[::-1]
+    while stack:
+        s = stack.pop()
+        mark = " !!" if s[6] else ""
+        lines.append(f"{'  ' * s[1]}{s[2]}.{s[3]} "
+                     f"{s[5] * 1e3:.2f}ms{mark}")
+        if len(s) > 8:
+            stack.extend(kids.get(s[7], [])[::-1])
     return "\n".join(lines)
 
 
 __all__ = ["ENABLED", "SLOW_FOP_THRESHOLD", "SLOW_FOP_COUNTS", "SPANS",
-           "CURRENT", "arm",
-           "enter", "exit_span", "current_id", "new_trace_id",
-           "recent_spans", "render_tree", "set_ring_size", "spans_for"]
+           "CURRENT", "ANNOTATE", "adopt", "arm",
+           "enter", "exit_span", "phase", "phase_sums", "current_id",
+           "new_trace_id", "recent_spans", "render_tree",
+           "set_ring_size", "spans_for"]

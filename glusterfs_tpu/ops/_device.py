@@ -1,0 +1,28 @@
+"""The device entry of every jax backend (ops/gf256_xla, ops/gf256_pallas):
+host arrays in, one jitted call, a host array out."""
+
+from __future__ import annotations
+
+import jax.numpy as jnp
+import numpy as np
+
+from ..core import tracing
+
+
+def device_call(fn, *host) -> np.ndarray:
+    """``np.asarray(fn(*[jnp.asarray(a) for a in host]))`` as three
+    phases of the enclosing flush's span (core/tracing.py), each a
+    wait of the HOST: ``codec.h2d`` is ``jnp.asarray`` returning,
+    ``codec.launch`` the jitted call returning (the dispatch: nothing
+    waits for the device that did not wait before), ``codec.d2h`` is
+    ``np.asarray``, which waits for the kernel and then for the copy
+    back.  None of them is the transfer alone, and a profiler trace
+    does not make them so: its device planes stood up to a millisecond
+    off its host planes (PERF.md section 6, PR 24), so a host span
+    cannot be laid against a device op."""
+    with tracing.phase(None, "codec.h2d"):
+        dev = [jnp.asarray(a) for a in host]
+    with tracing.phase(None, "codec.launch"):
+        out = fn(*dev)
+    with tracing.phase(None, "codec.d2h"):
+        return np.asarray(out)

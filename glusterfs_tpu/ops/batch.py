@@ -54,6 +54,18 @@ coalesced into HBM-resident batches" — is a batching window:
   fop traffic, "heal" = shd re-encode) and each opens a ``mesh-codec``
   span joined to the first queued fop's trace.
 
+* every flush is a span tree (core/tracing.py ``phase``): per fop
+  ``codec.queue`` (enqueue to the start of the flush in the pool: the
+  same-tick timer and the thread hop) and ``codec.resume`` (end of the
+  flush to the fop running again on the loop); per flush, on the pool
+  thread, ``codec.flush`` under the first fop's span, with
+  ``codec.gather`` (concatenate, pad to the bucket), the launch's
+  ``codec.h2d`` / ``codec.launch`` / ``codec.d2h`` (ops/_device
+  ``device_call``) and ``codec.scatter`` (per-fop copies) inside it;
+  a flush of one fop that fills its bucket gathers and scatters
+  nothing and has neither span.
+  ``dump_stats()["phases"]`` has their sums.
+
 Correctness leans on fragment-stream concatenation: fragment ``f`` of
 ``concat(stripes_a, stripes_b)`` is ``concat(frag_f(a), frag_f(b))`` —
 stripes are independent (ec-method.c:393-408 loops stripes).
@@ -185,7 +197,8 @@ class BatchingCodec(Codec):
         self.window = window
         self.min_batch = min_batch
         self.max_batch_bytes = max_batch_bytes
-        self._enc_q: list[tuple] = []  # (data, fut, origin, trace_id)
+        # (data, fut, origin, the fop's open codec.queue phase)
+        self._enc_q: list[tuple] = []
         self._enc_task: asyncio.Task | None = None
         self._dec_q: dict[tuple[int, ...], list[tuple]] = {}
         self._dec_task: asyncio.Task | None = None
@@ -206,8 +219,14 @@ class BatchingCodec(Codec):
         self.max_batch = 0
         # two workers: batch N's device round trip overlaps batch N+1's
         # dispatch/host work (jax serializes on-device execution itself)
+        # sink one of this codec's phases (core/tracing.py): rows per
+        # (phase, thread), each written by its own thread alone.  The
+        # pool's threads work for this codec only, so the device
+        # entries' ownerless phases (ops/_device.py) land here too
+        self.phases: dict = {}
         self._pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=2, thread_name_prefix=f"ec-codec-{k}+{r}")
+            max_workers=2, thread_name_prefix=f"ec-codec-{k}+{r}",
+            initializer=_tracing.adopt, initargs=(self.name, self.phases))
         self._lock = threading.Lock()
         self._dev = _PathModel()
         self._nat = _PathModel()
@@ -249,6 +268,13 @@ class BatchingCodec(Codec):
         # the cold-start burst.
         self._last_flush = time.monotonic()
         self._cal_timer: asyncio.Task | None = None
+        if self.backend in _DEVICE_BACKENDS and _tracing.ANNOTATE is None:
+            # a jax backend: this is the process that owns the chip, so
+            # whoever starts jax.profiler here finds the program's
+            # spans beside the device ops (core/tracing.py, sink three)
+            import jax.profiler
+
+            _tracing.ANNOTATE = jax.profiler.TraceAnnotation
 
     _CAL_IDLE_S = 0.3
 
@@ -322,47 +348,29 @@ class BatchingCodec(Codec):
         a whole coalesced flush (runs in the pool).  Pads to the stripe
         bucket so the jit cache stays bounded (zero stripes encode to
         zero fragments — sliced back off), records the launch on the
-        mesh counters, and opens a ``mesh-codec`` span joined to the
-        first queued fop's trace so slow-fop trees show the dispatch."""
+        mesh counters, and opens a ``mesh-codec`` span (under the
+        flush's, so in the first queued fop's trace) so slow-fop trees
+        show the dispatch."""
         from . import codec as codec_mod
         from ..parallel import mesh_codec
 
-        origins = {o for _d, _f, o, _t in batch}
+        origins = {o for _d, _f, o, _q in batch}
         origin = origins.pop() if len(origins) == 1 else "mixed"
-        tid = next((t for _d, _f, _o, t in batch if t), None)
-        tok = _tracing.CURRENT.set((tid, 0)) \
-            if (_tracing.ENABLED and tid) else None
-        span = _tracing.enter("mesh-codec", op) if _tracing.ENABLED \
-            else None
-        t0 = time.perf_counter()
-        err = False
+        unit = self.fragment_chunk if op == "decode" else self.stripe_size
+        s = cat.shape[-1] // unit
         sb = 0
         try:
-            if op in ("encode", "delta"):
-                s = cat.size // self.stripe_size
-                sb = _bucket_stripes(s)
-                if sb != s:
-                    cat = np.concatenate(
-                        [cat, np.zeros((sb - s) * self.stripe_size,
-                                       dtype=np.uint8)])
+            with _tracing.phase("mesh-codec", op):
+                cat = self._pad_bucket(cat)
+                sb = cat.shape[-1] // unit
                 if op == "delta":
                     out = mesh_codec.sharded_parity(
                         self.k, self.r, cat, self._mesh)
-                else:
+                elif op == "encode":
                     out = mesh_codec.sharded_encode(
                         self.k, self.r, cat, self._mesh,
                         systematic=self.systematic)
-                out = out[:, : s * self.fragment_chunk]
-            else:
-                w = cat.shape[1]
-                s = w // self.fragment_chunk
-                sb = _bucket_stripes(s)
-                if sb != s:
-                    cat = np.concatenate(
-                        [cat, np.zeros((cat.shape[0],
-                                        (sb - s) * self.fragment_chunk),
-                                       dtype=np.uint8)], axis=1)
-                if cat.size > codec_mod.MESH_RING_DECODE_BYTES:
+                elif cat.size > codec_mod.MESH_RING_DECODE_BYTES:
                     # the memory-bounded alternative: fragments stay
                     # ring-sharded, an XOR accumulator ppermutes
                     from ..parallel import ring_codec
@@ -372,16 +380,10 @@ class BatchingCodec(Codec):
                 else:
                     out = mesh_codec.sharded_decode(
                         self.k, rows, cat, self._mesh)
-                out = out[: w * self.k]
-            return out
-        except Exception:
-            err = True
-            raise
+                if op == "decode":
+                    return out[: s * self.stripe_size]
+                return out[:, : s * self.fragment_chunk]
         finally:
-            if span is not None:
-                _tracing.exit_span(span, time.perf_counter() - t0, err)
-            if tok is not None:
-                _tracing.CURRENT.reset(tok)
             with self._lock:
                 self.launches += 1
                 key = (op, origin)
@@ -532,38 +534,91 @@ class BatchingCodec(Codec):
 
     # -- bucketed device launches ------------------------------------------
 
-    def _encode_bucketed(self, data: np.ndarray) -> np.ndarray:
-        """Device encode with zero-stripe padding to a bucketed shape."""
-        s = data.size // self.stripe_size
+    def _pad_bucket(self, cat: np.ndarray) -> np.ndarray:
+        """Zero-pad a flush to its power-of-two stripe bucket, along
+        the one axis of stripe-major bytes or the second of ``(k, w)``
+        fragments (zero stripes code to zeros, sliced back off)."""
+        unit = self.stripe_size if cat.ndim == 1 else self.fragment_chunk
+        s = cat.shape[-1] // unit
         sb = _bucket_stripes(s)
-        if sb != s:
-            data = np.concatenate(
-                [data, np.zeros((sb - s) * self.stripe_size, dtype=np.uint8)])
-        frags = self.encode(data)
-        return frags[:, : s * self.fragment_chunk]
+        if sb == s:
+            return cat
+        pad = np.zeros(cat.shape[:-1] + ((sb - s) * unit,), dtype=np.uint8)
+        return np.concatenate([cat, pad], axis=-1)
 
-    def _delta_bucketed(self, delta: np.ndarray) -> np.ndarray:
-        """Device parity-delta encode with zero-stripe bucket padding
-        (zero stripes have zero parity deltas — sliced back off)."""
-        s = delta.size // self.stripe_size
-        sb = _bucket_stripes(s)
-        if sb != s:
-            delta = np.concatenate(
-                [delta, np.zeros((sb - s) * self.stripe_size,
-                                 dtype=np.uint8)])
-        pds = self.encode_delta(delta)
-        return pds[:, : s * self.fragment_chunk]
+    # -- the flush's spans (core/tracing.py) -------------------------------
 
-    def _decode_bucketed(self, frags: np.ndarray, rows) -> np.ndarray:
-        w = frags.shape[1]
-        s = w // self.fragment_chunk
-        sb = _bucket_stripes(s)
-        if sb != s:
-            frags = np.concatenate(
-                [frags,
-                 np.zeros((frags.shape[0], (sb - s) * self.fragment_chunk),
-                          dtype=np.uint8)], axis=1)
-        return self.decode(frags, rows)[: w * self.k]
+    def _enqueue(self, q: list, item, fut, origin: str) -> None:
+        """Queue one fop's work with its ``codec.queue`` phase open:
+        it ends on the pool thread, when the flush starts."""
+        q.append((item, fut, origin,
+                  _tracing.phase(self.name, "codec.queue",
+                                 self.phases).start(push=False)))
+
+    def _gather(self, batch, kind: str, axis: int = 0) -> np.ndarray:
+        """The batch as one array, padded to its stripe bucket where it
+        goes to the device: ``codec.gather``.  One fop that fills its
+        bucket is passed on as it is, and has no such span."""
+        if len(batch) == 1:
+            cat = batch[0][0]
+            unit = self.stripe_size if cat.ndim == 1 \
+                else self.fragment_chunk
+            s = cat.shape[-1] // unit
+            if kind != "device" or _bucket_stripes(s) == s:
+                return cat
+        with _tracing.phase(self.name, "codec.gather", self.phases):
+            if len(batch) > 1:
+                cat = np.concatenate([d for d, *_ in batch], axis=axis)
+            return self._pad_bucket(cat) if kind == "device" else cat
+
+    def _scatter(self, batch, out: np.ndarray, width) -> list:
+        """Each fop's own copy of its part of a flush's answer:
+        ``codec.scatter`` (columns of fragments, or bytes of a decode;
+        ``width(item)`` is a fop's share).  One fop takes the answer
+        as it is, with no span."""
+        if len(batch) == 1:
+            return [out]
+        with _tracing.phase(self.name, "codec.scatter", self.phases):
+            results, off = [], 0
+            for item, *_ in batch:
+                n = width(item)
+                results.append(out[..., off:off + n].copy())
+                off += n
+            return results
+
+    def _flush_phase(self, op: str, batch, kind: str, total: int):
+        """Start of a flush, on the pool thread: every fop's
+        ``codec.queue`` ends, and ``codec.flush`` opens under the span
+        the first fop waits in, naming the other waiters' spans."""
+        for *_, q in batch:
+            q.stop()
+        meta = {"op": op, "route": kind, "fops": len(batch),
+                "bytes": total}
+        others = [str(q.origin[2]) for *_, q in batch[1:] if q.origin]
+        if others:
+            meta["others"] = ",".join(others)
+        return _tracing.phase(self.name, "codec.flush", self.phases,
+                              batch[0][3].origin, **meta)
+
+    def _hand_back(self, loop, batch, results, err) -> None:
+        """End of a flush, on the pool thread: each fop's
+        ``codec.resume`` opens here and ends when the fop runs again
+        on the loop."""
+        resumes = [_tracing.phase(self.name, "codec.resume", self.phases,
+                                  q.origin).start(push=False)
+                   for *_, q in batch]
+        loop.call_soon_threadsafe(self._resolve, batch, results, resumes,
+                                  err)
+
+    @staticmethod
+    def _resolve(batch, results, resumes, err) -> None:
+        for i, (_d, fut, *_rest) in enumerate(batch):
+            if err is None and not fut.done():
+                fut.set_result((results[i], resumes[i]))
+                continue
+            resumes[i].stop(err is not None)  # nobody awaits it
+            if not fut.done():
+                fut.set_exception(err)
 
     # -- encode ------------------------------------------------------------
 
@@ -579,12 +634,14 @@ class BatchingCodec(Codec):
             raise ValueError("data length not a multiple of the stripe")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
-        self._enc_q.append((data, fut, origin, _tracing.current_id()))
+        self._enqueue(self._enc_q, data, fut, origin)
         if sum(d.size for d, *_ in self._enc_q) >= self.max_batch_bytes:
             self._flush_encodes()
         elif self._enc_task is None:
             self._enc_task = asyncio.ensure_future(self._enc_timer())
-        return await fut
+        out, resume = await fut
+        resume.stop()
+        return out
 
     async def _enc_timer(self):
         # window 0 = same-tick coalescing: sleep(0) runs after every
@@ -626,46 +683,32 @@ class BatchingCodec(Codec):
     def _run_encode(self, loop, batch, codec: Codec, kind: str,
                     total: int) -> None:
         """Executes in the pool: concatenate, launch, time, resolve."""
+        results = err = None
         try:
-            t0 = time.perf_counter()
-            if len(batch) == 1:
-                cat = batch[0][0]
-            else:
-                cat = np.concatenate([d for d, *_ in batch])
-            if kind == "mesh":
-                frags = self._mesh_launch("encode", cat, None, batch)
-            elif kind == "device":
-                frags = self._encode_bucketed(cat)
-            else:
-                frags = codec.encode(cat)
-            if kind != "mesh":
-                # device samples observe the PADDED size — the launch
-                # did that much work, and _route predicts padded too.
-                # Mesh launches are key-routed, not model-routed: their
-                # timings must not skew the single-device model.
-                self._observe(kind == "device",
-                              self._padded(total) if kind == "device"
-                              else total,
-                              time.perf_counter() - t0)
-            results, off = [], 0
-            for d, *_ in batch:
-                flen = d.size // self.k
-                results.append(frags[:, off:off + flen].copy()
-                               if len(batch) > 1 else frags)
-                off += flen
-            loop.call_soon_threadsafe(self._resolve, batch, results, None)
+            with self._flush_phase("encode", batch, kind, total):
+                t0 = time.perf_counter()
+                cat = self._gather(batch, kind)
+                if kind == "mesh":
+                    frags = self._mesh_launch("encode", cat, None, batch)
+                elif kind == "device":
+                    frags = self.encode(cat)[:, : total // self.k]
+                else:
+                    frags = codec.encode(cat)
+                if kind != "mesh":
+                    # device samples observe the PADDED size — the
+                    # launch did that much work, and _route predicts
+                    # padded too.  Mesh launches are key-routed, not
+                    # model-routed: their timings must not skew the
+                    # single-device model.
+                    self._observe(kind == "device",
+                                  self._padded(total) if kind == "device"
+                                  else total,
+                                  time.perf_counter() - t0)
+                results = self._scatter(batch, frags,
+                                        lambda d: d.size // self.k)
         except Exception as e:
-            loop.call_soon_threadsafe(self._resolve, batch, None, e)
-
-    @staticmethod
-    def _resolve(batch, results, err) -> None:
-        for i, (_d, fut, *_rest) in enumerate(batch):
-            if fut.done() or fut.cancelled():
-                continue
-            if err is not None:
-                fut.set_exception(err)
-            else:
-                fut.set_result(results[i])
+            results, err = None, e
+        self._hand_back(loop, batch, results, err)
 
     # -- parity-delta encode (ISSUE 10) ------------------------------------
 
@@ -684,12 +727,14 @@ class BatchingCodec(Codec):
             raise ValueError("delta length not a multiple of the stripe")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
-        self._delta_q.append((delta, fut, origin, _tracing.current_id()))
+        self._enqueue(self._delta_q, delta, fut, origin)
         if sum(d.size for d, *_ in self._delta_q) >= self.max_batch_bytes:
             self._flush_deltas()
         elif self._delta_task is None:
             self._delta_task = asyncio.ensure_future(self._delta_timer())
-        return await fut
+        out, resume = await fut
+        resume.stop()
+        return out
 
     async def _delta_timer(self):
         await asyncio.sleep(self.window)
@@ -715,31 +760,27 @@ class BatchingCodec(Codec):
 
     def _run_delta(self, loop, batch, codec: Codec, kind: str,
                    total: int) -> None:
+        results = err = None
         try:
-            t0 = time.perf_counter()
-            if len(batch) == 1:
-                cat = batch[0][0]
-            else:
-                cat = np.concatenate([d for d, *_ in batch])
-            if kind == "mesh":
-                # parity deltas ride the same parity-rows-only sharded
-                # program as the systematic mesh encode (ISSUE 12)
-                pds = self._mesh_launch("delta", cat, None, batch)
-            elif kind == "device":
-                pds = self._delta_bucketed(cat)
-            else:
-                pds = codec.encode_delta(cat)
-            # the single-device models track full-generator encodes;
-            # parity-only work would skew them low — don't observe
-            results, off = [], 0
-            for d, *_ in batch:
-                flen = d.size // self.k
-                results.append(pds[:, off:off + flen].copy()
-                               if len(batch) > 1 else pds)
-                off += flen
-            loop.call_soon_threadsafe(self._resolve, batch, results, None)
+            with self._flush_phase("delta", batch, kind, total):
+                cat = self._gather(batch, kind)
+                if kind == "mesh":
+                    # parity deltas ride the same parity-rows-only
+                    # sharded program as the systematic mesh encode
+                    # (ISSUE 12)
+                    pds = self._mesh_launch("delta", cat, None, batch)
+                elif kind == "device":
+                    pds = self.encode_delta(cat)[:, : total // self.k]
+                else:
+                    pds = codec.encode_delta(cat)
+                # the single-device models track full-generator
+                # encodes; parity-only work would skew them low — don't
+                # observe
+                results = self._scatter(batch, pds,
+                                        lambda d: d.size // self.k)
         except Exception as e:
-            loop.call_soon_threadsafe(self._resolve, batch, None, e)
+            results, err = None, e
+        self._hand_back(loop, batch, results, err)
 
     # -- decode ------------------------------------------------------------
 
@@ -751,12 +792,14 @@ class BatchingCodec(Codec):
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         q = self._dec_q.setdefault(rows, [])
-        q.append((frags, fut, origin, _tracing.current_id()))
+        self._enqueue(q, frags, fut, origin)
         if sum(f.size for f, *_ in q) >= self.max_batch_bytes:
             self._flush_decodes()  # same blow-up guard as the encode path
         elif self._dec_task is None:
             self._dec_task = asyncio.ensure_future(self._dec_timer())
-        return await fut
+        out, resume = await fut
+        resume.stop()
+        return out
 
     async def _dec_timer(self):
         await asyncio.sleep(self.window)
@@ -789,32 +832,27 @@ class BatchingCodec(Codec):
 
     def _run_decode(self, loop, rows, batch, codec: Codec, kind: str,
                     total: int) -> None:
+        results = err = None
         try:
-            t0 = time.perf_counter()
-            if len(batch) == 1:
-                cat = batch[0][0]
-            else:
-                cat = np.concatenate([f for f, *_ in batch], axis=1)
-            if kind == "mesh":
-                out = self._mesh_launch("decode", cat, rows, batch)
-            elif kind == "device":
-                out = self._decode_bucketed(cat, rows)
-            else:
-                out = codec.decode(cat, rows)
-            if kind != "mesh":
-                self._observe(kind == "device",
-                              self._padded(total) if kind == "device"
-                              else total,
-                              time.perf_counter() - t0)
-            results, off = [], 0
-            for f, *_ in batch:
-                nbytes = f.shape[1] * self.k
-                results.append(out[off:off + nbytes].copy()
-                               if len(batch) > 1 else out)
-                off += nbytes
-            loop.call_soon_threadsafe(self._resolve, batch, results, None)
+            with self._flush_phase("decode", batch, kind, total):
+                t0 = time.perf_counter()
+                cat = self._gather(batch, kind, axis=1)
+                if kind == "mesh":
+                    out = self._mesh_launch("decode", cat, rows, batch)
+                elif kind == "device":
+                    out = self.decode(cat, rows)[:total]
+                else:
+                    out = codec.decode(cat, rows)
+                if kind != "mesh":
+                    self._observe(kind == "device",
+                                  self._padded(total) if kind == "device"
+                                  else total,
+                                  time.perf_counter() - t0)
+                results = self._scatter(batch, out,
+                                        lambda f: f.shape[1] * self.k)
         except Exception as e:
-            loop.call_soon_threadsafe(self._resolve, batch, None, e)
+            results, err = None, e
+        self._hand_back(loop, batch, results, err)
 
     def close(self) -> None:
         """Release the flush pool.  The EC layer calls this when a
@@ -850,6 +888,9 @@ class BatchingCodec(Codec):
             "device_model": dev,
             "native_model": nat,
             "break_even_bytes": self.break_even_bytes(),
+            # per phase of a flush: count, seconds, slowest (the summed
+            # wait at the codec; core/tracing.py sink one)
+            "phases": _tracing.phase_sums(self.phases),
             "mesh": {
                 "requested": self.mesh_requested,
                 "state": self._mesh_state,

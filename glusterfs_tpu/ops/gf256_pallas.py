@@ -30,7 +30,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import tracing
 from . import gf256
+from ._device import device_call
 
 # Lane tile for uint8 is (32, 128); keep W tiles big to amortize grid overhead.
 _TILE_W = 8192
@@ -142,6 +144,7 @@ def _xor3_apply_fn(sels: tuple[tuple[int, ...], ...], c: int,
             out_specs=pl.BlockSpec((r, _TILE3_M, 128), lambda i: (0, i, 0),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_xor3",
         )(x3)
         return out.reshape(r, w)
 
@@ -169,6 +172,7 @@ def _xor_apply_fn(sels: tuple[tuple[int, ...], ...], c: int, interpret: bool):
             out_specs=pl.BlockSpec((r, _TILE_W), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_xor",
         )(x)
 
     return run
@@ -196,6 +200,7 @@ def _mxu_apply_fn(r: int, c: int, interpret: bool):
             out_specs=pl.BlockSpec((r, tile_w), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_mxu",
         )(abits, x)
 
     return run
@@ -351,6 +356,7 @@ def _fused_encode_fn(k: int, n: int, interpret: bool):
                                    lambda i: (0, i, 0),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_encode",
         )(x)
         return out[:, :s, :].reshape(n, s * gf256.CHUNK_SIZE)
 
@@ -387,6 +393,7 @@ def _fused_decode_fn(k: int, rows: tuple[int, ...], interpret: bool):
             out_specs=pl.BlockSpec((ts, k * 512), lambda i: (i, 0),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_decode",
         )(x)
         return out[:s].reshape(s * k * gf256.CHUNK_SIZE)
 
@@ -453,6 +460,7 @@ def _fused_parity_fn(k: int, n: int, interpret: bool):
             out_specs=pl.BlockSpec((r, ts, 512), lambda i: (0, i, 0),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_parity",
         )(x)
         return out[:, :s, :].reshape(r, s * gf256.CHUNK_SIZE)
 
@@ -486,6 +494,7 @@ def _fused_reconstruct_fn(k: int, rows: tuple[int, ...],
             out_specs=pl.BlockSpec((m, ts, 512), lambda i: (0, i, 0),
                                    memory_space=pltpu.VMEM),
             interpret=interpret,
+            name="gf256_reconstruct",
         )(x)
         return out[:, :s, :].reshape(m, s * gf256.CHUNK_SIZE)
 
@@ -513,7 +522,7 @@ def parity(data: np.ndarray, k: int, n: int,
     cs = max(1, _PARITY_CHUNK_BYTES // stripe)
     fn = _fused_parity_fn(k, n, interpret)
     if s <= cs:
-        return np.asarray(fn(jnp.asarray(data)))
+        return device_call(fn, data)
     launches = []
     for off in range(0, s, cs):
         w = min(cs, s - off)
@@ -521,10 +530,14 @@ def parity(data: np.ndarray, k: int, n: int,
         if w < cs:  # pad the tail so every launch shares one jit shape
             chunk = np.concatenate(
                 [chunk, np.zeros((cs - w) * stripe, dtype=np.uint8)])
-        launches.append((fn(jnp.asarray(chunk)), w))
-    return np.concatenate(
-        [np.asarray(d)[:, : w * gf256.CHUNK_SIZE] for d, w in launches],
-        axis=1)
+        with tracing.phase(None, "codec.h2d"):
+            chunk = jnp.asarray(chunk)
+        with tracing.phase(None, "codec.launch"):
+            launches.append((fn(chunk), w))
+    with tracing.phase(None, "codec.d2h"):
+        return np.concatenate(
+            [np.asarray(d)[:, : w * gf256.CHUNK_SIZE]
+             for d, w in launches], axis=1)
 
 
 def reconstruct(frags: np.ndarray, rows, wanted, k: int,
@@ -532,7 +545,7 @@ def reconstruct(frags: np.ndarray, rows, wanted, k: int,
     """Missing systematic data rows from k survivors (fragment-major)."""
     fn = _fused_reconstruct_fn(k, tuple(int(x) for x in rows),
                                tuple(int(x) for x in wanted), interpret)
-    return np.asarray(fn(jnp.asarray(frags)))
+    return device_call(fn, frags)
 
 
 # ---------------------------------------------------------------------------
@@ -606,8 +619,8 @@ def encode(data, k: int, n: int, formulation: str = "fused",
     if data.size % (k * gf256.CHUNK_SIZE):
         raise ValueError("data length must be a multiple of k*512")
     if formulation == "fused":
-        return np.asarray(_fused_encode_fn(k, n, interpret)(jnp.asarray(data)))
-    return np.asarray(_encode_fn(k, n, formulation, interpret)(jnp.asarray(data)))
+        return device_call(_fused_encode_fn(k, n, interpret), data)
+    return device_call(_encode_fn(k, n, formulation, interpret), data)
 
 
 def decode(frags, rows, k: int, formulation: str = "fused",
@@ -615,11 +628,10 @@ def decode(frags, rows, k: int, formulation: str = "fused",
     frags = np.ascontiguousarray(frags, dtype=np.uint8)
     rows = tuple(int(x) for x in rows)
     if formulation == "fused":
-        fn = _fused_decode_fn(k, rows, interpret)
-        return np.asarray(fn(jnp.asarray(frags)))
+        return device_call(_fused_decode_fn(k, rows, interpret), frags)
     if formulation in ("xor", "xor3"):
-        fn = _decode_fn(k, formulation, interpret, rows)
-        return np.asarray(fn(jnp.asarray(frags)))
+        return device_call(_decode_fn(k, formulation, interpret, rows),
+                           frags)
     bbits_np = gf256.decode_bits_cached(k, rows)
-    fn = _decode_fn(k, "mxu", interpret, None)
-    return np.asarray(fn(jnp.asarray(frags), jnp.asarray(bbits_np, jnp.int8)))
+    return device_call(_decode_fn(k, "mxu", interpret, None), frags,
+                       bbits_np.astype(np.int8))

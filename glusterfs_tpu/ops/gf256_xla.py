@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import gf256
+from ._device import device_call
 
 _BIT_SHIFTS = tuple(1 << t for t in range(8))
 
@@ -173,8 +174,7 @@ def parity(data: np.ndarray, k: int, n: int,
     data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
     if data.size % (k * gf256.CHUNK_SIZE):
         raise ValueError("data length must be a multiple of k*512")
-    out = _parity_fn(k, n, formulation)(jnp.asarray(data))
-    return np.asarray(out)
+    return device_call(_parity_fn(k, n, formulation), data)
 
 
 def encode(data: np.ndarray, k: int, n: int, formulation: str = "matmul",
@@ -183,8 +183,7 @@ def encode(data: np.ndarray, k: int, n: int, formulation: str = "matmul",
     data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
     if data.size % (k * gf256.CHUNK_SIZE):
         raise ValueError("data length must be a multiple of k*512")
-    out = _encode_fn(k, n, formulation, systematic)(jnp.asarray(data))
-    return np.asarray(out)
+    return device_call(_encode_fn(k, n, formulation, systematic), data)
 
 
 def decode(
@@ -196,9 +195,6 @@ def decode(
     rows = tuple(int(x) for x in rows)
     if formulation == "xor":
         fn = _decode_fn(k, "xor", rows, systematic)
-        out = fn(jnp.asarray(frags), None)
-    else:
-        bbits_np = gf256.decode_bits_cached(k, rows, systematic)
-        fn = _decode_fn(k, "matmul", None)
-        out = fn(jnp.asarray(frags), jnp.asarray(bbits_np))
-    return np.asarray(out)
+        return device_call(lambda x: fn(x, None), frags)
+    bbits_np = gf256.decode_bits_cached(k, rows, systematic)
+    return device_call(_decode_fn(k, "matmul", None), frags, bbits_np)

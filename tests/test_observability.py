@@ -634,3 +634,345 @@ end-volume
             await c.unmount()
 
     asyncio.run(run())
+
+
+# -- phases below the fop boundary (ISSUE 24) ------------------------------
+
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation`` as
+    ``tracing.ANNOTATE``: keeps every annotation's name, metadata and
+    the thread that ended it."""
+
+    log: list = []
+
+    def __init__(self, name, **meta):
+        self.name, self.meta = name, meta
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        import threading
+
+        _Recorder.log.append((self.name, self.meta,
+                              threading.current_thread().name))
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    _Recorder.log = []
+    monkeypatch.setattr(tracing, "ENABLED", True)
+    yield _Recorder
+    # after the graph is down: a codec built meanwhile has put jax's
+    # annotation there, which is what the next test expects to find
+    tracing.ANNOTATE = None
+
+
+def _names(log, skip=()):
+    from collections import Counter
+
+    return Counter(n for n, _m, _t in log if n not in skip)
+
+
+# per fop inside a held eager window with the gfid lock free (one fop
+# that fills its bucket has nothing to gather or scatter; a window's
+# first fop adds ``ec.lock``, the fop under which it closes
+# ``ec.unlock`` over ``ec.xattrop``)
+WRITE_PHASES = {"ec.codec_wait", "ec.fanout", "codec.queue", "codec.flush",
+                "codec.h2d", "codec.launch", "codec.d2h", "codec.resume"}
+READ_PHASES = WRITE_PHASES | {"ec.reassemble"}
+
+
+def test_ring_tuple_and_parent_ids_under_gather(tmp_path):
+    """Six wire children under one ``asyncio.gather`` are SIBLINGS: the
+    six ``protocol/client`` writev spans share one ``ec.fanout`` parent
+    (a depth alone cannot tell them from nested calls), and the ring
+    tuple keeps its first seven fields where they were."""
+    import time
+
+    async def run():
+        servers = [await serve_brick(BRICK_VOLFILE.format(
+            dir=tmp_path / f"b{i}")) for i in range(6)]
+        vf = "".join(CLIENT_VOLFILE.replace("c0", f"c{i}").format(
+            port=s.port) for i, s in enumerate(servers))
+        vf += ("volume disp\n    type cluster/disperse\n"
+               "    option redundancy 2\n    option cpu-extensions ref\n"
+               f"    subvolumes {' '.join(f'c{i}' for i in range(6))}\n"
+               "end-volume\n")
+        g = Graph.construct(vf)
+        c = Client(g)
+        await c.mount()
+        try:
+            for _ in range(200):
+                if all(ch.connected for ch in g.top.children):
+                    break
+                await asyncio.sleep(0.05)
+            f = await c.create("/x", os.O_RDWR)
+            await f.write(b"a" * 8192, 0)
+            tracing.SPANS.clear()
+            t_before = time.time()
+            await f.write(b"b" * 8192, 8192)
+            spans = list(tracing.SPANS)
+            await f.close()
+        finally:
+            await c.unmount()
+            for s in servers:
+                await s.stop()
+        root = next(s for s in spans if s[2] == "disp" and s[3] == "writev")
+        tid, depth, layer, op, start, dur, err = root[:7]
+        assert (len(tid), depth, err) == (16, 0, False)
+        assert t_before - 1 < start < time.time() and 0 < dur < 60
+        sid, pid, start_ns = root[7:]
+        assert len(root) == 10 and sid > 0 and pid == 0
+        assert abs(start_ns - time.perf_counter_ns()) < 60e9
+        fan = [s for s in spans if s[3] == "ec.fanout" and s[0] == tid]
+        assert len(fan) == 1 and fan[0][8] == sid, fan
+        wire = [s for s in spans if s[3] == "writev" and s[0] == tid
+                and s[2] in {f"c{i}" for i in range(6)}]
+        assert len(wire) == 6 and {s[8] for s in wire} == {fan[0][7]}
+        assert len({s[7] for s in wire}) == 6
+        assert {s[1] for s in wire} == {fan[0][1] + 1}
+        # the brick side joined the trace over the wire, and the dump
+        # shows ids beside the wall-clock start it always had
+        assert any(s[2] == "posix" and s[0] == tid for s in spans)
+        dumped = [d for d in tracing.recent_spans(4096)
+                  if d["trace"] == tid and d["op"] == "ec.fanout"]
+        assert dumped[0]["span"] == fan[0][7] and \
+            abs(dumped[0]["start"] - fan[0][4]) < 1e-5
+
+    asyncio.run(run())
+
+
+def test_flush_hangs_under_first_fop_and_lists_others(recorder):
+    """The flush runs in a pool thread, outside every ContextVar: it
+    hangs under the span the FIRST fop of its batch waits in (from the
+    open ``codec.queue`` phase in the queue tuple) and names the other
+    waiters; each fop has its own queue and resume span."""
+    import numpy as np
+
+    from glusterfs_tpu.ops.batch import BatchingCodec
+
+    codec = BatchingCodec(4, 2, "xla", window=0.01, min_batch=0,
+                          systematic=True, name="batcher")
+    tracing.ANNOTATE = recorder
+    waits = []
+
+    async def fop(i):
+        with tracing.phase("batcher", "ec.codec_wait") as w:
+            waits.append(w._span[7])
+            return await codec.encode_async(
+                np.full(4 * 2048, i, dtype=np.uint8))
+
+    async def run():
+        tracing.SPANS.clear()
+        return await asyncio.gather(fop(1), fop(2))
+
+    a, b = asyncio.run(run())
+    codec.close()
+    assert a.shape == b.shape == (6, 2048) and a[0, 0] == 1 and b[0, 0] == 2
+    by_name = {}
+    for name, meta, thread in recorder.log:
+        by_name.setdefault(name, []).append((meta, thread))
+    (flush, thread), = by_name["gftpu:codec.flush"]
+    assert thread.startswith("ec-codec-") and flush["parent"] == waits[0]
+    assert flush["others"] == str(waits[1])
+    assert (flush["fops"], flush["route"], flush["op"], flush["bytes"]) \
+        == (2, "device", "encode", 2 * 4 * 2048)
+    for name in ("gftpu:codec.queue", "gftpu:codec.resume"):
+        assert sorted(m["parent"] for m, _t in by_name[name]) == \
+            sorted(waits), name
+    # queue ends where the flush starts (pool), resume on the loop
+    assert all(t.startswith("ec-codec-")
+               for _m, t in by_name["gftpu:codec.queue"])
+    assert all(t == "MainThread"
+               for _m, t in by_name["gftpu:codec.resume"])
+    for name in ("gather", "h2d", "launch", "d2h", "scatter"):
+        (meta, _t), = by_name[f"gftpu:codec.{name}"]
+        assert meta["parent"] == flush["span"], name
+    # the ring shows the same tree, under the first fop's trace
+    ring = {s[3]: s for s in tracing.SPANS if s[3].startswith("codec.")}
+    assert ring["codec.flush"][8] == waits[0]
+    assert ring["codec.h2d"][8] == ring["codec.flush"][7]
+    tree = tracing.render_tree(ring["codec.flush"][0])
+    assert tree.index("ec.codec_wait") < tree.index("codec.queue") < \
+        tree.index("codec.flush") < tree.index("codec.d2h") < \
+        tree.index("codec.resume")
+    sums = codec.dump_stats()["phases"]
+    assert sums["codec.queue"]["count"] == 2 == sums["codec.resume"]["count"]
+    assert sums["codec.flush"]["count"] == 1
+    assert sums["codec.flush"]["seconds"] >= sums["codec.d2h"]["seconds"] > 0
+
+
+def _ec_4p2(tmp_path):
+    from glusterfs_tpu.utils.volspec import ec_volfile
+
+    g = Graph.construct(ec_volfile(tmp_path, 6, 2, options={
+        "cpu-extensions": "xla", "stripe-cache": "on",
+        "stripe-cache-min-batch": 0, "systematic": "on"}))
+    return Client(g), g.top
+
+
+@pytest.mark.parametrize("kind", ["write", "degraded-read"])
+def test_one_mib_through_4p2_yields_every_phase_once(tmp_path, recorder,
+                                                     kind):
+    """A 1 MiB write through an in-process 4+2 graph on a jax backend
+    yields every write-side phase exactly once under its fop span, and
+    a degraded read every read-side phase; taking the window shows as
+    ``ec.lock`` on its first fop and closing it as ``ec.unlock`` on its
+    last, and a fop in between has neither; the sums show in the dumps
+    an operator already reads."""
+    c, ec = _ec_4p2(tmp_path)
+    data = os.urandom(1 << 20)
+    skip = {f"gftpu:storage/posix.{fop}"
+            for fop in ("writev", "readv", "xattrop")}
+
+    def held(fop, want):
+        return {fop: 1, **{f"gftpu:{p}": 1 for p in want}}
+
+    async def run():
+        await c.mount()
+        tracing.ANNOTATE = recorder
+        f = await c.create("/f", os.O_RDWR)
+        try:
+            recorder.log.clear()
+            await f.write(data, 0)  # opens the window: lock, metadata
+            assert _names(recorder.log)["gftpu:ec.lock"] == 1
+            if kind == "write":
+                fop = "gftpu:cluster/disperse.writev"
+                recorder.log.clear()
+                await f.write(data, 1 << 20)
+                got = _names(recorder.log, skip)
+                assert got == held(fop, WRITE_PHASES), got
+                # the fop under which the window closes (max-hold
+                # reached): the post-op rides its ec.unlock
+                ec.opts["eager-lock-max-hold"], hold = \
+                    0, ec.opts["eager-lock-max-hold"]
+                recorder.log.clear()
+                await f.write(data, 2 << 20)
+                ec.opts["eager-lock-max-hold"] = hold
+                last = _names(recorder.log, skip)
+                assert last == held(fop, WRITE_PHASES | {
+                    "ec.unlock", "ec.xattrop"}) | {"gftpu:ec.fanout": 2}, \
+                    last
+                unlock, = [m for n, m, _t in recorder.log
+                           if n == "gftpu:ec.unlock"]
+                post, = [m for n, m, _t in recorder.log
+                         if n == "gftpu:ec.xattrop"]
+                assert post["parent"] == unlock["span"]
+                recorder.log.clear()
+                await f.write(b"tail", (3 << 20) - 2)  # crosses EOF: RMW
+                rmw = _names(recorder.log)
+                assert rmw["gftpu:ec.rmw_read"] == 1 == \
+                    rmw["gftpu:ec.reassemble"] == rmw["gftpu:ec.lock"]
+                recorder.log.clear()
+                await f.fsync()  # the window closes outside a write
+                assert _names(recorder.log)["gftpu:ec.xattrop"] == 1
+            else:
+                await f.fsync()
+                ec.set_child_up(1, False)
+                recorder.log.clear()
+                await f.read(4096, 0)  # opens the window again
+                assert _names(recorder.log)["gftpu:ec.lock"] == 1
+                recorder.log.clear()
+                assert bytes(await f.read(1 << 20, 0)) == data
+                got = _names(recorder.log, skip)
+                assert got == held("gftpu:cluster/disperse.readv",
+                                   READ_PHASES), got
+        finally:
+            await f.close()
+            await c.unmount()
+
+    asyncio.run(run())
+    want = WRITE_PHASES if kind == "write" else READ_PHASES
+    ec_sums = ec.dump_private()["phases"]
+    codec_sums = ec.dump_private()["stripe_cache"]["phases"]
+    assert set(ec_sums) >= {"ec.lock"} | {
+        p for p in want if p.startswith("ec.")}
+    # (the RMW's edge stripe, or the 4 KiB read, was padded to its
+    # bucket on the way)
+    assert set(codec_sums) == {"codec.gather"} | {
+        p for p in WRITE_PHASES if p.startswith("codec.")}, codec_sums
+    assert all(v["count"] >= 1 and v["seconds"] > 0 and v["max_ms"] > 0
+               for v in {**ec_sums, **codec_sums}.values())
+    # every span names its trace, itself and its parent
+    assert all({"trace", "span", "parent"} <= set(m)
+               for _n, m, _t in recorder.log)
+
+
+def test_core_tracing_imports_no_jax():
+    """core/ stays importable in a process that must never load jax
+    (bricks, glusterd): the profiler sink is injected, not imported."""
+    import subprocess
+    import sys
+
+    code = ("import sys; import glusterfs_tpu.core.tracing as t; "
+            "import glusterfs_tpu.core.layer; "
+            "assert t.ANNOTATE is None; "
+            "sys.exit('jax' in sys.modules)")
+    assert subprocess.run([sys.executable, "-c", code],
+                          timeout=120).returncode == 0
+
+
+def test_sums_live_on_the_instance_and_count_in_the_dark(monkeypatch):
+    """Sink one is a dict on the codec (or layer) that ran the phase:
+    two codecs of one name in one process (a client mount and the shd
+    graph; the codec rebuilt by a reconfigure) share no row, a device
+    entry's ownerless phases land with the codec whose pool thread ran
+    them, and with span work off the sums still count."""
+    import numpy as np
+
+    from glusterfs_tpu.ops.batch import BatchingCodec
+
+    monkeypatch.setattr(tracing, "ENABLED", False)
+    a, b = (BatchingCodec(4, 2, "xla", window=0, min_batch=0,
+                          systematic=True, name="vol") for _ in "ab")
+    tracing.SPANS.clear()
+
+    async def run():
+        for _ in range(3):
+            await a.encode_async(np.zeros(16 * 2048, dtype=np.uint8))
+
+    asyncio.run(run())
+    try:
+        sums = a.dump_stats()["phases"]
+        assert {n: v["count"] for n, v in sums.items()} == dict.fromkeys(
+            ("codec.queue", "codec.flush", "codec.h2d", "codec.launch",
+             "codec.d2h", "codec.resume"), 3), sums
+        assert a.dump_stats()["flushes"] == 3
+        assert b.dump_stats()["phases"] == {} == b.phases
+        assert not tracing.SPANS  # dark: sums and nothing else
+    finally:
+        a.close()
+        b.close()
+
+
+def test_a_phase_nests_under_the_phase_it_opens_in():
+    """Every phase becomes its context's current span: a fop span or a
+    phase opened inside one hangs under it, siblings share it, and a
+    phase begun for another thread (``start(push=False)``) is nobody's
+    parent by context."""
+    tracing.SPANS.clear()
+    sums: dict = {}
+    with tracing.phase("l", "x.outer", sums) as outer:
+        handed = tracing.phase("l", "x.handed", sums).start(push=False)
+        with tracing.phase("l", "x.inner", sums) as inner:
+            with tracing.phase(None, "x.ownerless") as deepest:
+                pass
+        with tracing.phase("l", "x.sibling", sums) as sibling:
+            pass
+        handed.stop()
+    sid = {p.name: p._span[7] for p in (outer, handed, inner, deepest,
+                                        sibling)}
+    parent = {s[3]: s[8] for s in tracing.SPANS}
+    assert parent == {"x.outer": 0, "x.handed": sid["x.outer"],
+                      "x.inner": sid["x.outer"],
+                      "x.ownerless": sid["x.inner"],
+                      "x.sibling": sid["x.outer"]}
+    # the ownerless phase took the enclosing span's layer, and no sums
+    assert {s[3]: s[2] for s in tracing.SPANS}["x.ownerless"] == "l"
+    assert set(tracing.phase_sums(sums)) == {
+        "x.outer", "x.handed", "x.inner", "x.sibling"}
