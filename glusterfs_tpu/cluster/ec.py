@@ -73,6 +73,13 @@ _metrics.REGISTRY.register_objects(
     lambda l: [({"layer": l.name}, l.write_path["rmw"])],
     live=_LIVE_EC_LAYERS)
 _metrics.REGISTRY.register_objects(
+    "gftpu_ec_split_writes_total", "counter",
+    "write waves sent in two parts: the data fragments of a systematic "
+    "layout went to their bricks while the codec computed parity, the "
+    "parity fragments followed (one wave to the transaction)",
+    lambda l: [({"layer": l.name}, l.write_path["split"])],
+    live=_LIVE_EC_LAYERS)
+_metrics.REGISTRY.register_objects(
     "gftpu_ec_delta_bytes_saved_total", "counter",
     "fragment bytes the delta path did NOT move versus the full RMW "
     "it replaced (dir=read: decode-source bytes not read; dir=write: "
@@ -335,7 +342,9 @@ class DisperseLayer(Layer):
         self.read_coalesced = {"chains": 0, "links": 0}
         # parity-delta write plane (ISSUE 10): path taken per unaligned
         # write + fragment bytes the delta path saved over full RMW
-        self.write_path = {"delta": 0, "rmw": 0}
+        # "split" counts the write waves that went out in two parts
+        # around the codec (_writev_in_window), whatever else they were
+        self.write_path = {"delta": 0, "rmw": 0, "split": 0}
         # delta writes split by traffic_origin ("serve" vs "rebalance"
         # vs "heal"): write_path["delta"] stays the total; this dict
         # feeds the per-origin samples on the registry family so an
@@ -854,15 +863,46 @@ class DisperseLayer(Layer):
             self._local_cached = cached
         return cached
 
-    async def _dispatch(self, idxs: list[int], op: str, argfn):
+    async def _dispatch(self, idxs: list[int], op: str, argfn, **meta):
         """Run fop on children idxs concurrently; returns {idx: result or
         exception}.  argfn(i) -> (args, kwargs) per child.  One
         ``ec.fanout`` span: building every child's arguments (a write's
         ``tobytes()``) and the gather; the children's fop spans are its
-        children."""
-        with _tracing.phase(self.name, "ec.fanout", self.phases, op=op):
+        children.  ``meta`` is more metadata of that span (the ``part``
+        of a wave sent in two)."""
+        with _tracing.phase(self.name, "ec.fanout", self.phases, op=op,
+                            **meta):
             return await self._dispatch_multi(
                 {i: (op, *argfn(i)) for i in idxs}, order=idxs)
+
+    async def _dispatch_around(self, idxs: list[int], op: str, argfn,
+                               early, coded: asyncio.Task):
+        """One wave in two parts around a codec answer that is still on
+        its way: the children in ``early``, whose arguments need
+        nothing of it, are called at once; the others when ``coded``
+        (the codec's task) has resolved.  Returns like
+        :meth:`_dispatch`, when every child has answered.  Should the
+        codec fail, the first part is left to settle (its bricks may
+        hold the new bytes already) and the caller gets EIO; a cancel
+        takes the first part down with it (``coded`` is its
+        creator's to cancel)."""
+        head = asyncio.ensure_future(self._dispatch(
+            [i for i in idxs if i in early], op, argfn, part="data"))
+        try:
+            try:
+                await coded
+            except Exception as e:
+                await asyncio.wait([head])
+                raise FopError(
+                    errno.EIO, f"{self.name}: the codec failed with the "
+                    f"data part of a {op} wave on the bricks: {e!r}") from e
+            tail = await self._dispatch(
+                [i for i in idxs if i not in early], op, argfn,
+                part="parity")
+            return {**await head, **tail}
+        except BaseException:
+            head.cancel()
+            raise
 
     async def _dispatch_multi(self, wave: dict[int, tuple],
                               order: list[int] | None = None):
@@ -1508,11 +1548,22 @@ class DisperseLayer(Layer):
         return out
 
     async def _window_op(self, fd: FdObj, loc: Loc, st: _EagerState,
-                         op: str, argfn) -> dict:
+                         op: str, argfn, early=(),
+                         coded: asyncio.Task | None = None) -> dict:
         """One write-class wave through the open eager window: pre-op
         once per window, poison-across-dispatch (a torn-off wave must
         never let the flush release dirty over diverged fragments),
-        good-set intersection, quorum, version delta."""
+        good-set intersection, quorum, version delta.
+
+        With ``coded`` (the codec's task) still running the wave goes
+        out in two parts (:meth:`_dispatch_around`): the targets in
+        ``early`` now, the others when it has resolved.  To the
+        transaction that is still ONE wave: one target set, the
+        piggybacked pre-op on every call of both parts, one
+        ``inflight`` count, the good set and the quorum judged once
+        over the union of the answers, and a cancel or a failure
+        anywhere between the first call and the last answer poisons
+        the whole target set."""
         targets = sorted(st.good & set(self._up_idx()))
         if not st.pre:
             # pre-op once per window: dirty+1 (ec-common.c:2377).  For
@@ -1537,7 +1588,12 @@ class DisperseLayer(Layer):
         st.idle.clear()
         ok: set[int] | None = None
         try:
-            res = await self._dispatch(targets, op, argfn)
+            if coded is None or coded.done():  # nothing to overlap
+                res = await self._dispatch(targets, op, argfn)
+            else:
+                self.write_path["split"] += 1
+                res = await self._dispatch_around(targets, op, argfn,
+                                                  early, coded)
             ok = {i for i, r in res.items()
                   if not isinstance(r, BaseException)}
         finally:
@@ -1792,12 +1848,53 @@ class DisperseLayer(Layer):
                     buf[max(0, true_size - a_off): old.size] = 0
         buf[offset - a_off: end - a_off] = np.frombuffer(
             bytes(data), dtype=np.uint8)
-        frags = await self._codec_encode(buf)
         f_off = a_off // self.k
-        good = await self._window_op(
-            fd, loc, st, "writev",
-            lambda i: ((self._child_fd(fd, i),
-                        frags[i].tobytes(), f_off), {}))
+        # The first ``uncoded`` children's fragments need no codec: the
+        # data rows of a systematic layout are the user's bytes.  Where
+        # the codec also works off this loop (the batcher's pool
+        # thread), those children are written while it computes, and
+        # only the parity children wait for its answer; with none such
+        # (every fragment a codeword, or a codec that would hold the
+        # loop anyway) the one wave follows the encode.
+        uncoded = self.k if self.codec.systematic and self._batching else 0
+        rows = buf.reshape(-1, self.k, CHUNK)
+        coded = None
+        try:
+            if uncoded:
+                launched = asyncio.get_running_loop().create_future()
+                coded = asyncio.ensure_future(
+                    self._codec_encode(buf, launched=launched))
+                # The data part is marshalled once the flush has
+                # dispatched its launch, not before: up to there the
+                # pool thread holds the interpreter (h2d, the jitted
+                # call), and a loop that marshals 4 x 256 KiB beside it
+                # stretches both; from there on it only waits, and the
+                # codec's leg, which the parity part follows, is the
+                # longer one anyway.  A codec that fails before its
+                # launch has touched no brick: its error goes up as it
+                # always did.
+                await asyncio.wait((launched, coded),
+                                   return_when=asyncio.FIRST_COMPLETED)
+                if coded.done():
+                    await coded
+            else:
+                frags = await self._codec_encode(buf)
+
+            def argfn(i):
+                if i < uncoded:
+                    # chunk i of every stripe (ops/codec ``_data_rows``),
+                    # copied whole first: a strided ``tobytes()`` walks
+                    # it byte by byte
+                    frag = np.ascontiguousarray(rows[:, i, :])
+                else:
+                    frag = (coded.result() if coded else frags)[i]
+                return (self._child_fd(fd, i), frag.tobytes(), f_off), {}
+
+            good = await self._window_op(fd, loc, st, "writev", argfn,
+                                         range(uncoded), coded)
+        finally:
+            if coded is not None:
+                coded.cancel()  # left behind by a cancel or a failure
         # re-read st.size (not the wave-start snapshot): a concurrent
         # parallel write past our range may have grown it meanwhile
         st.size = max(st.size, end)
@@ -2215,11 +2312,15 @@ class DisperseLayer(Layer):
     # the batcher as the fop saw it; its queue, flush and resume spans
     # (ops/batch.py) are that span's children
 
-    async def _codec_encode(self, buf, origin: str | None = None):
+    async def _codec_encode(self, buf, origin: str | None = None,
+                            launched: asyncio.Future | None = None):
+        """``launched``: the batcher resolves it when the flush has
+        dispatched its launch (ops/batch ``encode_async``)."""
         with _tracing.phase(self.name, "ec.codec_wait", self.phases):
             if self._batching:
                 return await self.codec.encode_async(
-                    buf, origin=origin or self.traffic_origin)
+                    buf, origin=origin or self.traffic_origin,
+                    launched=launched)
             return self.codec.encode(buf)
 
     async def _codec_delta(self, buf, origin: str | None = None):

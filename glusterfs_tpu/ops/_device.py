@@ -3,10 +3,34 @@ host arrays in, one jitted call, a host array out."""
 
 from __future__ import annotations
 
+import threading
+
 import jax.numpy as jnp
 import numpy as np
 
 from ..core import tracing
+
+#: per thread: who wants to hear of this thread's next launch
+_THREAD = threading.local()
+
+
+def after_launch(cb) -> None:
+    """``cb()`` is called once, on this thread, when its next launch
+    has been dispatched (:func:`launched`).  Up to there the entry
+    works in the interpreter (``jnp.asarray`` and the jitted call hold
+    it almost throughout on the v5e's host: PERF.md section 6, PR 25);
+    what is left is the wait for the answer, which releases it.  The
+    batcher tells the fops of a flush (ops/batch ``encode_async``
+    ``launched``)."""
+    _THREAD.cb = cb
+
+
+def launched() -> None:
+    """The launch is dispatched: tell whoever asked, once."""
+    cb = getattr(_THREAD, "cb", None)
+    if cb is not None:
+        _THREAD.cb = None
+        cb()
 
 
 def device_call(fn, *host) -> np.ndarray:
@@ -24,5 +48,6 @@ def device_call(fn, *host) -> np.ndarray:
         dev = [jnp.asarray(a) for a in host]
     with tracing.phase(None, "codec.launch"):
         out = fn(*dev)
+    launched()
     with tracing.phase(None, "codec.d2h"):
         return np.asarray(out)

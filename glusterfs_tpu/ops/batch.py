@@ -197,7 +197,8 @@ class BatchingCodec(Codec):
         self.window = window
         self.min_batch = min_batch
         self.max_batch_bytes = max_batch_bytes
-        # (data, fut, origin, the fop's open codec.queue phase)
+        # (data, fut, origin, the fop's ``launched`` future or None,
+        # the fop's open codec.queue phase)
         self._enc_q: list[tuple] = []
         self._enc_task: asyncio.Task | None = None
         self._dec_q: dict[tuple[int, ...], list[tuple]] = {}
@@ -354,7 +355,7 @@ class BatchingCodec(Codec):
         from . import codec as codec_mod
         from ..parallel import mesh_codec
 
-        origins = {o for _d, _f, o, _q in batch}
+        origins = {o for _d, _f, o, *_ in batch}
         origin = origins.pop() if len(origins) == 1 else "mixed"
         unit = self.fragment_chunk if op == "decode" else self.stripe_size
         s = cat.shape[-1] // unit
@@ -548,10 +549,11 @@ class BatchingCodec(Codec):
 
     # -- the flush's spans (core/tracing.py) -------------------------------
 
-    def _enqueue(self, q: list, item, fut, origin: str) -> None:
+    def _enqueue(self, q: list, item, fut, origin: str,
+                 launched=None) -> None:
         """Queue one fop's work with its ``codec.queue`` phase open:
         it ends on the pool thread, when the flush starts."""
-        q.append((item, fut, origin,
+        q.append((item, fut, origin, launched,
                   _tracing.phase(self.name, "codec.queue",
                                  self.phases).start(push=False)))
 
@@ -598,7 +600,7 @@ class BatchingCodec(Codec):
         if others:
             meta["others"] = ",".join(others)
         return _tracing.phase(self.name, "codec.flush", self.phases,
-                              batch[0][3].origin, **meta)
+                              batch[0][-1].origin, **meta)
 
     def _hand_back(self, loop, batch, results, err) -> None:
         """End of a flush, on the pool thread: each fop's
@@ -622,19 +624,29 @@ class BatchingCodec(Codec):
 
     # -- encode ------------------------------------------------------------
 
-    async def encode_async(self, data: np.ndarray,
-                           origin: str = "serve") -> np.ndarray:
+    async def encode_async(self, data: np.ndarray, origin: str = "serve",
+                           launched: asyncio.Future | None = None
+                           ) -> np.ndarray:
         """Encode stripe-aligned bytes; coalesced with concurrent calls.
 
         ``origin`` labels the traffic source on the mesh counters
         ("serve" = fop data path, "heal" = shd re-encode) and rides the
-        queue so a flush can attribute its launch."""
+        queue so a flush can attribute its launch.
+
+        ``launched`` (a future of the caller's loop) is resolved when
+        the flush that carries this fop has got past the part of its
+        work that holds the interpreter: on the device route when the
+        launch is dispatched (ops/_device ``after_launch``), on the
+        others when the coding call begins; at the latest when the
+        flush ends, however it ends.  A caller with work of its own for
+        the loop does it from then on, beside the pool thread's wait,
+        instead of fighting it for the interpreter."""
         data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
         if data.size % self.stripe_size:
             raise ValueError("data length not a multiple of the stripe")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
-        self._enqueue(self._enc_q, data, fut, origin)
+        self._enqueue(self._enc_q, data, fut, origin, launched)
         if sum(d.size for d, *_ in self._enc_q) >= self.max_batch_bytes:
             self._flush_encodes()
         elif self._enc_task is None:
@@ -684,10 +696,24 @@ class BatchingCodec(Codec):
                     total: int) -> None:
         """Executes in the pool: concatenate, launch, time, resolve."""
         results = err = None
+        waiting = [w for _d, _f, _o, w, _q in batch if w is not None]
+
+        def tell():
+            # once: the fops that asked hear that the launch is out
+            if waiting:
+                loop.call_soon_threadsafe(self._tell_launched, waiting[:])
+                waiting.clear()
+
         try:
             with self._flush_phase("encode", batch, kind, total):
                 t0 = time.perf_counter()
                 cat = self._gather(batch, kind)
+                if kind == "device" and waiting:
+                    from . import _device
+
+                    _device.after_launch(tell)
+                else:
+                    tell()
                 if kind == "mesh":
                     frags = self._mesh_launch("encode", cat, None, batch)
                 elif kind == "device":
@@ -708,7 +734,14 @@ class BatchingCodec(Codec):
                                         lambda d: d.size // self.k)
         except Exception as e:
             results, err = None, e
+        tell()  # a flush that ended before its launch
         self._hand_back(loop, batch, results, err)
+
+    @staticmethod
+    def _tell_launched(waiting) -> None:
+        for fut in waiting:
+            if not fut.done():
+                fut.set_result(None)
 
     # -- parity-delta encode (ISSUE 10) ------------------------------------
 

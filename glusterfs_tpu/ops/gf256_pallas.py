@@ -32,7 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core import tracing
 from . import gf256
-from ._device import device_call
+from ._device import device_call, launched
 
 # Lane tile for uint8 is (32, 128); keep W tiles big to amortize grid overhead.
 _TILE_W = 8192
@@ -534,6 +534,7 @@ def parity(data: np.ndarray, k: int, n: int,
             chunk = jnp.asarray(chunk)
         with tracing.phase(None, "codec.launch"):
             launches.append((fn(chunk), w))
+    launched()
     with tracing.phase(None, "codec.d2h"):
         return np.concatenate(
             [np.asarray(d)[:, : w * gf256.CHUNK_SIZE]

@@ -305,3 +305,54 @@ def test_calibration_schedule_check_is_locked():
     t1, t2, t3 = asyncio.run(run())
     assert t1 is t2 and t1 is not None
     assert t3 is None
+
+
+@pytest.mark.parametrize("route", ["device", "cpu", "fails"])
+def test_launched_future_resolves_on_every_route(monkeypatch, route):
+    """``encode_async(launched=...)``: on the device route the future
+    resolves after the jitted call was dispatched and before the answer
+    is fetched; on the CPU ladder when the coding call begins; and a
+    flush that fails before any launch still resolves it (ISSUE 25:
+    cluster/ec marshals a write's data part from then on)."""
+    from glusterfs_tpu.ops import _device, gf256_xla
+
+    order: list = []
+    codec = BatchingCodec(K, R, "xla", systematic=True,
+                          min_batch=(1 << 30) if route == "cpu" else 0)
+    if route == "fails":
+        def refuse(*_a, **_kw):
+            order.append("refused")
+            raise RuntimeError("no device")
+
+        monkeypatch.setattr(gf256_xla, "encode", refuse)
+    else:
+        real_launched = _device.launched
+
+        def launched():
+            order.append("launch dispatched")
+            real_launched()
+
+        monkeypatch.setattr(_device, "launched", launched)
+    d = _rand(STRIPE * 4, 21)
+
+    async def run():
+        fut = asyncio.get_running_loop().create_future()
+        fut.add_done_callback(lambda _f: order.append("told"))
+        job = asyncio.ensure_future(codec.encode_async(d, launched=fut))
+        await asyncio.wait_for(fut, 10)
+        try:
+            return await job
+        except RuntimeError as e:
+            return e
+
+    out = asyncio.run(run())
+    codec.close()
+    if route == "fails":
+        assert isinstance(out, RuntimeError) and order == ["refused", "told"]
+        return
+    assert np.array_equal(out, gf256.ref_encode(d, K, K + R,
+                                                systematic=True))
+    if route == "device":
+        assert order == ["launch dispatched", "told"] and codec.launches == 1
+    else:
+        assert order == ["told"] and codec.cpu_launches == 1

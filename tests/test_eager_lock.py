@@ -256,3 +256,222 @@ def test_degraded_window_keeps_dirty_for_shd(vol):
     assert c.read_file("/deg") == a + b
     ec.set_child_up(0, True)
     ec.set_child_up(1, True)
+
+
+# -- the write wave in two parts (ISSUE 25): a systematic write sends its
+# data fragments while the codec computes parity; to the transaction the
+# two parts are ONE wave ------------------------------------------------
+
+
+@pytest.fixture
+def sysvol(tmp_path):
+    g = Graph.construct(ec_volfile(
+        tmp_path, N, R, brick_layers=BRICK_LAYERS,
+        options={"eager-lock-timeout": 30, "systematic": "on"}))
+    c = SyncClient(g)
+    c.mount()
+    yield c, g.top, tmp_path
+    c.close()
+
+
+def _spy_writev(ec, log, fail=None):
+    """Every child's writev as it arrives: ``(child, carried the
+    pre-xattrop)`` appended to ``log``; children in ``fail`` (child ->
+    errno) refuse theirs."""
+    from glusterfs_tpu.core.fops import FopError
+
+    for i, ch in enumerate(ec.children):
+        async def writev(fd, data, offset, xdata=None, _i=i,
+                         _real=ch.writev):
+            log.append((_i, "pre-xattrop" in (xdata or {})))
+            if fail and _i in fail:
+                raise FopError(fail[_i], f"brick {_i} refuses")
+            return await _real(fd, data, offset, xdata)
+
+        ch.writev = writev
+
+
+def _hold_codec(ec, gate, error=None):
+    """The flush says its launch is out, and its answer waits for
+    ``gate`` (and then raises ``error``, if given): the parity future
+    of a write, held."""
+    real = ec.codec.encode_async
+
+    async def held(buf, origin="serve", launched=None):
+        if launched is not None:
+            launched.set_result(None)
+        await gate.wait()
+        if error is not None:
+            raise error
+        return await real(buf, origin=origin)
+
+    ec.codec.encode_async = held
+
+
+async def _until(cond, what):
+    for _ in range(400):
+        if cond():
+            return
+        await asyncio.sleep(0.005)
+    raise AssertionError(f"never happened: {what}")
+
+
+def _any_k_agree(c, ec, path, allowed):
+    """Every choice of lost bricks tried reads the same bytes, one of
+    ``allowed``."""
+    seen = set()
+    for drop in ((4, 5), (0, 1), (2, 4), (1, 5)):
+        for i in drop:
+            ec.set_child_up(i, False)
+        seen.add(c.read_file(path))
+        for i in drop:
+            ec.set_child_up(i, True)
+    assert len(seen) == 1, "bricks diverge"
+    assert seen.pop() in allowed
+
+
+def test_first_write_carries_the_preop_on_both_parts(sysvol):
+    """A window's first write: every brick sees pre-xattrop dirty+1 on
+    its own call, data part and parity part alike, and ``pre_landed``
+    waits for the sixth answer; the next write of the window carries
+    none."""
+    c, ec, base = sysvol
+    data = _rand(2 * STRIPE, seed=30).tobytes()
+    log: list = []
+
+    async def drive():
+        gate = asyncio.Event()
+        f = await c._client.create("/pre")
+        _spy_writev(ec, log)
+        _hold_codec(ec, gate)
+        w = asyncio.ensure_future(ec.writev(f.fd, data, 0))
+        await _until(lambda: len(log) == K, "the data part")
+        await asyncio.sleep(0.02)
+        st = ec._eager[f.fd.gfid]
+        assert log == [(i, True) for i in range(K)]
+        assert not w.done() and st.inflight == 1
+        assert not st.pre_landed.is_set(), \
+            "pre_landed before the parity bricks answered"
+        gate.set()
+        await w
+        assert sorted(log) == [(i, True) for i in range(N)]
+        assert st.pre_landed.is_set() and st.inflight == 0
+        assert st.good == set(range(N)) and st.delta == 1
+        del log[:]
+        await ec.writev(f.fd, data, 2 * STRIPE)
+        assert sorted(log) == [(i, False) for i in range(N)]
+        assert st.delta == 2
+        await f.close()
+
+    c._run(drive())
+    assert ec.dump_private()["write_path"]["split"] == 2
+    assert c.read_file("/pre") == data * 2
+    info = c._run(ec.heal_info(Loc("/pre")))
+    assert info["bad"] == [] and not info["dirty"]
+
+
+@pytest.mark.parametrize("how", ["codec-raises", "cancelled"])
+def test_torn_between_the_parts_poisons_the_whole_wave(sysvol, how):
+    """The data part is on the bricks and the parity never comes (the
+    codec fails, or the write is cancelled between the parts): the
+    caller gets EIO (or its cancel), the wave's whole target set leaves
+    the good set, nothing is committed, dirty stays, and heal
+    reconverges the file."""
+    from glusterfs_tpu.core.fops import FopError
+    import errno
+
+    c, ec, base = sysvol
+    old = _rand(2 * STRIPE, seed=31).tobytes()
+    new = _rand(2 * STRIPE, seed=32).tobytes()
+    c.write_file("/torn", old)
+    log: list = []
+
+    async def drive():
+        f = await c._client.open("/torn")
+        await f.fsync()  # the baseline's post-op is committed
+        gate = asyncio.Event()
+        _spy_writev(ec, log)
+        real = ec.codec.encode_async
+        _hold_codec(ec, gate, RuntimeError("device lost")
+                    if how == "codec-raises" else None)
+        w = asyncio.ensure_future(ec.writev(f.fd, new, 0))
+        await _until(lambda: len(log) == K, "the data part")
+        await asyncio.sleep(0.02)
+        if how == "codec-raises":
+            gate.set()
+            with pytest.raises(FopError) as ei:
+                await w
+            assert ei.value.err == errno.EIO
+        else:
+            w.cancel()
+            with pytest.raises(asyncio.CancelledError):
+                await w
+        assert sorted(i for i, _ in log) == list(range(K)), \
+            "a parity brick was written without its parity"
+        st = ec._eager[f.fd.gfid]
+        assert st.good == set() and st.delta == 0 and st.inflight == 0
+        ec.codec.encode_async = real
+        await f.close()
+
+    c._run(drive())
+    info = c._run(ec.heal_info(Loc("/torn")))
+    assert info["dirty"], "a torn wave released dirty"
+    assert all(_index_entries(base, i) for i in range(K))
+    report = c._run(crawl_once(c._client))
+    assert [h["path"] for h in report["healed"]] == ["/torn"]
+    for i in range(N):
+        assert _index_entries(base, i) == []
+    _any_k_agree(c, ec, "/torn", (old, new))
+    info = c._run(ec.heal_info(Loc("/torn")))
+    assert info["bad"] == [] and not info["dirty"]
+
+
+@pytest.mark.parametrize("fail, lost", [
+    ({1: 28, 4: 28}, False),          # ENOSPC on a data and a parity brick
+    ({1: 122, 2: 122, 4: 122}, True),  # EDQUOT on three: below quorum
+], ids=["two-fail", "quorum-lost"])
+@pytest.mark.parametrize("systematic", ["on", "off"])
+def test_brick_failures_judged_once_over_both_parts(tmp_path, systematic,
+                                                    fail, lost):
+    """A data brick failing in the first part and a parity brick in
+    the second give the good set, the quorum verdict and the errno that
+    the one wave (``systematic off``) gives."""
+    from glusterfs_tpu.core.fops import FopError
+
+    g = Graph.construct(ec_volfile(
+        tmp_path, N, R, brick_layers=BRICK_LAYERS,
+        options={"eager-lock-timeout": 30, "systematic": systematic}))
+    c = SyncClient(g)
+    c.mount()
+    ec = g.top
+    data = _rand(2 * STRIPE, seed=33).tobytes()
+    log: list = []
+
+    async def drive():
+        f = await c._client.create("/ef")
+        _spy_writev(ec, log, fail)
+        gate = asyncio.Event()
+        gate.set()  # nothing held: the codec answers in its own time
+        _hold_codec(ec, gate)
+        try:
+            await ec.writev(f.fd, data, 0)
+            err = None
+        except FopError as e:
+            err = e.err
+        st = ec._eager[f.fd.gfid]
+        out = (err, set(st.good), st.delta, st.pre_landed.is_set())
+        await f.close()
+        return out
+
+    try:
+        err, good, delta, landed = c._run(drive())
+    finally:
+        c.close()
+    assert sorted(i for i, _ in log) == list(range(N))
+    assert all(pre for _i, pre in log)
+    assert good == set(range(N)) - set(fail)
+    if lost:
+        assert err == 122 and delta == 0 and not landed
+    else:
+        assert err is None and delta == 1 and landed
+    assert ec.write_path["split"] == (1 if systematic == "on" else 0)

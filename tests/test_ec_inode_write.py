@@ -4,6 +4,7 @@ ec-inode-write.c (ec_fallocate/ec_discard/ec_zerofill), ec-inode-read.c
 (ec_seek).  Zero stripes encode to zero fragments (linear code), so
 holes line up across user space and fragments."""
 
+import asyncio
 import os
 
 import numpy as np
@@ -14,6 +15,7 @@ from glusterfs_tpu.core.fops import FopError
 from glusterfs_tpu.core.graph import Graph
 from glusterfs_tpu.core.layer import Loc
 from glusterfs_tpu.utils.volspec import ec_volfile
+from tests.test_systematic import _hold_answer, _spy
 
 K, R = 4, 2
 N = K + R
@@ -24,9 +26,12 @@ def _rand(n, seed=0):
     return np.random.default_rng(seed).integers(0, 256, n, dtype=np.uint8)
 
 
-@pytest.fixture
-def vol(tmp_path):
-    g = Graph.construct(ec_volfile(tmp_path, N, R))
+@pytest.fixture(params=["off", "on"], ids=["reference", "systematic"])
+def vol(tmp_path, request):
+    """Both layouts: on the systematic one every wave of the window
+    write path goes out in two parts around the codec (ISSUE 25)."""
+    g = Graph.construct(ec_volfile(
+        tmp_path, N, R, options={"systematic": request.param}))
     c = SyncClient(g)
     c.mount()
     yield c, g.top, tmp_path
@@ -181,5 +186,42 @@ def test_afr_allocation_fops_replicate(tmp_path):
             assert (tmp_path / f"brick{i}" / "r").read_bytes() == want, i
         info = c._run(afr.heal_info(Loc("/r")))
         assert info["bad"] == [] and not info["dirty"]
+    finally:
+        c.close()
+
+
+def test_unaligned_write_splits_after_its_rmw_read(tmp_path):
+    """An EOF-crossing unaligned write on a systematic volume reads the
+    stripes it overlaps first (full RMW: the parity-delta path does not
+    take it), and then sends the whole buffer's wave in two parts."""
+    g = Graph.construct(ec_volfile(tmp_path, N, R,
+                                   options={"systematic": "on"}))
+    c = SyncClient(g)
+    c.mount()
+    ec = g.top
+    try:
+        data = _rand(2 * STRIPE + 1024, seed=9).tobytes()
+        c.write_file("/u", data)
+        before = dict(ec.write_path)
+        log = []
+        _spy(ec, log, ops=("readv", "writev"))
+        gate = asyncio.Event()
+        gate.set()
+        _hold_answer(ec, gate, delay=0.02)  # an answer that takes time
+        patch = _rand(3000, seed=10).tobytes()
+        f = c.open("/u")
+        f.write(patch, 2 * STRIPE + 512)
+        f.close()
+        assert ec.write_path["rmw"] == before["rmw"] + 1
+        assert ec.write_path["split"] == before["split"] + 1
+        assert ec.write_path["delta"] == before["delta"]
+        ops = [op for op, _i in log]
+        assert "readv" in ops and ops.count("writev") == N
+        assert ops.index("writev") > max(
+            j for j, op in enumerate(ops) if op == "readv"), log
+        assert [i for op, i in log if op == "writev"][:K] == list(range(K))
+        exp = data[: 2 * STRIPE + 512] + patch
+        assert c.read_file("/u") == exp
+        assert c.stat("/u").size == len(exp)
     finally:
         c.close()

@@ -846,7 +846,16 @@ def test_one_mib_through_4p2_yields_every_phase_once(tmp_path, recorder,
                 recorder.log.clear()
                 await f.write(data, 1 << 20)
                 got = _names(recorder.log, skip)
-                assert got == held(fop, WRITE_PHASES), got
+                # a systematic write behind the batcher: two fan-outs,
+                # the data part beside the codec wait (ISSUE 25)
+                assert got == held(fop, WRITE_PHASES) | {
+                    "gftpu:ec.fanout": 2}, got
+                parts = [(m["part"], m["parent"]) for n, m, _t
+                         in recorder.log if n == "gftpu:ec.fanout"]
+                wait, = [m for n, m, _t in recorder.log
+                         if n == "gftpu:ec.codec_wait"]
+                assert [p for p, _ in parts] == ["data", "parity"]
+                assert {par for _, par in parts} == {wait["parent"]}
                 # the fop under which the window closes (max-hold
                 # reached): the post-op rides its ec.unlock
                 ec.opts["eager-lock-max-hold"], hold = \
@@ -856,7 +865,7 @@ def test_one_mib_through_4p2_yields_every_phase_once(tmp_path, recorder,
                 ec.opts["eager-lock-max-hold"] = hold
                 last = _names(recorder.log, skip)
                 assert last == held(fop, WRITE_PHASES | {
-                    "ec.unlock", "ec.xattrop"}) | {"gftpu:ec.fanout": 2}, \
+                    "ec.unlock", "ec.xattrop"}) | {"gftpu:ec.fanout": 3}, \
                     last
                 unlock, = [m for n, m, _t in recorder.log
                            if n == "gftpu:ec.unlock"]

@@ -501,7 +501,8 @@ def test_ec_traffic_origin_default_and_rebalance_tag(tmp_path):
             seen = []
 
             class StubCodec:
-                async def encode_async(self, buf, origin="serve"):
+                async def encode_async(self, buf, origin="serve",
+                                       launched=None):
                     seen.append(origin)
                     return buf  # the plumb is under test, not the math
 

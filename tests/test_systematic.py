@@ -329,3 +329,176 @@ def test_systematic_heal_rebuilds_reference_bytes(tmp_path):
     oracle = gf256.ref_encode(data2, K, N, systematic=True)
     frag = open(os.path.join(str(tmp_path), "brick2", "z"), "rb").read()
     assert frag == oracle[2].tobytes()
+
+
+# -- the write wave in two parts (ISSUE 25) ----------------------------
+
+
+def _spy(ec, log, ops=("writev",)):
+    """``(op, child)`` of every child call of ``ops`` as it arrives."""
+    for i, ch in enumerate(ec.children):
+        for op in ops:
+            def make(real, _i=i, _op=op):
+                async def call(*a, **kw):
+                    log.append((_op, _i))
+                    return await real(*a, **kw)
+                return call
+
+            setattr(ch, op, make(getattr(ch, op)))
+
+
+def _hold_answer(ec, gate, delay=0.0):
+    """The codec computes as it does (its flush is submitted, its
+    launch told), and its answer then waits ``delay`` seconds and for
+    ``gate``: the parity of a write, held."""
+    import asyncio
+
+    real = ec.codec.encode_async
+
+    async def held(buf, origin="serve", launched=None):
+        out = await real(buf, origin=origin, launched=launched)
+        await asyncio.sleep(delay)
+        await gate.wait()
+        return out
+
+    ec.codec.encode_async = held
+
+
+def _brick_files_are_the_oracle(tmp_path, name, data):
+    import os
+
+    oracle = gf256.ref_encode(np.frombuffer(data, dtype=np.uint8), K, N,
+                              systematic=True)
+    for i in range(N):
+        frag = open(os.path.join(str(tmp_path), f"brick{i}", name),
+                    "rb").read()
+        assert frag == oracle[i].tobytes(), f"brick {i}"
+
+
+def test_data_fragments_go_out_while_parity_is_held(tmp_path):
+    """With the codec's answer held, the k data children have each
+    received their writev and the parity children none; released, all
+    six have it and the brick files are the reference encoding.  The
+    flush was handed to the batcher's pool before the first data call
+    was made."""
+    import asyncio
+
+    c, ec = _mount(tmp_path)
+    data = _rand(4 * STRIPE, seed=41).tobytes()
+    log: list = []
+    try:
+        async def drive():
+            gate = asyncio.Event()
+            f = await c._client.create("/a")
+            _spy(ec, log)
+            submit = ec.codec._submit
+
+            def submitted(*a):
+                log.append(("flush", -1))
+                return submit(*a)
+
+            ec.codec._submit = submitted
+            _hold_answer(ec, gate)
+            w = asyncio.ensure_future(ec.writev(f.fd, data, 0))
+            for _ in range(400):
+                if len(log) > K:
+                    break
+                await asyncio.sleep(0.005)
+            await asyncio.sleep(0.02)
+            assert log == [("flush", -1)] + [("writev", i)
+                                             for i in range(K)], log
+            assert not w.done()
+            gate.set()
+            await w
+            assert sorted(log[K + 1:]) == [("writev", i)
+                                           for i in range(K, N)]
+            await f.close()
+
+        c._run(drive())
+        assert ec.dump_private()["write_path"] == {
+            "delta": 0, "rmw": 0, "split": 1}
+        assert c.read_file("/a") == data
+    finally:
+        c.close()
+    _brick_files_are_the_oracle(tmp_path, "a", data)
+
+
+@pytest.mark.parametrize("options", [
+    {"systematic": "off"},
+    {"systematic": "on", "stripe-cache": "off"},
+], ids=["non-systematic", "stripe-cache-off"])
+def test_one_wave_where_nothing_can_overlap(tmp_path, options):
+    """Every fragment a codeword, or a codec that holds the loop: the
+    six calls follow the encode as one wave, and nothing counts as
+    split."""
+    g = Graph.construct(ec_volfile(tmp_path, N, R, options=options))
+    c = SyncClient(g)
+    c.mount()
+    ec = g.top
+    data = _rand(4 * STRIPE, seed=42).tobytes()
+    log: list = []
+    try:
+        async def drive():
+            f = await c._client.create("/one")
+            _spy(ec, log)
+            encode = ec._codec_encode
+
+            async def encoded(buf, origin=None):
+                out = await encode(buf, origin)
+                log.append(("encoded", -1))
+                return out
+
+            ec._codec_encode = encoded
+            await ec.writev(f.fd, data, 0)
+            await f.close()
+
+        c._run(drive())
+        assert log == [("encoded", -1)] + [("writev", i)
+                                           for i in range(N)], log
+        assert ec.dump_private()["write_path"]["split"] == 0
+        assert c.read_file("/one") == data
+    finally:
+        c.close()
+
+
+def test_parallel_writes_on_disjoint_ranges_both_split(tmp_path):
+    """Two parallel-writes waves in flight at once, each in two parts:
+    the parity of both is held until all eight data calls are in, and
+    the bytes on the bricks are exact."""
+    import asyncio
+
+    c, ec = _mount(tmp_path)
+    a, b, head = (_rand(2 * STRIPE, seed=s).tobytes() for s in (43, 44, 45))
+    log: list = []
+    try:
+        async def drive():
+            gate = asyncio.Event()
+            _hold_answer(ec, gate, delay=0.01)  # an answer takes time
+            f = await c._client.create("/pw")
+            gate.set()
+            await ec.writev(f.fd, head, 0)  # the pre-op has landed
+            gate.clear()
+            _spy(ec, log)
+            ws = [asyncio.ensure_future(ec.writev(f.fd, a, 2 * STRIPE)),
+                  asyncio.ensure_future(ec.writev(f.fd, b, 6 * STRIPE))]
+            for _ in range(400):
+                if len(log) >= 2 * K:
+                    break
+                await asyncio.sleep(0.005)
+            await asyncio.sleep(0.02)
+            st = ec._eager[f.fd.gfid]
+            assert sorted(log) == sorted([("writev", i) for i in range(K)]
+                                         * 2), log
+            assert st.inflight == 2 and len(st.ranges) == 2
+            gate.set()
+            await asyncio.gather(*ws)
+            assert st.good == set(range(N)) and st.delta == 3
+            await f.close()
+
+        c._run(drive())
+        assert ec.dump_private()["write_path"]["split"] == 3
+        want = head + a + b"\0" * (2 * STRIPE) + b
+        assert c.read_file("/pw") == want
+    finally:
+        c.close()
+    _brick_files_are_the_oracle(tmp_path, "pw", want)
