@@ -39,7 +39,7 @@ from ..core.fops import FopError
 from ..core.iatt import IAType, gfid_new
 from ..core.layer import FdObj, Layer, Loc, register
 from ..core.options import Option
-from ..core import gflog
+from ..core import gflog, tracing
 
 log = gflog.get_logger("dht")
 
@@ -166,6 +166,8 @@ class DistributeLayer(Layer):
         # live defrag status (gf_defrag_info analog), published by
         # rebalance() and polled by glusterd's drain for status ops
         self.rebal_status: dict = {"state": "not started"}
+        #: data fops (readv, writev, xorv) handed to each child
+        self.routed = [0] * self.n
         self._recompute_active()
 
     def _recompute_active(self) -> None:
@@ -840,6 +842,16 @@ class DistributeLayer(Layer):
 
     # -- data fops (forward to cached subvol) ------------------------------
 
+    async def _routed_fd(self, fd: FdObj) -> tuple[int, FdObj]:
+        """``_fd_target`` for a data fop (``readv``, ``writev``,
+        ``xorv``): counted per subvolume, and named on the fop's span,
+        so that a statedump and a trace both say how the load fell on
+        the children (under distribute-over-disperse: on which codec)."""
+        i, cfd = await self._fd_target(fd)
+        self.routed[i] += 1
+        tracing.tag(subvol=self.children[i].name)
+        return i, cfd
+
     async def _fd_target(self, fd: FdObj) -> tuple[int, FdObj]:
         ctx: DhtFdCtx | None = fd.ctx_get(self)
         if ctx is not None:
@@ -856,19 +868,19 @@ class DistributeLayer(Layer):
 
     async def readv(self, fd: FdObj, size: int, offset: int,
                     xdata: dict | None = None):
-        i, cfd = await self._fd_target(fd)
+        i, cfd = await self._routed_fd(fd)
         return await self.children[i].readv(cfd, size, offset, xdata)
 
     async def writev(self, fd: FdObj, data, offset: int,
                      xdata: dict | None = None):
-        i, cfd = await self._fd_target(fd)
+        i, cfd = await self._routed_fd(fd)
         return await self.children[i].writev(cfd, data, offset, xdata)
 
     async def xorv(self, fd: FdObj, data, offset: int,
                    xdata: dict | None = None):
         # routed like writev (fd-addressed data fop): the base-class
         # first-child default would land the delta on the wrong subvol
-        i, cfd = await self._fd_target(fd)
+        i, cfd = await self._routed_fd(fd)
         return await self.children[i].xorv(cfd, data, offset, xdata)
 
     async def flush(self, fd: FdObj, xdata: dict | None = None):
@@ -1439,4 +1451,6 @@ class DistributeLayer(Layer):
         return {"subvolumes": self.n,
                 "layout": [{"subvol": c.name,
                             "range": ranges.get(i, "decommissioned")}
-                           for i, c in enumerate(self.children)]}
+                           for i, c in enumerate(self.children)],
+                "routed": {c.name: self.routed[i]
+                           for i, c in enumerate(self.children)}}

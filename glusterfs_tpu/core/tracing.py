@@ -84,7 +84,9 @@ SLOW_FOP_THRESHOLD = 0.0
 #: a codec is built on a jax backend.  Such an annotation records its
 #: own start and end (it is closed by whichever thread ends the span,
 #: and interleaved tasks need no nesting); while no profiler session
-#: runs, ``is_enabled()`` is one C call and no annotation is made
+#: runs, ``is_enabled()`` is one C call and no annotation is made.
+#: An open one takes more metadata through ``set_metadata(**kw)``
+#: (:func:`tag`)
 ANNOTATE = None
 
 _RING_DEFAULT = 4096
@@ -97,7 +99,8 @@ _RING_DEFAULT = 4096
 SPANS: collections.deque = collections.deque(maxlen=_RING_DEFAULT)
 
 #: (trace_id, depth, span_id, layer) of the span currently open in
-#: this context
+#: this context, and fifth, where the span itself opened it, its
+#: profiler annotation or ``None`` (:func:`tag`)
 CURRENT: contextvars.ContextVar = contextvars.ContextVar(
     "gftpu_trace", default=None)
 
@@ -145,7 +148,7 @@ def arm(trace_id: str) -> None:
     """Adopt a wire-carried trace id for the rest of this context (the
     protocol/server re-arm: brick-graph spans join the client's trace
     instead of minting their own)."""
-    CURRENT.set((str(trace_id), 0, 0, ""))
+    CURRENT.set((str(trace_id), 0, 0, "", None))
 
 
 def enter(layer_name: str, op: str, annot: str | None = None,
@@ -165,14 +168,25 @@ def enter(layer_name: str, op: str, annot: str | None = None,
     else:
         tid, depth, pid, root = cur[0], cur[1] + 1, cur[2], False
     sid = next(_IDS)
-    tok = CURRENT.set((tid, depth, sid, layer_name)) if push else None
     ann = None
     if ANNOTATE is not None and ANNOTATE.is_enabled():
         ann = ANNOTATE(annot or _phase_annot(layer_name, op), trace=tid,
                        span=sid, parent=pid, **(meta or {}))
         ann.__enter__()
+    tok = CURRENT.set((tid, depth, sid, layer_name, ann)) if push else None
     return (tid, depth, root, tok, layer_name, op,
             time.perf_counter_ns(), sid, pid, ann)
+
+
+def tag(**meta) -> None:
+    """Metadata that a fop's code learns after its span has begun
+    (cluster/dht: the subvolume the fop is routed to), put on the span
+    open in this context.  Only sink three carries metadata, so this
+    reaches the profiler's annotation and nothing else; while no
+    profiler runs it is one context read."""
+    cur = CURRENT.get()
+    if cur is not None and cur[4] is not None:
+        cur[4].set_metadata(**meta)
 
 
 def _phase_annot(layer_name: str, name: str) -> str:
@@ -356,6 +370,6 @@ def render_tree(trace_id: str) -> str:
 
 __all__ = ["ENABLED", "SLOW_FOP_THRESHOLD", "SLOW_FOP_COUNTS", "SPANS",
            "CURRENT", "ANNOTATE", "adopt", "arm",
-           "enter", "exit_span", "phase", "phase_sums", "current_id",
+           "enter", "exit_span", "phase", "phase_sums", "tag", "current_id",
            "new_trace_id", "recent_spans", "render_tree",
            "set_ring_size", "spans_for"]

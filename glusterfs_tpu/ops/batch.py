@@ -175,7 +175,9 @@ class BatchingCodec(Codec):
     ``launches`` counts device batch launches (sync calls included),
     ``cpu_launches`` counts flushes routed to the CPU ladder,
     ``batched_fops`` total fops served, ``max_batch`` the largest
-    coalesced batch in fops.
+    coalesced batch in fops, ``stripes`` the stripes coded for fops and
+    ``padded_stripes`` the stripes launched for them (on the device and
+    mesh routes the power-of-two bucket, so the zero padding with it).
 
     ``min_batch`` is a hard floor below which flushes never go to the
     device; ``min_batch=0`` disables routing entirely (every flush takes
@@ -218,6 +220,10 @@ class BatchingCodec(Codec):
         self.cpu_launches = 0
         self.batched_fops = 0
         self.max_batch = 0
+        # over every flush: the stripes coded for fops, and the stripes
+        # launched (the zero padding up to the bucket included)
+        self.stripes = 0
+        self.padded_stripes = 0
         # two workers: batch N's device round trip overlaps batch N+1's
         # dispatch/host work (jax serializes on-device execution itself)
         # sink one of this codec's phases (core/tracing.py): rows per
@@ -588,14 +594,43 @@ class BatchingCodec(Codec):
                 off += n
             return results
 
+    def _launch_stripes(self, kind: str, total: int) -> tuple[int, int]:
+        """(stripes coded for fops, stripes launched) of a flush of
+        ``total`` bytes: the device and mesh routes pad to the bucket."""
+        s = total // self.stripe_size
+        return s, s if kind == "cpu" else _bucket_stripes(s)
+
+    def _count_flush(self, batch, decode: bool = False):
+        """A batch leaves its queue, on the loop: route it and count it
+        -> ``(codec, kind, total bytes)``."""
+        self._last_flush = time.monotonic()
+        total = sum(d.size for d, *_ in batch)
+        codec, kind = self._route(total)
+        if kind == "mesh" and decode and self.systematic:
+            # the systematic mesh tier is encode-only (parity-rows
+            # sharded launch): a degraded decode reconstructs
+            # missing data rows on the single-device ladder
+            codec, kind = self, "device"
+        if kind == "cpu" and codec is not self:
+            self.cpu_launches += 1
+        self.flushes += 1
+        self.batched_fops += len(batch)
+        self.max_batch = max(self.max_batch, len(batch))
+        coded, launched = self._launch_stripes(kind, total)
+        self.stripes += coded
+        self.padded_stripes += launched
+        return codec, kind, total
+
     def _flush_phase(self, op: str, batch, kind: str, total: int):
         """Start of a flush, on the pool thread: every fop's
         ``codec.queue`` ends, and ``codec.flush`` opens under the span
         the first fop waits in, naming the other waiters' spans."""
         for *_, q in batch:
             q.stop()
+        stripes, bucket = self._launch_stripes(kind, total)
         meta = {"op": op, "route": kind, "fops": len(batch),
-                "bytes": total}
+                "bytes": total, "stripes": stripes,
+                "bucket_stripes": bucket}
         others = [str(q.origin[2]) for *_, q in batch[1:] if q.origin]
         if others:
             meta["others"] = ",".join(others)
@@ -671,14 +706,7 @@ class BatchingCodec(Codec):
         batch, self._enc_q = self._enc_q, []
         if not batch:
             return
-        self._last_flush = time.monotonic()
-        self.flushes += 1
-        self.batched_fops += len(batch)
-        self.max_batch = max(self.max_batch, len(batch))
-        total = sum(d.size for d, *_ in batch)
-        codec, kind = self._route(total)
-        if kind == "cpu" and codec is not self:
-            self.cpu_launches += 1
+        codec, kind, total = self._count_flush(batch)
         loop = asyncio.get_running_loop()
         self._submit(self._run_encode, loop, batch, codec, kind, total)
 
@@ -780,14 +808,7 @@ class BatchingCodec(Codec):
         batch, self._delta_q = self._delta_q, []
         if not batch:
             return
-        self._last_flush = time.monotonic()
-        self.flushes += 1
-        self.batched_fops += len(batch)
-        self.max_batch = max(self.max_batch, len(batch))
-        total = sum(d.size for d, *_ in batch)
-        codec, kind = self._route(total)
-        if kind == "cpu" and codec is not self:
-            self.cpu_launches += 1
+        codec, kind, total = self._count_flush(batch)
         loop = asyncio.get_running_loop()
         self._submit(self._run_delta, loop, batch, codec, kind, total)
 
@@ -845,21 +866,9 @@ class BatchingCodec(Codec):
         queues, self._dec_q = self._dec_q, {}
         if not queues:
             return
-        self._last_flush = time.monotonic()
         loop = asyncio.get_running_loop()
         for rows, batch in queues.items():
-            self.flushes += 1
-            self.batched_fops += len(batch)
-            self.max_batch = max(self.max_batch, len(batch))
-            total = sum(f.size for f, *_ in batch)
-            codec, kind = self._route(total)
-            if kind == "mesh" and self.systematic:
-                # the systematic mesh tier is encode-only (parity-rows
-                # sharded launch): a degraded decode reconstructs
-                # missing data rows on the single-device ladder
-                codec, kind = self, "device"
-            if kind == "cpu" and codec is not self:
-                self.cpu_launches += 1
+            codec, kind, total = self._count_flush(batch, decode=True)
             self._submit(self._run_decode, loop, rows, batch, codec,
                          kind, total)
 
@@ -914,6 +923,8 @@ class BatchingCodec(Codec):
             "cpu_launches": self.cpu_launches,
             "batched_fops": self.batched_fops,
             "max_batch": self.max_batch,
+            "stripes": self.stripes,
+            "padded_stripes": self.padded_stripes,
             "window_s": self.window,
             "min_batch_bytes": self.min_batch,
             "calibration": cal,
