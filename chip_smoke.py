@@ -62,8 +62,7 @@ FULL = {
     "kernel_big_mib": 64,
     "geometries": ((4, 2), (8, 3), (8, 4), (16, 4)),  # BASELINE sweep
     # what else cpu-extensions can select on a chip host, once at 4+2:
-    # pallas-mxu, the transpose-sandwich xor / xor3, xla, xla-xor
-    "pallas_forms": ("mxu", "xor", "xor3"),
+    # xla, xla-xor
     "xla_forms": ("matmul", "xor"),
 }
 
@@ -278,13 +277,13 @@ class Smoke:
                     frs = gf256.ref_encode(data, k, n, systematic=True)
                 tag = f"{k}+{r}/{label}"
                 check(f"{tag}/fused-encode",
-                      lambda: gf256_pallas.encode(data, k, n, "fused", interp),
+                      lambda: gf256_pallas.encode(data, k, n, interp),
                       fr)
                 for rows in (tuple(range(r, n)),
                              (0,) + tuple(range(2, k + 1))):
                     check(f"{tag}/fused-decode{list(rows)}",
                           lambda: gf256_pallas.decode(
-                              fr[list(rows)], rows, k, "fused", interp),
+                              fr[list(rows)], rows, k, interp),
                           data)
                 check(f"{tag}/parity",
                       lambda: gf256_pallas.parity(data, k, n, interp),
@@ -302,13 +301,13 @@ class Smoke:
             data = g[f"in_{k}_{r}"]
             frags = np.stack([g[f"frag_{k}_{r}_{i}"] for i in range(n)])
             check(f"golden/{k}+{r}/encode",
-                  lambda: gf256_pallas.encode(data, k, n, "fused", interp),
+                  lambda: gf256_pallas.encode(data, k, n, interp),
                   frags)
             for which in (0, 1):
                 rows = tuple(int(x) for x in g[f"decmask_{k}_{r}_{which}"])
                 check(f"golden/{k}+{r}/decode{which}",
                       lambda: gf256_pallas.decode(
-                          frags[list(rows)], rows, k, "fused", interp),
+                          frags[list(rows)], rows, k, interp),
                       data)
         # everything else cpu-extensions can select on a chip host, 4+2
         k, r, n = K, R, K + R
@@ -318,13 +317,6 @@ class Smoke:
                 0, 256, stripes * k * 512, dtype=np.uint8)
             fr = native.encode(data, k, n, enc_bits(k, n))
             par = native.encode(data, k, n, sys_bits(k, n))[k:]
-            for form in self.sizes["pallas_forms"]:
-                check(f"4+2/{label}/pallas-{form}-encode",
-                      lambda: gf256_pallas.encode(data, k, n, form, interp),
-                      fr)
-                check(f"4+2/{label}/pallas-{form}-decode",
-                      lambda: gf256_pallas.decode(
-                          fr[list(rows)], rows, k, form, interp), data)
             for form in self.sizes["xla_forms"]:
                 check(f"4+2/{label}/xla-{form}-encode",
                       lambda: gf256_xla.encode(data, k, n, form), fr)

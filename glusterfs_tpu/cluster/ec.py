@@ -190,7 +190,7 @@ class DisperseLayer(Layer):
         Option("redundancy", "int", default=2, min=1, max=8),
         Option("cpu-extensions", "enum", default="auto",
                values=("auto", "ref", "native", "xla", "xla-xor",
-                       "pallas-xor", "pallas-mxu", "mesh"),
+                       "pallas-xor", "mesh"),
                description="codec backend (reference disperse.cpu-extensions"
                            " {none,auto,x64,sse,avx} -> TPU ladder; mesh ="
                            " multi-chip sharded data plane)"),

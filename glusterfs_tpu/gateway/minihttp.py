@@ -1,9 +1,9 @@
 """Minimal HTTP/1.1 client for driving the gateway.
 
-One copy shared by tests/test_gateway.py, bench.py's concurrency
-ladder, and the ci.sh smoke stage — a dialect change (headers, chunked
-bodies, HEAD semantics) lands everywhere at once instead of drifting
-across three hand-rolled parsers.  Deliberately tiny: no redirects, no
+One copy shared by the tests (tests/test_gateway.py and others),
+tools/chaos.py and the ci.sh smoke stages — a dialect change (headers,
+chunked bodies, HEAD semantics) lands everywhere at once instead of
+drifting across hand-rolled parsers.  Deliberately tiny: no redirects, no
 TLS, no response streaming — exactly what driving the gateway needs.
 """
 

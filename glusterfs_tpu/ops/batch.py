@@ -5,8 +5,11 @@ The reference amortizes per-write stripe work with a stripe-cache
 TPU analog — and the north star's "stripe fragments from concurrent fops
 coalesced into HBM-resident batches" — is a batching window:
 
-* concurrent ``encode_async``/``decode_async`` calls within one event-loop
-  tick (plus ``window`` seconds) queue into a pending list;
+* concurrent ``encode_async`` / ``encode_delta_async`` / ``decode_async``
+  calls within one event-loop tick (plus ``window`` seconds) queue into
+  the pending list of their lane (:class:`_Lane`: the three ops differ
+  by a few values, and the queue, the blow-up guard, the timer, the
+  flush and the pool run are one code for all of them);
 * one flush concatenates the queued stripe-aligned payloads and makes ONE
   kernel launch for the whole batch (encode; decodes group by surviving
   mask — one launch per mask, the same ``(k, rows)`` keying as the
@@ -88,7 +91,7 @@ from .codec import Codec
 
 _log = _gflog.get_logger("ec")
 
-_DEVICE_BACKENDS = ("pallas-xor", "pallas-mxu", "xla", "xla-xor", "mesh")
+_DEVICE_BACKENDS = ("pallas-xor", "xla", "xla-xor", "mesh")
 
 #: live BatchingCodecs, scraped (not owned) by the unified registry —
 #: the mesh data-plane families (ISSUE 8): launches prove coalesced
@@ -165,6 +168,41 @@ class _PathModel:
         return self.overhead + nbytes / self.rate if self.ready else float("inf")
 
 
+class _Lane:
+    """One op of the batcher, as data: what ``encode``, ``delta`` and
+    ``decode`` do not share.  ``op`` names the lane on spans and
+    counters.  ``entry`` names the codec's entry point that codes a
+    flush, looked up on the instance WHEN THE FLUSH RUNS: the
+    batcher's own (counted) one on the device route, the small codec's
+    on the CPU ladder (benchmarks/control.py replaces ``encode`` and
+    ``decode`` on the instance and must be what a flush calls).  A
+    fop's share of a flush's answer is its own size over ``shrink``
+    along the last axis (fragments of bytes: k; bytes of fragments:
+    1).  ``modelled``: its timings feed the router's models (they
+    track full-generator work; parity-only deltas would skew them
+    low).  ``mesh_systematic``: the mesh tier codes it on a systematic
+    volume (it is encode-only there: a degraded decode reconstructs
+    the missing rows on the single-device route).
+
+    Fops queue by ``key``, the entry point's arguments after the data:
+    ``()``, or ``(rows,)`` for a decode, one queue a surviving mask
+    (the keying of the per-mask program LRU, so a flush lands on one
+    cached kernel).  One timer serves every queue of the lane and a
+    flush empties them all.  Loop-side state only."""
+
+    __slots__ = ("op", "entry", "shrink", "modelled", "mesh_systematic",
+                 "queues", "timer")
+
+    def __init__(self, op: str, entry: str, shrink: int,
+                 modelled: bool = True, mesh_systematic: bool = True):
+        self.op, self.entry, self.shrink = op, entry, shrink
+        self.modelled, self.mesh_systematic = modelled, mesh_systematic
+        # key -> [(data, fut, origin, the fop's ``launched`` future or
+        # None, the fop's open codec.queue phase)]
+        self.queues: dict[tuple, list[tuple]] = {}
+        self.timer: asyncio.Task | None = None
+
+
 class BatchingCodec(Codec):
     """Codec with an async batching window for the served data path.
 
@@ -199,17 +237,13 @@ class BatchingCodec(Codec):
         self.window = window
         self.min_batch = min_batch
         self.max_batch_bytes = max_batch_bytes
-        # (data, fut, origin, the fop's ``launched`` future or None,
-        # the fop's open codec.queue phase)
-        self._enc_q: list[tuple] = []
-        self._enc_task: asyncio.Task | None = None
-        self._dec_q: dict[tuple[int, ...], list[tuple]] = {}
-        self._dec_task: asyncio.Task | None = None
-        # parity-delta queue (ISSUE 10): coalesced sub-stripe write
-        # deltas ride the same flush ladder as full encodes — one
-        # parity-rows-only launch per flush
-        self._delta_q: list[tuple] = []
-        self._delta_task: asyncio.Task | None = None
+        # parity deltas (ISSUE 10) ride the same flush ladder as full
+        # encodes: one parity-rows-only launch per flush
+        self._lanes = {
+            "encode": _Lane("encode", "encode", k),
+            "delta": _Lane("delta", "encode_delta", k, modelled=False),
+            "decode": _Lane("decode", "decode", 1, mesh_systematic=False),
+        }
         # lazy small-batch codec; CPU-ladder backends alias self HERE
         # (pre-publication, against self.backend as RESOLVED by the
         # base init) so _small()'s lazy build is the only
@@ -350,7 +384,7 @@ class BatchingCodec(Codec):
             await asyncio.sleep(0.01)
         return self._mesh_state == "ready"
 
-    def _mesh_launch(self, op: str, cat: np.ndarray, rows, batch):
+    def _mesh_launch(self, op: str, cat: np.ndarray, batch, rows=None):
         """ONE pjit'd NamedSharding launch over the (dp, frag) mesh for
         a whole coalesced flush (runs in the pool).  Pads to the stripe
         bucket so the jit cache stays bounded (zero stripes encode to
@@ -553,17 +587,143 @@ class BatchingCodec(Codec):
         pad = np.zeros(cat.shape[:-1] + ((sb - s) * unit,), dtype=np.uint8)
         return np.concatenate([cat, pad], axis=-1)
 
-    # -- the flush's spans (core/tracing.py) -------------------------------
+    # -- one lane: queue, guard, timer, flush, pool run --------------------
 
-    def _enqueue(self, q: list, item, fut, origin: str,
-                 launched=None) -> None:
-        """Queue one fop's work with its ``codec.queue`` phase open:
-        it ends on the pool thread, when the flush starts."""
+    async def _enqueue(self, lane: _Lane, item: np.ndarray, key: tuple,
+                       origin: str, launched=None) -> np.ndarray:
+        """One fop's work joins its lane and waits for its share of the
+        flush's answer.  Its ``codec.queue`` phase opens here and ends
+        on the pool thread, when the flush starts."""
+        fut: asyncio.Future = asyncio.get_running_loop().create_future()
+        q = lane.queues.setdefault(key, [])
         q.append((item, fut, origin, launched,
                   _tracing.phase(self.name, "codec.queue",
                                  self.phases).start(push=False)))
+        if sum(d.size for d, *_ in q) >= self.max_batch_bytes:
+            self._flush(lane)  # blow-up guard: do not wait for the timer
+        elif lane.timer is None:
+            lane.timer = asyncio.ensure_future(self._timer(lane))
+        out, resume = await fut
+        resume.stop()
+        return out
 
-    def _gather(self, batch, kind: str, axis: int = 0) -> np.ndarray:
+    async def _timer(self, lane: _Lane):
+        # window 0 = same-tick coalescing: sleep(0) runs after every
+        # already-scheduled callback, so fops made concurrent in this
+        # loop pass still land in one batch, while a lone sequential
+        # writer pays no idle wait (a fixed window poll costs ~0.3 ms
+        # of epoll timeout per flush on the smallfile path)
+        await asyncio.sleep(self.window)
+        self._flush(lane)
+
+    def _flush(self, lane: _Lane) -> None:
+        """Every queue of the lane leaves for the pool, on the loop:
+        each is routed and counted as one flush."""
+        if lane.timer is not None:
+            lane.timer.cancel()
+            lane.timer = None
+        queues, lane.queues = lane.queues, {}
+        loop = asyncio.get_running_loop()
+        for key, batch in queues.items():
+            self._last_flush = time.monotonic()
+            total = sum(d.size for d, *_ in batch)
+            codec, kind = self._route(total)
+            if kind == "mesh" and self.systematic \
+                    and not lane.mesh_systematic:
+                codec, kind = self, "device"
+            if kind == "cpu" and codec is not self:
+                self.cpu_launches += 1
+            self.flushes += 1
+            self.batched_fops += len(batch)
+            self.max_batch = max(self.max_batch, len(batch))
+            coded, padded = self._launch_stripes(kind, total)
+            self.stripes += coded
+            self.padded_stripes += padded
+            self._submit(self._run, loop, lane, key, batch, codec, kind,
+                         total)
+
+    def _submit(self, fn, loop, *args) -> None:
+        """Pool submit with an inline fallback: a batch still pending in
+        the window when close() shuts the pool (live reconfigure swaps
+        the codec) must NOT strand its awaiting fops — run the flush on
+        the loop thread instead."""
+        try:
+            self._pool.submit(fn, loop, *args)
+        except RuntimeError:  # pool shut down after close()
+            fn(loop, *args)
+
+    def _launch_stripes(self, kind: str, total: int) -> tuple[int, int]:
+        """(stripes coded for fops, stripes launched) of a flush of
+        ``total`` bytes: the device and mesh routes pad to the bucket."""
+        s = total // self.stripe_size
+        return s, s if kind == "cpu" else _bucket_stripes(s)
+
+    def _run(self, loop, lane: _Lane, key: tuple, batch, codec: Codec,
+             kind: str, total: int) -> None:
+        """One flush, in the pool: gather, code, time, scatter, hand
+        back.  The fops that gave a ``launched`` future
+        (:meth:`encode_async`) hear once, at the latest when the flush
+        ends, however it ends."""
+        results = err = None
+        waiting = [w for _d, _f, _o, w, _q in batch if w is not None]
+
+        def tell():
+            if waiting:
+                loop.call_soon_threadsafe(self._tell_launched, waiting[:])
+                waiting.clear()
+
+        try:
+            # every fop's codec.queue ends; codec.flush opens under the
+            # span the first fop waits in, naming the others' spans
+            for *_, q in batch:
+                q.stop()
+            stripes, bucket = self._launch_stripes(kind, total)
+            meta = {"op": lane.op, "route": kind, "fops": len(batch),
+                    "bytes": total, "stripes": stripes,
+                    "bucket_stripes": bucket}
+            others = [str(q.origin[2]) for *_, q in batch[1:] if q.origin]
+            if others:
+                meta["others"] = ",".join(others)
+            with _tracing.phase(self.name, "codec.flush", self.phases,
+                                batch[0][-1].origin, **meta):
+                t0 = time.perf_counter()
+                cat = self._gather(batch, kind)
+                if kind == "device" and waiting:
+                    from . import _device
+
+                    _device.after_launch(tell)
+                else:
+                    tell()
+                if kind == "mesh":
+                    out = self._mesh_launch(lane.op, cat, batch, *key)
+                else:
+                    # ``codec`` is this one on the device route
+                    out = getattr(codec, lane.entry)(cat, *key)
+                    if kind == "device":  # the bucket's zero padding
+                        out = out[..., : total // lane.shrink]
+                    if lane.modelled:
+                        # device samples observe the PADDED size — the
+                        # launch did that much work, and _route predicts
+                        # padded too.  Mesh launches are key-routed, not
+                        # model-routed: their timings must not skew the
+                        # single-device model.
+                        self._observe(kind == "device",
+                                      self._padded(total)
+                                      if kind == "device" else total,
+                                      time.perf_counter() - t0)
+                results = self._scatter(batch, out, lane.shrink)
+        except Exception as e:
+            results, err = None, e
+        tell()  # a flush that ended before its launch
+        # each fop's codec.resume opens here and ends when the fop
+        # runs again on the loop
+        resumes = [_tracing.phase(self.name, "codec.resume", self.phases,
+                                  q.origin).start(push=False)
+                   for *_, q in batch]
+        loop.call_soon_threadsafe(self._resolve, batch, results, resumes,
+                                  err)
+
+    def _gather(self, batch, kind: str) -> np.ndarray:
         """The batch as one array, padded to its stripe bucket where it
         goes to the device: ``codec.gather``.  One fop that fills its
         bucket is passed on as it is, and has no such span."""
@@ -576,76 +736,22 @@ class BatchingCodec(Codec):
                 return cat
         with _tracing.phase(self.name, "codec.gather", self.phases):
             if len(batch) > 1:
-                cat = np.concatenate([d for d, *_ in batch], axis=axis)
+                cat = np.concatenate([d for d, *_ in batch], axis=-1)
             return self._pad_bucket(cat) if kind == "device" else cat
 
-    def _scatter(self, batch, out: np.ndarray, width) -> list:
-        """Each fop's own copy of its part of a flush's answer:
-        ``codec.scatter`` (columns of fragments, or bytes of a decode;
-        ``width(item)`` is a fop's share).  One fop takes the answer
-        as it is, with no span."""
+    def _scatter(self, batch, out: np.ndarray, shrink: int) -> list:
+        """Each fop's own copy of its part of a flush's answer, along
+        the last axis: ``codec.scatter``.  One fop takes the answer as
+        it is, with no span."""
         if len(batch) == 1:
             return [out]
         with _tracing.phase(self.name, "codec.scatter", self.phases):
             results, off = [], 0
             for item, *_ in batch:
-                n = width(item)
+                n = item.size // shrink
                 results.append(out[..., off:off + n].copy())
                 off += n
             return results
-
-    def _launch_stripes(self, kind: str, total: int) -> tuple[int, int]:
-        """(stripes coded for fops, stripes launched) of a flush of
-        ``total`` bytes: the device and mesh routes pad to the bucket."""
-        s = total // self.stripe_size
-        return s, s if kind == "cpu" else _bucket_stripes(s)
-
-    def _count_flush(self, batch, decode: bool = False):
-        """A batch leaves its queue, on the loop: route it and count it
-        -> ``(codec, kind, total bytes)``."""
-        self._last_flush = time.monotonic()
-        total = sum(d.size for d, *_ in batch)
-        codec, kind = self._route(total)
-        if kind == "mesh" and decode and self.systematic:
-            # the systematic mesh tier is encode-only (parity-rows
-            # sharded launch): a degraded decode reconstructs
-            # missing data rows on the single-device ladder
-            codec, kind = self, "device"
-        if kind == "cpu" and codec is not self:
-            self.cpu_launches += 1
-        self.flushes += 1
-        self.batched_fops += len(batch)
-        self.max_batch = max(self.max_batch, len(batch))
-        coded, launched = self._launch_stripes(kind, total)
-        self.stripes += coded
-        self.padded_stripes += launched
-        return codec, kind, total
-
-    def _flush_phase(self, op: str, batch, kind: str, total: int):
-        """Start of a flush, on the pool thread: every fop's
-        ``codec.queue`` ends, and ``codec.flush`` opens under the span
-        the first fop waits in, naming the other waiters' spans."""
-        for *_, q in batch:
-            q.stop()
-        stripes, bucket = self._launch_stripes(kind, total)
-        meta = {"op": op, "route": kind, "fops": len(batch),
-                "bytes": total, "stripes": stripes,
-                "bucket_stripes": bucket}
-        others = [str(q.origin[2]) for *_, q in batch[1:] if q.origin]
-        if others:
-            meta["others"] = ",".join(others)
-        return _tracing.phase(self.name, "codec.flush", self.phases,
-                              batch[0][-1].origin, **meta)
-
-    def _hand_back(self, loop, batch, results, err) -> None:
-        """End of a flush, on the pool thread: each fop's
-        ``codec.resume`` opens here and ends when the fop runs again
-        on the loop."""
-        resumes = [_tracing.phase(self.name, "codec.resume", self.phases,
-                                  q.origin).start(push=False)
-                   for *_, q in batch]
-        loop.call_soon_threadsafe(self._resolve, batch, results, resumes,
-                                  err)
 
     @staticmethod
     def _resolve(batch, results, resumes, err) -> None:
@@ -657,7 +763,13 @@ class BatchingCodec(Codec):
             if not fut.done():
                 fut.set_exception(err)
 
-    # -- encode ------------------------------------------------------------
+    @staticmethod
+    def _tell_launched(waiting) -> None:
+        for fut in waiting:
+            if not fut.done():
+                fut.set_result(None)
+
+    # -- the three callers -------------------------------------------------
 
     async def encode_async(self, data: np.ndarray, origin: str = "serve",
                            launched: asyncio.Future | None = None
@@ -679,99 +791,8 @@ class BatchingCodec(Codec):
         data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
         if data.size % self.stripe_size:
             raise ValueError("data length not a multiple of the stripe")
-        loop = asyncio.get_running_loop()
-        fut: asyncio.Future = loop.create_future()
-        self._enqueue(self._enc_q, data, fut, origin, launched)
-        if sum(d.size for d, *_ in self._enc_q) >= self.max_batch_bytes:
-            self._flush_encodes()
-        elif self._enc_task is None:
-            self._enc_task = asyncio.ensure_future(self._enc_timer())
-        out, resume = await fut
-        resume.stop()
-        return out
-
-    async def _enc_timer(self):
-        # window 0 = same-tick coalescing: sleep(0) runs after every
-        # already-scheduled callback, so fops made concurrent in this
-        # loop pass still land in one batch, while a lone sequential
-        # writer pays no idle wait (a fixed window poll costs ~0.3 ms
-        # of epoll timeout per flush on the smallfile path)
-        await asyncio.sleep(self.window)
-        self._flush_encodes()
-
-    def _flush_encodes(self) -> None:
-        if self._enc_task is not None:
-            self._enc_task.cancel()
-            self._enc_task = None
-        batch, self._enc_q = self._enc_q, []
-        if not batch:
-            return
-        codec, kind, total = self._count_flush(batch)
-        loop = asyncio.get_running_loop()
-        self._submit(self._run_encode, loop, batch, codec, kind, total)
-
-    def _submit(self, fn, loop, *args) -> None:
-        """Pool submit with an inline fallback: a batch still pending in
-        the window when close() shuts the pool (live reconfigure swaps
-        the codec) must NOT strand its awaiting fops — run the flush on
-        the loop thread instead."""
-        try:
-            self._pool.submit(fn, loop, *args)
-        except RuntimeError:  # pool shut down after close()
-            fn(loop, *args)
-
-    def _run_encode(self, loop, batch, codec: Codec, kind: str,
-                    total: int) -> None:
-        """Executes in the pool: concatenate, launch, time, resolve."""
-        results = err = None
-        waiting = [w for _d, _f, _o, w, _q in batch if w is not None]
-
-        def tell():
-            # once: the fops that asked hear that the launch is out
-            if waiting:
-                loop.call_soon_threadsafe(self._tell_launched, waiting[:])
-                waiting.clear()
-
-        try:
-            with self._flush_phase("encode", batch, kind, total):
-                t0 = time.perf_counter()
-                cat = self._gather(batch, kind)
-                if kind == "device" and waiting:
-                    from . import _device
-
-                    _device.after_launch(tell)
-                else:
-                    tell()
-                if kind == "mesh":
-                    frags = self._mesh_launch("encode", cat, None, batch)
-                elif kind == "device":
-                    frags = self.encode(cat)[:, : total // self.k]
-                else:
-                    frags = codec.encode(cat)
-                if kind != "mesh":
-                    # device samples observe the PADDED size — the
-                    # launch did that much work, and _route predicts
-                    # padded too.  Mesh launches are key-routed, not
-                    # model-routed: their timings must not skew the
-                    # single-device model.
-                    self._observe(kind == "device",
-                                  self._padded(total) if kind == "device"
-                                  else total,
-                                  time.perf_counter() - t0)
-                results = self._scatter(batch, frags,
-                                        lambda d: d.size // self.k)
-        except Exception as e:
-            results, err = None, e
-        tell()  # a flush that ended before its launch
-        self._hand_back(loop, batch, results, err)
-
-    @staticmethod
-    def _tell_launched(waiting) -> None:
-        for fut in waiting:
-            if not fut.done():
-                fut.set_result(None)
-
-    # -- parity-delta encode (ISSUE 10) ------------------------------------
+        return await self._enqueue(self._lanes["encode"], data, (),
+                                   origin, launched)
 
     async def encode_delta_async(self, delta: np.ndarray,
                                  origin: str = "serve") -> np.ndarray:
@@ -786,115 +807,15 @@ class BatchingCodec(Codec):
         delta = np.ascontiguousarray(delta, dtype=np.uint8).ravel()
         if delta.size % self.stripe_size:
             raise ValueError("delta length not a multiple of the stripe")
-        loop = asyncio.get_running_loop()
-        fut: asyncio.Future = loop.create_future()
-        self._enqueue(self._delta_q, delta, fut, origin)
-        if sum(d.size for d, *_ in self._delta_q) >= self.max_batch_bytes:
-            self._flush_deltas()
-        elif self._delta_task is None:
-            self._delta_task = asyncio.ensure_future(self._delta_timer())
-        out, resume = await fut
-        resume.stop()
-        return out
-
-    async def _delta_timer(self):
-        await asyncio.sleep(self.window)
-        self._flush_deltas()
-
-    def _flush_deltas(self) -> None:
-        if self._delta_task is not None:
-            self._delta_task.cancel()
-            self._delta_task = None
-        batch, self._delta_q = self._delta_q, []
-        if not batch:
-            return
-        codec, kind, total = self._count_flush(batch)
-        loop = asyncio.get_running_loop()
-        self._submit(self._run_delta, loop, batch, codec, kind, total)
-
-    def _run_delta(self, loop, batch, codec: Codec, kind: str,
-                   total: int) -> None:
-        results = err = None
-        try:
-            with self._flush_phase("delta", batch, kind, total):
-                cat = self._gather(batch, kind)
-                if kind == "mesh":
-                    # parity deltas ride the same parity-rows-only
-                    # sharded program as the systematic mesh encode
-                    # (ISSUE 12)
-                    pds = self._mesh_launch("delta", cat, None, batch)
-                elif kind == "device":
-                    pds = self.encode_delta(cat)[:, : total // self.k]
-                else:
-                    pds = codec.encode_delta(cat)
-                # the single-device models track full-generator
-                # encodes; parity-only work would skew them low — don't
-                # observe
-                results = self._scatter(batch, pds,
-                                        lambda d: d.size // self.k)
-        except Exception as e:
-            results, err = None, e
-        self._hand_back(loop, batch, results, err)
-
-    # -- decode ------------------------------------------------------------
+        return await self._enqueue(self._lanes["delta"], delta, (), origin)
 
     async def decode_async(self, frags: np.ndarray, rows,
                            origin: str = "serve") -> np.ndarray:
         """Decode k fragments; coalesced with concurrent same-mask calls."""
         rows = tuple(int(x) for x in rows)
         frags = np.ascontiguousarray(frags, dtype=np.uint8)
-        loop = asyncio.get_running_loop()
-        fut: asyncio.Future = loop.create_future()
-        q = self._dec_q.setdefault(rows, [])
-        self._enqueue(q, frags, fut, origin)
-        if sum(f.size for f, *_ in q) >= self.max_batch_bytes:
-            self._flush_decodes()  # same blow-up guard as the encode path
-        elif self._dec_task is None:
-            self._dec_task = asyncio.ensure_future(self._dec_timer())
-        out, resume = await fut
-        resume.stop()
-        return out
-
-    async def _dec_timer(self):
-        await asyncio.sleep(self.window)
-        self._flush_decodes()
-
-    def _flush_decodes(self) -> None:
-        if self._dec_task is not None:
-            self._dec_task.cancel()
-            self._dec_task = None
-        queues, self._dec_q = self._dec_q, {}
-        if not queues:
-            return
-        loop = asyncio.get_running_loop()
-        for rows, batch in queues.items():
-            codec, kind, total = self._count_flush(batch, decode=True)
-            self._submit(self._run_decode, loop, rows, batch, codec,
-                         kind, total)
-
-    def _run_decode(self, loop, rows, batch, codec: Codec, kind: str,
-                    total: int) -> None:
-        results = err = None
-        try:
-            with self._flush_phase("decode", batch, kind, total):
-                t0 = time.perf_counter()
-                cat = self._gather(batch, kind, axis=1)
-                if kind == "mesh":
-                    out = self._mesh_launch("decode", cat, rows, batch)
-                elif kind == "device":
-                    out = self.decode(cat, rows)[:total]
-                else:
-                    out = codec.decode(cat, rows)
-                if kind != "mesh":
-                    self._observe(kind == "device",
-                                  self._padded(total) if kind == "device"
-                                  else total,
-                                  time.perf_counter() - t0)
-                results = self._scatter(batch, out,
-                                        lambda f: f.shape[1] * self.k)
-        except Exception as e:
-            results, err = None, e
-        self._hand_back(loop, batch, results, err)
+        return await self._enqueue(self._lanes["decode"], frags, (rows,),
+                                   origin)
 
     def close(self) -> None:
         """Release the flush pool.  The EC layer calls this when a

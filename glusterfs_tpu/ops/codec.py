@@ -12,16 +12,20 @@ backend        implementation
 ``native``     C++ AVX2 XOR kernels via ctypes (native/)
 ``xla``        MXU binary matmul via jitted XLA (ops/gf256_xla.py)
 ``xla-xor``    VPU XOR chains via jitted XLA
-``pallas-xor`` Pallas TPU kernel, static XOR chains in VMEM
-``pallas-mxu`` Pallas TPU kernel, in-VMEM unpack + MXU matmul
+``pallas-xor`` Pallas TPU kernels, the CSE'd XOR program unrolled
+               in VMEM over the wire layouts (ops/gf256_pallas.py)
 ``mesh``       multi-chip: stripes sharded over the device mesh's
                ``dp`` axis, fragments over ``frag`` (parallel/
                mesh_codec shard_map plane); decodes past a memory
                threshold ride the ring-pipelined ppermute reduce
 ``auto``       mesh on a multi-chip TPU host; pallas-xor on one
-               chip (wide-k encode auto-routes to the MXU form);
-               else native, else xla
+               chip; else native, else xla
 =============  =================================================
+
+Each name is one object with the same four operations (:class:`_Backend`:
+``encode``, ``decode``, ``parity``, ``reconstruct``); :data:`_IMPLS` is
+the one table from name to object, :func:`detect` the one place that
+picks a name, and :class:`Codec` holds what they resolved to.
 
 Orthogonally to the backend, the ``cluster.mesh-codec`` volume key
 (op-version 10) arms a mesh TIER in ops/batch.BatchingCodec: coalesced
@@ -50,9 +54,6 @@ import numpy as np
 
 from ..core import gflog, metrics
 from . import gf256
-
-BACKENDS = ("ref", "native", "xla", "xla-xor", "pallas-xor", "pallas-mxu",
-            "mesh")
 
 # mesh decodes larger than this ride the ring-pipelined ppermute path
 # (streaming reduce over the frag axis instead of one all-gather whose
@@ -103,7 +104,7 @@ def virtual_mesh_env(n_devices: int | None = None,
     CPU platform only (the child can never open the chip its parent
     may own) and — when ``n_devices`` is given — exactly that many
     forced host devices.  The one copy of the rules every subprocess
-    spawner shares (bench, ``dryrun_multichip``)."""
+    spawner shares (``__graft_entry__.dryrun_multichip``)."""
     out = dict(os.environ if env is None else env)
     out["JAX_PLATFORMS"] = "cpu"
     flags = " ".join(
@@ -224,13 +225,198 @@ def detect(requested: str = "auto") -> str:
 
 
 @functools.cache
-def _encode_bits_sys(k: int, n: int) -> np.ndarray:
-    return gf256.expand_bitmatrix(gf256.systematic_matrix(k, n))
+def _generator_bits(k: int, n: int, systematic: bool) -> np.ndarray:
+    return gf256.expand_bitmatrix(gf256.generator_matrix(k, n, systematic))
 
 
-@functools.cache
-def _encode_bits(k: int, n: int) -> np.ndarray:
-    return gf256.expand_bitmatrix(gf256.encode_matrix(k, n))
+def _data_rows(data: np.ndarray, k: int) -> np.ndarray:
+    """Data fragments of the systematic code: a pure host reshape of
+    the stripe-major bytes (fragment j = chunk j of every stripe)."""
+    c = gf256.CHUNK_SIZE
+    s = data.size // (k * c)
+    return np.ascontiguousarray(
+        data.reshape(s, k, c).transpose(1, 0, 2)).reshape(k, s * c)
+
+
+def _interleave(k: int, pieces) -> np.ndarray:
+    """Stripe-major bytes from the k data rows of the systematic code,
+    handed over as ``(row, fragment)`` pairs: pure host assembly."""
+    pieces = list(pieces)
+    c = gf256.CHUNK_SIZE
+    s = pieces[0][1].size // c
+    out = np.empty((s, k, c), dtype=np.uint8)
+    for row, frag in pieces:
+        out[:, row, :] = frag.reshape(s, c)
+    return out.reshape(-1)
+
+
+class _Backend:
+    """A backend over one ``(k, r)`` geometry: the same four operations
+    on each.  ``encode(data, systematic)`` and ``decode(frags, rows,
+    systematic)`` apply the whole generator of either code;
+    ``parity(data)`` gives only the r parity rows of the systematic
+    code (a parity delta, and a systematic encode on a device);
+    ``reconstruct(frags, rows, missing)`` only its ``missing`` data
+    rows.  Arguments arrive checked and contiguous from :class:`Codec`."""
+
+    def __init__(self, k: int, r: int):
+        self.k, self.r, self.n = k, r, k + r
+
+    def reconstruct(self, frags: np.ndarray, rows, missing) -> np.ndarray:
+        # no kernel of its own: the rows out of the whole decode
+        return _data_rows(self.decode(frags, rows, True),
+                          self.k)[list(missing)]
+
+
+class _Ref(_Backend):
+    """The NumPy oracle every other backend is held to."""
+
+    def encode(self, data, systematic):
+        return gf256.ref_encode(data, self.k, self.n, systematic=systematic)
+
+    def decode(self, frags, rows, systematic):
+        return gf256.ref_decode(frags, rows, self.k, systematic=systematic)
+
+    def parity(self, data):
+        return gf256.ref_parity(data, self.k, self.n)
+
+
+class _Native(_Backend):
+    """The CPU platform: AVX2 XOR kernels, compiled programs per mask."""
+
+    def encode(self, data, systematic):
+        from glusterfs_tpu import native
+
+        return native.encode(data, self.k, self.n,
+                             _generator_bits(self.k, self.n, systematic))
+
+    def decode(self, frags, rows, systematic):
+        from glusterfs_tpu import native
+
+        return native.decode_program(
+            frags, self.k,
+            gf256.decode_program(self.k, tuple(rows), systematic))
+
+    def parity(self, data):
+        from glusterfs_tpu import native
+
+        # gf_encode walks whatever (rows, k*8) bit-matrix it is handed:
+        # the parity submatrix with n-k output fragments
+        return native.encode(data, self.k, self.r,
+                             gf256.parity_bits_cached(self.k, self.n))
+
+
+class _Xla(_Backend):
+    """Jitted XLA, ``form`` ``matmul`` (the MXU) or ``xor`` (VPU
+    chains): the names ``xla`` and ``xla-xor``."""
+
+    def __init__(self, k: int, r: int, form: str):
+        super().__init__(k, r)
+        self.form = form
+
+    def encode(self, data, systematic):
+        from . import gf256_xla
+
+        return gf256_xla.encode(data, self.k, self.n, self.form,
+                                systematic=systematic)
+
+    def decode(self, frags, rows, systematic):
+        from . import gf256_xla
+
+        return gf256_xla.decode(frags, rows, self.k, self.form,
+                                systematic=systematic)
+
+    def parity(self, data):
+        from . import gf256_xla
+
+        return gf256_xla.parity(data, self.k, self.n, self.form)
+
+
+class _Pallas(_Backend):
+    """One chip.  On the systematic code the device computes, and the
+    link carries, only what the host cannot reshape for itself: parity
+    rows on encode, the missing data rows on a degraded decode."""
+
+    def __init__(self, k: int, r: int, interpret: bool = False):
+        super().__init__(k, r)
+        self.interpret = interpret
+
+    def encode(self, data, systematic):
+        from . import gf256_pallas
+
+        if not systematic:
+            return gf256_pallas.encode(data, self.k, self.n, self.interpret)
+        out = np.empty((self.n, data.size // self.k), dtype=np.uint8)
+        out[: self.k] = _data_rows(data, self.k)
+        out[self.k:] = self.parity(data)
+        return out
+
+    def decode(self, frags, rows, systematic):
+        if not systematic:
+            from . import gf256_pallas
+
+            return gf256_pallas.decode(frags, rows, self.k, self.interpret)
+        missing = [j for j in range(self.k) if j not in rows]
+        have = [(row, frags[i]) for i, row in enumerate(rows)
+                if row < self.k]
+        if missing:
+            have += zip(missing, self.reconstruct(frags, rows, missing))
+        return _interleave(self.k, have)
+
+    def parity(self, data):
+        from . import gf256_pallas
+
+        return gf256_pallas.parity(data, self.k, self.n, self.interpret)
+
+    def reconstruct(self, frags, rows, missing):
+        from . import gf256_pallas
+
+        return gf256_pallas.reconstruct(frags, rows, missing, self.k,
+                                        self.interpret)
+
+
+class _Mesh(_Backend):
+    """Several chips: stripes over ``dp``, fragments over ``frag``.  A
+    systematic encode is the parity-rows-only sharded launch (data rows
+    are host reshapes); the systematic mesh is encode-only, so a
+    degraded systematic decode rides the single-device XLA matmul —
+    there on every host the mesh resolves on, and orders of magnitude
+    over the bit-sliced oracle."""
+
+    def encode(self, data, systematic):
+        from glusterfs_tpu.parallel import mesh_codec
+
+        return mesh_codec.sharded_encode(self.k, self.r, data,
+                                         systematic=systematic)
+
+    def decode(self, frags, rows, systematic):
+        if systematic:
+            from . import gf256_xla
+
+            return gf256_xla.decode(frags, rows, self.k, "matmul",
+                                    systematic=True)
+        from glusterfs_tpu.parallel import mesh_codec, ring_codec
+
+        if frags.size > MESH_RING_DECODE_BYTES:
+            return ring_codec.ring_decode(self.k, tuple(rows), frags)
+        return mesh_codec.sharded_decode(self.k, tuple(rows), frags)
+
+    def parity(self, data):
+        from glusterfs_tpu.parallel import mesh_codec
+
+        return mesh_codec.sharded_parity(self.k, self.r, data)
+
+
+#: THE table from a backend's name to its object, built ``(k, r)``
+_IMPLS = {
+    "ref": _Ref,
+    "native": _Native,
+    "xla": functools.partial(_Xla, form="matmul"),
+    "xla-xor": functools.partial(_Xla, form="xor"),
+    "pallas-xor": _Pallas,
+    "mesh": _Mesh,
+}
+BACKENDS = tuple(_IMPLS)
 
 
 class Codec:
@@ -255,23 +441,19 @@ class Codec:
             raise ValueError("k + r must be <= 255")
         self.fragment_chunk = gf256.CHUNK_SIZE
         self.stripe_size = k * gf256.CHUNK_SIZE
-        # auto-resolved backends may re-route per geometry (wide-k
-        # encode rides the MXU); an EXPLICIT backend is honored as-is
+        # whether the operator named the backend (ops/batch: a named
+        # device backend that cannot code fails the fop, ``auto`` is
+        # served from the CPU ladder)
         self._auto = backend == "auto"
         self.backend = detect(backend)
+        self._impl = _IMPLS[self.backend](k, r)
         # systematic generator (gf256.systematic_matrix): data rows are
         # raw stripe chunks — healthy reads need no math, encode ships
         # only parity off-device, degraded reads reconstruct only the
         # missing rows.  Incompatible fragment format with the default
         # (reference-parity) code: fixed per volume at create.
-        # systematic + mesh composes since ISSUE 12: encodes ride the
-        # parity-rows-only sharded launch (mesh_codec._parity_fn);
-        # degraded decodes take the ref systematic path (healthy reads
-        # are host assembly and never decode at all)
         self.systematic = systematic
         _LIVE_CODECS.add(self)  # unified-registry scrape target
-
-    # -- encode ------------------------------------------------------------
 
     def encode(self, data: np.ndarray) -> np.ndarray:
         data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
@@ -279,37 +461,7 @@ class Codec:
             raise ValueError(
                 f"data length {data.size} not a multiple of stripe "
                 f"{self.stripe_size}")
-        if self.systematic:
-            return self._encode_systematic(data)
-        b = self.backend
-        if b == "ref":
-            return gf256.ref_encode(data, self.k, self.n)
-        if b == "mesh":
-            from glusterfs_tpu.parallel import mesh_codec
-
-            return mesh_codec.sharded_encode(self.k, self.r, data)
-        if b == "native":
-            from glusterfs_tpu import native
-
-            return native.encode(data, self.k, self.n,
-                                 _encode_bits(self.k, self.n))
-        if b == "xla":
-            from . import gf256_xla
-
-            return gf256_xla.encode(data, self.k, self.n, "matmul")
-        if b == "xla-xor":
-            from . import gf256_xla
-
-            return gf256_xla.encode(data, self.k, self.n, "xor")
-        from . import gf256_pallas
-
-        # the CSE'd transposed XOR program beats the MXU sandwich at
-        # every geometry now (16+4: 79 vs 40 GiB/s), so auto no longer
-        # re-routes wide-k encodes; mxu stays an explicit backend
-        form = "fused" if b == "pallas-xor" else "mxu"
-        return gf256_pallas.encode(data, self.k, self.n, form)
-
-    # -- decode ------------------------------------------------------------
+        return self._impl.encode(data, self.systematic)
 
     def decode(self, frags: np.ndarray, rows) -> np.ndarray:
         """Reconstruct from the k fragments ``frags`` with indices ``rows``."""
@@ -319,74 +471,10 @@ class Codec:
         if any(x < 0 or x >= self.n for x in rows):
             raise ValueError("fragment index out of range")
         frags = np.ascontiguousarray(frags, dtype=np.uint8)
-        if self.systematic:
-            return self._decode_systematic(frags, rows)
-        b = self.backend
-        if b == "ref":
-            return gf256.ref_decode(frags, rows, self.k)
-        if b == "mesh":
-            from glusterfs_tpu.parallel import mesh_codec, ring_codec
-
-            if frags.size > MESH_RING_DECODE_BYTES:
-                return ring_codec.ring_decode(self.k, tuple(rows), frags)
-            return mesh_codec.sharded_decode(self.k, tuple(rows), frags)
-        if b == "native":
-            from glusterfs_tpu import native
-
-            return native.decode_program(
-                frags, self.k, gf256.decode_program(self.k, tuple(rows)))
-        if b in ("xla", "xla-xor"):
-            from . import gf256_xla
-
-            form = "xor" if b == "xla-xor" else "matmul"
-            return gf256_xla.decode(frags, rows, self.k, form)
-        from . import gf256_pallas
-
-        form = "fused" if b == "pallas-xor" else "mxu"
-        return gf256_pallas.decode(frags, rows, self.k, form)
-
-    # -- systematic paths (disperse.systematic) ----------------------------
-
-    def _data_rows(self, data: np.ndarray) -> np.ndarray:
-        """Data fragments of the systematic code: a pure host reshape of
-        the stripe-major bytes (fragment j = chunk j of every stripe)."""
-        s = data.size // self.stripe_size
-        c = self.fragment_chunk
-        return np.ascontiguousarray(
-            data.reshape(s, self.k, c).transpose(1, 0, 2)).reshape(
-                self.k, s * c)
-
-    def _encode_systematic(self, data: np.ndarray) -> np.ndarray:
-        b = self.backend
-        if b == "mesh":
-            from glusterfs_tpu.parallel import mesh_codec
-
-            # parity-rows-only sharded encode: the mesh computes just
-            # the r parity fragments, data rows are host reshapes
-            return mesh_codec.sharded_encode(self.k, self.r, data,
-                                             systematic=True)
-        if b in ("pallas-xor", "pallas-mxu"):
-            # the device computes (and the link carries) ONLY parity
-            from . import gf256_pallas
-
-            s = data.size // self.stripe_size
-            out = np.empty((self.n, s * self.fragment_chunk),
-                           dtype=np.uint8)
-            out[: self.k] = self._data_rows(data)
-            out[self.k:] = gf256_pallas.parity(data, self.k, self.n)
-            return out
-        if b == "native":
-            from glusterfs_tpu import native
-
-            return native.encode(data, self.k, self.n,
-                                 _encode_bits_sys(self.k, self.n))
-        if b in ("xla", "xla-xor"):
-            from . import gf256_xla
-
-            form = "xor" if b == "xla-xor" else "matmul"
-            return gf256_xla.encode(data, self.k, self.n, form,
-                                    systematic=True)
-        return gf256.ref_encode(data, self.k, self.n, systematic=True)
+        if self.systematic and max(rows) < self.k:
+            # healthy read: every data row survived — pure host assembly
+            return _interleave(self.k, zip(rows, frags))
+        return self._impl.decode(frags, rows, self.systematic)
 
     def encode_delta(self, delta: np.ndarray) -> np.ndarray:
         """Parity-fragment deltas ((n-k), len/k) of a stripe-aligned
@@ -408,28 +496,7 @@ class Codec:
             raise ValueError(
                 f"delta length {delta.size} not a multiple of stripe "
                 f"{self.stripe_size}")
-        b = self.backend
-        if b == "mesh":
-            from glusterfs_tpu.parallel import mesh_codec
-
-            return mesh_codec.sharded_parity(self.k, self.r, delta)
-        if b in ("pallas-xor", "pallas-mxu"):
-            from . import gf256_pallas
-
-            return gf256_pallas.parity(delta, self.k, self.n)
-        if b == "native":
-            from glusterfs_tpu import native
-
-            # gf_encode walks whatever (rows, k*8) bit-matrix it is
-            # handed: the parity submatrix with n-k output fragments
-            return native.encode(delta, self.k, self.n - self.k,
-                                 gf256.parity_bits_cached(self.k, self.n))
-        if b in ("xla", "xla-xor"):
-            from . import gf256_xla
-
-            form = "xor" if b == "xla-xor" else "matmul"
-            return gf256_xla.parity(delta, self.k, self.n, form)
-        return gf256.ref_parity(delta, self.k, self.n)
+        return self._impl.parity(delta)
 
     def reassemble(self, bufs, rows, frag_len: int) -> np.ndarray | None:
         """Healthy systematic fast path straight from fragment BUFFERS
@@ -463,46 +530,6 @@ class Codec:
                 dst[whole, rem:] = 0
             dst[whole + (1 if rem else 0):] = 0
         return out.reshape(-1)
-
-    def _decode_systematic(self, frags: np.ndarray, rows) -> np.ndarray:
-        k, c = self.k, self.fragment_chunk
-        s = frags.shape[1] // c
-        missing = [j for j in range(k) if j not in rows]
-        if not missing:
-            # healthy read: every data row survived — pure host assembly
-            out = np.empty((s, k, c), dtype=np.uint8)
-            for idx, row in enumerate(rows):
-                out[:, row, :] = frags[idx].reshape(s, c)
-            return out.reshape(-1)
-        b = self.backend
-        if b in ("pallas-xor", "pallas-mxu"):
-            # degraded: reconstruct ONLY the missing data rows on device
-            from . import gf256_pallas
-
-            rec = gf256_pallas.reconstruct(frags, tuple(rows),
-                                           tuple(missing), k)
-            out = np.empty((s, k, c), dtype=np.uint8)
-            for idx, row in enumerate(rows):
-                if row < k:
-                    out[:, row, :] = frags[idx].reshape(s, c)
-            for i, j in enumerate(missing):
-                out[:, j, :] = rec[i].reshape(s, c)
-            return out.reshape(-1)
-        if b == "native":
-            from glusterfs_tpu import native
-
-            return native.decode_program(
-                frags, k, gf256.decode_program(k, tuple(rows), True))
-        if b in ("xla", "xla-xor", "mesh"):
-            # mesh systematic is encode-only (parity-rows sharded):
-            # degraded reconstruction rides the single-device xla
-            # kernels — available on every host the mesh resolves on,
-            # and orders of magnitude over the bit-sliced ref oracle
-            from . import gf256_xla
-
-            form = "xor" if b == "xla-xor" else "matmul"
-            return gf256_xla.decode(frags, rows, k, form, systematic=True)
-        return gf256.ref_decode(frags, rows, k, systematic=True)
 
     # -- convenience -------------------------------------------------------
 

@@ -1,23 +1,17 @@
 """Pallas TPU kernels for the GF(256) erasure codec.
 
-The contraction ``y[r, :] = XOR_j { x[j, :] : abits[r, j] == 1 }`` over
-plane-major byte data (see ops/gf256.py) is computed entirely in VMEM so the
-8x bit-expanded intermediates of the XLA path never touch HBM.  Two kernel
-bodies (reference analog: the JIT'd XOR-chain kernels of
-xlators/cluster/ec/src/ec-code.c, selected by disperse.cpu-extensions):
-
-* ``xor``: statically unrolled per-row XOR chains on the VPU — the direct
-  TPU analog of the reference's AVX chains.  Coefficients are baked into the
-  trace (per-matrix specialization, like the reference's per-matrix JIT with
-  its LRU cache, ec-method.c:200-245).
-* ``mxu``: in-kernel unpack -> int8 binary matmul on the MXU (mod 2) ->
-  repack.  Coefficient bit-matrix arrives as a kernel operand, so decode
-  does not recompile per surviving-fragment mask.
-
-Data layout in/out of the kernels is plane-major ``(planes, W)``: plane row
-``j`` of the input holds byte ``w`` of plane ``j & 7`` of chunk-column
-``j >> 3``, across all stripes.  ``ops/codec.py`` wraps the stripe-major <->
-plane-major transposes.
+One formulation: the CSE'd straight-line XOR program of a bit-matrix
+(``gf256.XorProgram``; reference analog: the JIT'd XOR-chain kernels of
+xlators/cluster/ec/src/ec-code.c, selected by disperse.cpu-extensions)
+unrolled into the trace of one ``pallas_call`` that reads and writes the
+wire layouts directly and does the bit-plane relayout in VMEM, so the 8x
+bit-expanded intermediates of the XLA path never touch HBM.  Four
+kernels, named for the profiler: ``gf256_encode`` and ``gf256_decode``
+(the whole code, either direction), ``gf256_parity`` and
+``gf256_reconstruct`` (the systematic code's serving kernels: only the
+rows the host cannot reshape for itself).  Coefficients are baked into
+the trace (per-matrix specialization, like the reference's per-matrix
+JIT with its LRU cache, ec-method.c:200-245).
 """
 
 from __future__ import annotations
@@ -34,216 +28,12 @@ from ..core import tracing
 from . import gf256
 from ._device import device_call, launched
 
-# Lane tile for uint8 is (32, 128); keep W tiles big to amortize grid overhead.
-_TILE_W = 8192
-
-# xor3 kernel geometry: plane rows viewed as (M, 128) so every row slice is
-# a full (BM, 128) vreg tile (8 sublanes x 128 lanes fully used; the 2-D
-# kernel's (1, W) slices waste 7/8 of each vreg).
-_TILE3_M = 256  # measured best on v5e (probe: 44 GiB/s e2e encode 4+2)
-_TILE3_W = _TILE3_M * 128  # bytes per plane row per grid step (32 KiB)
-
-# VMEM working-set budget for the mxu kernel (the int32 matmul output
-# dominates at R rows x 8*tile int32); stay well under the ~16 MiB more
-# conservative TPU VMEM sizes.
-_MXU_VMEM_BUDGET = 8 << 20
-
-
-def _mxu_tile_w(r: int, c: int) -> int:
-    """Largest power-of-two tile (dividing _TILE_W) whose mxu working set
-    fits the VMEM budget: y (r, 8t) i32 + bits (c, 8t) i8 + x (c, t) i32."""
-    t = _TILE_W
-    while t > 512:
-        working = r * 8 * t * 4 + c * 8 * t + c * t * 4 + (r + c) * t
-        if working <= _MXU_VMEM_BUDGET:
-            break
-        t //= 2
-    return t
-
-
-def _xor_kernel_body(sels: tuple[tuple[int, ...], ...]):
-    """Build a kernel computing out[r] = XOR of x[j] for j in sels[r]."""
-
-    def kernel(x_ref, o_ref):
-        x = x_ref[:]
-        for r, sel in enumerate(sels):
-            if not sel:
-                o_ref[r : r + 1, :] = jnp.zeros_like(o_ref[r : r + 1, :])
-                continue
-            acc = x[sel[0] : sel[0] + 1, :]
-            for j in sel[1:]:
-                acc = acc ^ x[j : j + 1, :]
-            o_ref[r : r + 1, :] = acc
-
-    return kernel
-
-
-def _mxu_kernel(a_ref, x_ref, o_ref):
-    """Unpack -> binary matmul (mod 2) -> pack, all in VMEM.
-
-    Bit positions use grouped order (all bit-0 columns, then all bit-1
-    columns, ...) so everything stays rank-2: Mosaic can't insert minor dims
-    on int8.  The bit dim is a free dim of the matmul, so any consistent
-    order is valid as long as pack mirrors unpack.
-    """
-    x = x_ref[:].astype(jnp.int32)  # (C, TW); int8 shifts don't legalize
-    tw = x.shape[1]
-    bits = jnp.concatenate(
-        [((x >> b) & 1).astype(jnp.int8) for b in range(8)], axis=1
-    )  # (C, 8*TW)
-    y = jax.lax.dot_general(
-        a_ref[:],
-        bits,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # (R, 8*TW)
-    acc = y[:, 0:tw] & 1
-    for b in range(1, 8):
-        acc = acc | ((y[:, b * tw : (b + 1) * tw] & 1) << b)
-    o_ref[:] = acc.astype(jnp.uint8)
-
-
-def _xor3_kernel_body(sels: tuple[tuple[int, ...], ...]):
-    """out[r] = XOR of x[j] for j in sels[r], on (BM, 128) row tiles."""
-
-    def kernel(x_ref, o_ref):
-        x = x_ref[:]
-        for r, sel in enumerate(sels):
-            if not sel:
-                o_ref[r] = jnp.zeros_like(o_ref[r])
-                continue
-            acc = x[sel[0]]
-            for j in sel[1:]:
-                acc = acc ^ x[j]
-            o_ref[r] = acc
-
-    return kernel
-
-
-@functools.lru_cache(maxsize=256)
-def _xor3_apply_fn(sels: tuple[tuple[int, ...], ...], c: int,
-                   interpret: bool):
-    """(C, W) uint8 -> (R, W) uint8; W % _TILE3_W == 0; 3-D tiled."""
-    r = len(sels)
-    kernel = _xor3_kernel_body(sels)
-
-    @jax.jit
-    def run(x):
-        w = x.shape[1]
-        m = w // 128
-        x3 = x.reshape(c, m, 128)
-        grid = (m // _TILE3_M,)
-        out = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((r, m, 128), jnp.uint8),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((c, _TILE3_M, 128), lambda i: (0, i, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((r, _TILE3_M, 128), lambda i: (0, i, 0),
-                                   memory_space=pltpu.VMEM),
-            interpret=interpret,
-            name="gf256_xor3",
-        )(x3)
-        return out.reshape(r, w)
-
-    return run
-
-
-@functools.lru_cache(maxsize=256)
-def _xor_apply_fn(sels: tuple[tuple[int, ...], ...], c: int, interpret: bool):
-    """(C, W) uint8 -> (R, W) uint8 via static XOR chains; W % _TILE_W == 0."""
-    r = len(sels)
-    kernel = _xor_kernel_body(sels)
-
-    @jax.jit
-    def run(x):
-        w = x.shape[1]
-        grid = (w // _TILE_W,)
-        return pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint8),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((c, _TILE_W), lambda i: (0, i),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((r, _TILE_W), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-            interpret=interpret,
-            name="gf256_xor",
-        )(x)
-
-    return run
-
-
-@functools.lru_cache(maxsize=16)
-def _mxu_apply_fn(r: int, c: int, interpret: bool):
-    """(R*8, C*8) bitmatrix (int8), (C*8, W) bytes -> (R*8, W) bytes."""
-
-    tile_w = _mxu_tile_w(r, c)
-
-    @jax.jit
-    def run(abits, x):
-        w = x.shape[1]
-        grid = (w // tile_w,)
-        return pl.pallas_call(
-            _mxu_kernel,
-            out_shape=jax.ShapeDtypeStruct((r, w), jnp.uint8),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((r, c), lambda i: (0, 0), memory_space=pltpu.VMEM),
-                pl.BlockSpec((c, tile_w), lambda i: (0, i),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((r, tile_w), lambda i: (0, i),
-                                   memory_space=pltpu.VMEM),
-            interpret=interpret,
-            name="gf256_mxu",
-        )(abits, x)
-
-    return run
-
-
-def _sels_from_bits(abits: np.ndarray) -> tuple[tuple[int, ...], ...]:
-    return tuple(tuple(int(j) for j in np.nonzero(row)[0]) for row in abits)
-
-
-def apply_bitmatrix(
-    abits: np.ndarray,
-    x: jnp.ndarray,
-    formulation: str = "xor",
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Apply an (R, C) GF(2) bit-matrix to plane-major bytes (C, W) -> (R, W).
-
-    W must be a multiple of _TILE_W (callers pad stripes accordingly).
-    """
-    if formulation not in ("xor", "xor3", "mxu"):
-        raise ValueError(
-            f"formulation must be 'xor', 'xor3' or 'mxu', got {formulation!r}")
-    r, c = abits.shape
-    if x.shape[0] != c:
-        raise ValueError(f"plane rows {x.shape[0]} != bitmatrix columns {c}")
-    if x.shape[1] % _TILE_W:
-        raise ValueError(f"W must be a multiple of {_TILE_W}")
-    if formulation == "xor3":
-        if x.shape[1] % _TILE3_W:
-            raise ValueError(f"W must be a multiple of {_TILE3_W} for xor3")
-        return _xor3_apply_fn(_sels_from_bits(abits), c, interpret)(x)
-    if formulation == "xor":
-        return _xor_apply_fn(_sels_from_bits(abits), c, interpret)(x)
-    return _mxu_apply_fn(r, c, interpret)(jnp.asarray(abits, jnp.int8), x)
-
-
 # ---------------------------------------------------------------------------
-# Fused wire-layout kernels (the production path).
-#
-# The transpose-sandwich wrappers below pay 3-4 extra HBM passes (XLA
-# materializes the u8 fragment-major <-> plane-major transposes at ~1/6 of
-# copy speed).  The fused kernels read and write the wire layouts directly
-# and do the plane relayout in VMEM via 64-byte lane slices:
+# Wire-layout kernels.  Each reads and writes the wire layouts directly
+# and does the plane relayout in VMEM via 64-byte lane slices (a
+# transposing wrapper around a plane-major kernel pays 3-4 extra HBM
+# passes: XLA materializes the u8 fragment-major <-> plane-major
+# transposes at ~1/6 of copy speed):
 #
 # * encode: stripe-major (S, k*512) blocks in, per-fragment (n, TS, 512)
 #   blocks out — measured 98 GiB/s e2e on v5e (4+2, 64 MiB).
@@ -549,90 +339,16 @@ def reconstruct(frags: np.ndarray, rows, wanted, k: int,
     return device_call(fn, frags)
 
 
-# ---------------------------------------------------------------------------
-# Stripe-major wrappers (same API as gf256_xla): transpose sandwich.
-# ---------------------------------------------------------------------------
-
-
-def _pad_w(s: int) -> int:
-    """Stripes padded so plane width S*64 is a multiple of every kernel's
-    tile (_TILE3_W = 32 KiB covers _TILE_W = 8 KiB too)."""
-    per = _TILE3_W // gf256.WORD_SIZE  # stripes per tile
-    return (s + per - 1) // per * per
-
-
-@functools.lru_cache(maxsize=64)
-def _encode_fn(k: int, n: int, formulation: str, interpret: bool):
-    abits_np = gf256.expand_bitmatrix(gf256.encode_matrix(k, n))
-
-    @jax.jit
-    def run(data):
-        s = data.shape[0] // (k * gf256.CHUNK_SIZE)
-        sp = _pad_w(s)
-        x = data.reshape(s, k * 8, gf256.WORD_SIZE)
-        x = jnp.pad(x, ((0, sp - s), (0, 0), (0, 0)))
-        xt = x.transpose(1, 0, 2).reshape(k * 8, sp * gf256.WORD_SIZE)
-        yt = apply_bitmatrix(abits_np, xt, formulation, interpret)
-        y = yt.reshape(n * 8, sp, gf256.WORD_SIZE)[:, :s, :]
-        # (n*8, S, 64) -> fragment-major (n, S*512)
-        return (
-            y.reshape(n, 8, s, gf256.WORD_SIZE)
-            .transpose(0, 2, 1, 3)
-            .reshape(n, s * gf256.CHUNK_SIZE)
-        )
-
-    return run
-
-
-@functools.lru_cache(maxsize=256)
-def _decode_fn(k: int, formulation: str, interpret: bool,
-               rows: tuple[int, ...] | None):
-    """Transpose-sandwich decode; static (xor/xor3) forms are cached per
-    surviving mask ``rows`` — matching the per-mask program LRU keying —
-    instead of per bit-matrix tuple (mxu passes rows=None: its bbits is
-    a traced operand, one compile serves every mask)."""
-    def run(frags, bbits_np):
-        s = frags.shape[1] // gf256.CHUNK_SIZE
-        sp = _pad_w(s)
-        x = jnp.pad(
-            frags.reshape(k, s, 8, gf256.WORD_SIZE).transpose(0, 2, 1, 3),
-            ((0, 0), (0, 0), (0, sp - s), (0, 0)),
-        ).reshape(k * 8, sp * gf256.WORD_SIZE)
-        yt = apply_bitmatrix(bbits_np, x, formulation, interpret)
-        y = yt.reshape(k * 8, sp, gf256.WORD_SIZE)[:, :s, :]
-        # plane rows (k*8) are chunk-major within the stripe: chunk j of the
-        # stripe is rows 8j..8j+7 -> output stripe-major bytes
-        return (
-            y.reshape(k, 8, s, gf256.WORD_SIZE)
-            .transpose(2, 0, 1, 3)
-            .reshape(s * k * gf256.CHUNK_SIZE)
-        )
-
-    if formulation in ("xor", "xor3"):
-        bb = gf256.decode_bits_cached(k, rows)
-        return jax.jit(lambda frags: run(frags, bb))
-    return jax.jit(run)
-
-
-def encode(data, k: int, n: int, formulation: str = "fused",
-           interpret: bool = False) -> np.ndarray:
+def encode(data, k: int, n: int, interpret: bool = False) -> np.ndarray:
+    """Stripe-major bytes (a multiple of k*512) -> (n, S*512) fragments."""
     data = np.ascontiguousarray(data, dtype=np.uint8).ravel()
     if data.size % (k * gf256.CHUNK_SIZE):
         raise ValueError("data length must be a multiple of k*512")
-    if formulation == "fused":
-        return device_call(_fused_encode_fn(k, n, interpret), data)
-    return device_call(_encode_fn(k, n, formulation, interpret), data)
+    return device_call(_fused_encode_fn(k, n, interpret), data)
 
 
-def decode(frags, rows, k: int, formulation: str = "fused",
-           interpret: bool = False) -> np.ndarray:
+def decode(frags, rows, k: int, interpret: bool = False) -> np.ndarray:
+    """k survivors (k, S*512) with indices ``rows`` -> the bytes."""
     frags = np.ascontiguousarray(frags, dtype=np.uint8)
     rows = tuple(int(x) for x in rows)
-    if formulation == "fused":
-        return device_call(_fused_decode_fn(k, rows, interpret), frags)
-    if formulation in ("xor", "xor3"):
-        return device_call(_decode_fn(k, formulation, interpret, rows),
-                           frags)
-    bbits_np = gf256.decode_bits_cached(k, rows)
-    return device_call(_decode_fn(k, "mxu", interpret, None), frags,
-                       bbits_np.astype(np.int8))
+    return device_call(_fused_decode_fn(k, rows, interpret), frags)

@@ -229,8 +229,7 @@ def _parity_fn(k: int, n: int, mesh: Mesh):
 def _planes_to_wire(y: np.ndarray, rows: int, s: int) -> np.ndarray:
     """Plane-major (rows*8, S, 64) -> wire fragment-major
     (rows, S*512): fragment f's chunk for stripe s' interleaves its 8
-    planes (same transform as the single-chip sandwich,
-    gf256_pallas._encode_fn)."""
+    planes."""
     return (y.reshape(rows, 8, s, gf256.WORD_SIZE)
              .transpose(0, 2, 1, 3)
              .reshape(rows, s * gf256.CHUNK_SIZE))
@@ -265,7 +264,7 @@ def sharded_encode(k: int, r: int, data: np.ndarray,
             y = np.asarray(_parity_fn(k, n, mesh)(jnp.asarray(x)))
         y = y[:, :s, :]  # (r*8, S, 64) parity planes
         out = np.empty((n, s * gf256.CHUNK_SIZE), dtype=np.uint8)
-        # data rows: verbatim stripe chunks (ops/codec._data_rows)
+        # data rows: verbatim stripe chunks (ops/codec ``_data_rows``)
         out[:k] = np.ascontiguousarray(
             data.reshape(s, k, gf256.CHUNK_SIZE)
                 .transpose(1, 0, 2)).reshape(k, s * gf256.CHUNK_SIZE)

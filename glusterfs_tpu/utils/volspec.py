@@ -1,8 +1,8 @@
-"""Volfile-spec builders shared by benches and tests.
+"""Volfile-spec builders shared by the tests and ``__graft_entry__``.
 
 The analog of the reference's volgen templates for the common shapes
-(reference xlators/mgmt/glusterd/src/glusterd-volgen.c); tests and
-bench.py previously each hand-rolled the same brick+disperse string.
+(reference xlators/mgmt/glusterd/src/glusterd-volgen.c), in place of
+one hand-rolled brick+disperse string per caller.
 """
 
 from __future__ import annotations

@@ -356,3 +356,173 @@ def test_launched_future_resolves_on_every_route(monkeypatch, route):
         assert order == ["launch dispatched", "told"] and codec.launches == 1
     else:
         assert order == ["told"] and codec.cpu_launches == 1
+
+
+# -- the one lane, under each of its three ops (ISSUE 28) -------------------
+
+from tests.test_batch_fill import recorder  # noqa: E402,F401 (fixture)
+
+LANES = ["encode", "delta", "decode"]
+ENTRY = {"encode": "encode", "delta": "encode_delta", "decode": "decode"}
+MASK = (1, 3, 4, 5)  # data rows 0 and 2 lost
+
+
+def _lane_codec(**kw):
+    kw.setdefault("min_batch", 0)  # the device route
+    return BatchingCodec(K, R, "xla", systematic=True, **kw)
+
+
+def _lane_fop(codec, lane, seed, stripes=2):
+    """One fop of ``lane`` -> (a call that starts it, its right answer)."""
+    data = _rand(STRIPE * stripes, seed)
+    if lane == "encode":
+        return (lambda: codec.encode_async(data),
+                gf256.ref_encode(data, K, K + R, systematic=True))
+    if lane == "delta":
+        return (lambda: codec.encode_delta_async(data),
+                gf256.ref_parity(data, K, K + R))
+    frags = gf256.ref_encode(data, K, K + R, systematic=True)[list(MASK)]
+    return lambda: codec.decode_async(frags, MASK), data
+
+
+def _phase_counts(codec):
+    return {name: row["count"]
+            for name, row in codec.dump_stats()["phases"].items()}
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_past_max_batch_bytes_flushes_before_the_timer(lane):
+    """The blow-up guard: with a window nobody would wait for, the fop
+    that takes the queue to ``max_batch_bytes`` flushes it on the spot
+    and the timer is gone."""
+    codec = _lane_codec(window=60.0, max_batch_bytes=2 * 2 * STRIPE)
+    fops = [_lane_fop(codec, lane, 30 + i) for i in range(2)]
+
+    async def run():
+        outs = await asyncio.wait_for(
+            asyncio.gather(*(start() for start, _want in fops)), 20)
+        assert codec._lanes[lane].timer is None
+        return outs
+
+    outs = asyncio.run(run())
+    codec.close()
+    assert codec.flushes == 1 and codec.batched_fops == 2
+    for (_start, want), out in zip(fops, outs):
+        assert np.array_equal(out, want)
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_batch_queued_at_close_runs_inline(lane):
+    """``close()`` with fops still in the window: their flush runs on
+    the loop's own thread (the pool is shut) and every waiter gets its
+    answer."""
+    import threading
+
+    codec = _lane_codec(window=0.05)
+    fops = [_lane_fop(codec, lane, 40 + i) for i in range(3)]
+    ran_on: list = []
+    run_flush = codec._run
+
+    def spy(*a):
+        ran_on.append(threading.current_thread())
+        return run_flush(*a)
+
+    codec._run = spy
+
+    async def run():
+        jobs = [asyncio.ensure_future(start()) for start, _want in fops]
+        await asyncio.sleep(0)  # queued, the timer not yet due
+        assert sum(map(len, codec._lanes[lane].queues.values())) == 3
+        codec.close()
+        return await asyncio.wait_for(asyncio.gather(*jobs), 20)
+
+    outs = asyncio.run(run())
+    assert ran_on == [threading.main_thread()]
+    for (_start, want), out in zip(fops, outs):
+        assert np.array_equal(out, want)
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_failed_launch_fails_its_waiters_and_no_others(lane):
+    """A launch that raises: every waiter of that flush gets the error,
+    each of their ``codec.queue`` and ``codec.resume`` phases is closed,
+    and the next flush of the lane works."""
+    codec = _lane_codec(window=0.005)
+    fops = [_lane_fop(codec, lane, 50 + i) for i in range(3)]
+
+    def refuse(*_a):
+        raise RuntimeError("launch refused")
+
+    async def run():
+        setattr(codec, ENTRY[lane], refuse)
+        failed = await asyncio.gather(
+            *(start() for start, _want in fops[:2]),
+            return_exceptions=True)
+        delattr(codec, ENTRY[lane])  # the class's own again
+        return failed, await fops[2][0]()
+
+    failed, out = asyncio.run(run())
+    codec.close()
+    assert [str(e) for e in failed] == ["launch refused"] * 2
+    assert all(isinstance(e, RuntimeError) for e in failed)
+    counts = _phase_counts(codec)
+    assert counts["codec.queue"] == counts["codec.resume"] == 3
+    assert counts["codec.flush"] == 2 and codec.flushes == 2
+    assert np.array_equal(out, fops[2][1])
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_flush_counts_and_names_itself(lane, recorder):
+    """A flush of two fops (2 + 3 stripes, launched in the 16-stripe
+    bucket): the dump's counters, and the ``codec.flush`` span's own
+    account under the lane's name."""
+    from glusterfs_tpu.core import tracing
+
+    codec = _lane_codec(window=0.005)
+    fops = [_lane_fop(codec, lane, 60 + i, stripes=2 + i) for i in range(2)]
+
+    async def run():
+        tracing.ANNOTATE = recorder
+        return await asyncio.gather(*(start() for start, _want in fops))
+
+    outs = asyncio.run(run())
+    codec.close()
+    for (_start, want), out in zip(fops, outs):
+        assert np.array_equal(out, want)
+    st = codec.dump_stats()
+    assert (st["flushes"], st["batched_fops"], st["max_batch"],
+            st["launches"], st["cpu_launches"]) == (1, 2, 2, 1, 0)
+    assert (st["stripes"], st["padded_stripes"]) == (5, 16)
+    flushes = [m for name, m in recorder.log if name == "gftpu:codec.flush"]
+    assert len(flushes) == 1
+    assert {key: flushes[0][key] for key in (
+        "op", "route", "fops", "bytes", "stripes", "bucket_stripes")} == {
+            "op": lane, "route": "device", "fops": 2, "bytes": 5 * STRIPE,
+            "stripes": 5, "bucket_stripes": 16}
+    counts = _phase_counts(codec)
+    assert counts["codec.gather"] == counts["codec.scatter"] == 1
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_device_flush_calls_the_entry_point_on_the_instance(lane):
+    """The seam benchmarks/control.py stands on: an entry point replaced
+    ON THE INSTANCE is what a device-route flush calls."""
+    codec = _lane_codec(window=0.005)
+    start, want = _lane_fop(codec, lane, 70)
+    entry = getattr(codec, ENTRY[lane])
+    calls: list = []
+
+    def altered(*a):
+        calls.append(len(a))
+        out = entry(*a).copy()
+        out.flat[0] ^= 1
+        return out
+
+    setattr(codec, ENTRY[lane], altered)
+    out = asyncio.run(start())
+    codec.close()
+    assert calls == [2 if lane == "decode" else 1]
+    assert codec.flushes == 1 and codec.cpu_launches == 0
+    want = want.copy()
+    want.flat[0] ^= 1
+    assert np.array_equal(out, want)

@@ -16,8 +16,7 @@ import chip_smoke  # noqa: E402
 
 TINY = {"files": 2, "file_mib": 0.25, "io_kib": 64, "inflight": 2,
         "kernel_stripes": 20, "kernel_big_mib": 0,
-        "geometries": ((4, 2),), "pallas_forms": ("xor3",),
-        "xla_forms": ()}  # the volume below runs on xla anyway
+        "geometries": ((4, 2),), "xla_forms": ()}  # the volume below runs on xla anyway
 
 
 def test_stages_at_tiny_size(tmp_path):

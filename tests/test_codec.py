@@ -124,3 +124,57 @@ def test_native_apply_bitmatrix_parity():
         for j in np.nonzero(abits[i])[0]:
             expect[i] ^= x[j]
     assert np.array_equal(got, expect)
+
+
+def _backend_object(name, k, r):
+    """The object ``name`` resolves to, as a Codec holds it (the one
+    table ``codec._IMPLS``); the chip's kernels run interpreted on a
+    host without one."""
+    from tests.harness import have_tpu
+
+    if name == "native" and not _native.available():
+        pytest.skip("no native toolchain")
+    if name == "pallas-xor" and not have_tpu():
+        return codec._Pallas(k, r, interpret=True)
+    return codec.Codec(k, r, name)._impl
+
+
+@pytest.mark.parametrize("name", codec.BACKENDS)
+def test_backend_object_has_the_four_operations(name):
+    """Every name left in BACKENDS is one object with ``encode``,
+    ``decode``, ``parity`` and ``reconstruct``, each byte-exact against
+    the oracle on both codes."""
+    k, r = 4, 2
+    n = k + r
+    impl = _backend_object(name, k, r)
+    data = _data(k, stripes=5, seed=11)
+    rows = [1, 3, 4, 5]  # data rows 0 and 2 lost
+    for systematic in (False, True):
+        frags = gf256.ref_encode(data, k, n, systematic=systematic)
+        assert np.array_equal(impl.encode(data, systematic), frags)
+        assert np.array_equal(
+            impl.decode(frags[rows], rows, systematic),
+            gf256.ref_decode(frags[rows], rows, k, systematic=systematic))
+    assert np.array_equal(impl.parity(data), gf256.ref_parity(data, k, n))
+    # ``frags`` is the systematic code now: its first k rows are the data
+    for missing in ((1,), (0, 2)):
+        rows = [j for j in range(n) if j not in missing][:k]
+        assert np.array_equal(
+            impl.reconstruct(frags[rows], rows, missing),
+            frags[list(missing)]), missing
+
+
+def test_removed_backend_name_is_refused_by_the_codec():
+    with pytest.raises(ValueError) as e:
+        codec.Codec(4, 2, "pallas-mxu")
+    assert all(b in str(e.value) for b in codec.BACKENDS)
+
+
+def test_removed_backend_name_is_refused_by_the_volume_option():
+    from glusterfs_tpu.cluster.ec import DisperseLayer
+    from glusterfs_tpu.core.options import OptionError, validate_options
+
+    with pytest.raises(OptionError) as e:
+        validate_options(DisperseLayer.OPTIONS,
+                         {"cpu-extensions": "pallas-mxu"})
+    assert all(b in str(e.value) for b in ("auto",) + codec.BACKENDS)

@@ -21,7 +21,7 @@ import pytest
 from glusterfs_tpu import native
 from glusterfs_tpu.ops import gf256
 
-# the bench.py redundancy sweep
+# the BASELINE redundancy sweep (chip_smoke.py runs the same four)
 GEOMETRIES = [(4, 2), (8, 3), (8, 4), (16, 4)]
 
 
@@ -170,7 +170,7 @@ def test_xla_xor_program_decode_parity(k, r):
 
 @pytest.mark.parametrize("k,r", [(4, 2), (8, 3)])
 def test_pallas_fused_program_decode_parity(k, r):
-    """Pallas fused decode (interpret mode; silicon covered by bench) on
+    """Pallas fused decode (interpret mode; silicon covered by chip_smoke.py) on
     sampled masks beyond the first-r-lost one the existing suite uses."""
     from glusterfs_tpu.ops import gf256_pallas
 
@@ -178,7 +178,7 @@ def test_pallas_fused_program_decode_parity(k, r):
     data = _data(k, seed=7 * k + r)
     frags = gf256.ref_encode(data, k, n)
     for rows in _masks(k, n, limit=2):
-        got = gf256_pallas.decode(frags[list(rows)], rows, k, "fused",
+        got = gf256_pallas.decode(frags[list(rows)], rows, k,
                                   interpret=True)
         assert np.array_equal(got, data), f"mask {rows}"
 

@@ -1,5 +1,5 @@
 """Pallas kernel parity (interpret mode on CPU; real lowering exercised on TPU
-by bench.py and __graft_entry__)."""
+by the silicon tests below, chip_smoke.py and __graft_entry__)."""
 
 import numpy as np
 import pytest
@@ -11,25 +11,23 @@ CONFIGS = [(4, 2), (8, 4), (16, 4)]
 
 
 @pytest.mark.parametrize("k,r", CONFIGS)
-@pytest.mark.parametrize("formulation", ["xor", "xor3", "mxu", "fused"])
-def test_encode_parity(k, r, formulation):
+def test_encode_parity(k, r):
     n = k + r
     rng = np.random.default_rng(k + r)
     data = rng.integers(0, 256, k * gf256.CHUNK_SIZE * 3, dtype=np.uint8)
     expect = gf256.ref_encode(data, k, n)
-    got = gf256_pallas.encode(data, k, n, formulation, interpret=True)
+    got = gf256_pallas.encode(data, k, n, interpret=True)
     assert np.array_equal(got, expect)
 
 
 @pytest.mark.parametrize("k,r", CONFIGS)
-@pytest.mark.parametrize("formulation", ["xor", "xor3", "mxu", "fused"])
-def test_decode_parity(k, r, formulation):
+def test_decode_parity(k, r):
     n = k + r
     rng = np.random.default_rng(k * 3 + r)
     data = rng.integers(0, 256, k * gf256.CHUNK_SIZE * 2, dtype=np.uint8)
     frags = gf256.ref_encode(data, k, n)
     rows = list(range(r, r + k))
-    got = gf256_pallas.decode(frags[rows], rows, k, formulation, interpret=True)
+    got = gf256_pallas.decode(frags[rows], rows, k, interpret=True)
     assert np.array_equal(got, data)
 
 
@@ -40,10 +38,10 @@ def test_fused_unaligned_stripe_counts(k, r):
     for s in (1, 3, 127, 129):
         rng = np.random.default_rng(s)
         data = rng.integers(0, 256, k * gf256.CHUNK_SIZE * s, dtype=np.uint8)
-        frags = gf256_pallas.encode(data, k, n, "fused", interpret=True)
+        frags = gf256_pallas.encode(data, k, n, interpret=True)
         assert np.array_equal(frags, gf256.ref_encode(data, k, n))
         rows = list(range(r, r + k))
-        out = gf256_pallas.decode(frags[rows], rows, k, "fused",
+        out = gf256_pallas.decode(frags[rows], rows, k,
                                   interpret=True)
         assert np.array_equal(out, data)
 
@@ -57,13 +55,13 @@ def test_fused_all_masks_4p2():
     data = rng.integers(0, 256, k * gf256.CHUNK_SIZE * 2, dtype=np.uint8)
     frags = gf256.ref_encode(data, k, n)
     for rows in itertools.combinations(range(n), k):
-        out = gf256_pallas.decode(frags[np.asarray(rows)], rows, k, "fused",
+        out = gf256_pallas.decode(frags[np.asarray(rows)], rows, k,
                                   interpret=True)
         assert np.array_equal(out, data), rows
 
 
 # -- real-lowering parity (VERDICT r3 weak #8: interpret-only parity
-# lets a Mosaic lowering bug reach bench.py before any test) ----------
+# lets a Mosaic lowering bug reach the chip before any test) ----------
 
 @pytest.mark.skipif(not have_tpu(), reason="needs a real TPU")
 @pytest.mark.parametrize("k,r", CONFIGS)
@@ -76,11 +74,11 @@ def test_fused_parity_on_silicon(k, r):
     data = rng.integers(0, 256, k * gf256.CHUNK_SIZE * 300,
                         dtype=np.uint8)
     expect = gf256.ref_encode(data, k, n)
-    got = gf256_pallas.encode(data, k, n, "fused", interpret=False)
+    got = gf256_pallas.encode(data, k, n, interpret=False)
     assert np.array_equal(got, expect)
     rows = list(range(r, r + k))
-    out = gf256_pallas.decode(expect[rows], rows, k, "fused",
-                              interpret=False)
+    out = gf256_pallas.decode(expect[rows], rows, k,
+                                  interpret=False)
     assert np.array_equal(out, data)
 
 
@@ -96,10 +94,10 @@ def test_golden_vectors_on_silicon():
         n = k + r
         data = g[f"in_{k}_{r}"]
         frags = np.stack([g[f"frag_{k}_{r}_{i}"] for i in range(n)])
-        got = gf256_pallas.encode(data, k, n, "fused", interpret=False)
+        got = gf256_pallas.encode(data, k, n, interpret=False)
         assert np.array_equal(got, frags), (k, r)
         for which in (0, 1):
             rows = [int(x) for x in g[f"decmask_{k}_{r}_{which}"]]
-            out = gf256_pallas.decode(frags[rows], rows, k, "fused",
-                                      interpret=False)
+            out = gf256_pallas.decode(frags[rows], rows, k,
+                                  interpret=False)
             assert np.array_equal(out, data), (k, r, rows)

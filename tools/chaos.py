@@ -68,7 +68,7 @@ containment plane must not pay for failure handling with leaks.
 ``--baseline FILE`` loads a previous ``--json`` report and judges this
 run's timing rows against it at the documented 2-core swing band
 (ISSUE 20) — machine-readable ``regressions: [...]`` rows land in the
-report, the mirror of bench.py's throughput gate.
+report.
 
 Usage:
     python tools/chaos.py [--scenario NAME ...] [--json] [--with-fuse]
@@ -1123,18 +1123,23 @@ async def amain(opts) -> dict:
     return report
 
 
+#: the allowed new/old ratio of a timing row (ISSUE 20).  Every row is
+#: a full-stack time on a host that timeshares glusterd, six brick
+#: processes and the clients, where IDENTICAL code swings widely from
+#: run to run: the recorded identical-config wire rows of the 2-core
+#: sandbox spanned 9.7-45.1 MiB/s (docs/observability.md).  Inside
+#: that band a slower row is scheduling noise, not a regression.
+SWING_BAND_WIRE = 45.1 / 9.7
+
+
 def compare_reports(now: dict, prev: dict) -> list[dict]:
     """Baseline-compare (ISSUE 20): judge this run's timing rows
     against a previous ``--json`` report.  Chaos rows are WALL-CLOCK
-    TIMES, so the gate is the mirror of bench.py's throughput gate: a
-    regression is a time that GREW beyond the documented 2-core swing
-    band (bench.SWING_BAND_WIRE — identical-config full-stack rows
-    swing 4.65x on the shared host; docs/observability.md).  Only
-    scenarios that PASSED in both runs are comparable; every flag is
-    machine-readable: {"row", "prev", "now", "grow_pct", "band"}."""
-    import bench
-
-    band = bench.SWING_BAND_WIRE
+    TIMES: a regression is a time that GREW beyond
+    :data:`SWING_BAND_WIRE`.  Only scenarios that PASSED in both runs
+    are comparable; every flag is machine-readable: {"row", "prev",
+    "now", "grow_pct", "band"}."""
+    band = SWING_BAND_WIRE
     flags: list[dict] = []
 
     def check(name: str, new, old) -> None:

@@ -109,7 +109,7 @@ class RepoIndex:
 
     # -- construction ------------------------------------------------------
 
-    CODE_GLOBS = ("glusterfs_tpu/**/*.py", "tools/**/*.py", "bench.py",
+    CODE_GLOBS = ("glusterfs_tpu/**/*.py", "tools/**/*.py",
                   "__graft_entry__.py")
     TEST_GLOBS = ("tests/**/*.py",)
     DOC_GLOBS = ("docs/*.md",)

@@ -67,7 +67,7 @@ def _git_changed() -> list[str] | None:
     scannable = [c for c in changed
                  if c.endswith(".py") and
                  (c.startswith(("glusterfs_tpu/", "tools/", "tests/"))
-                  or c in ("bench.py", "__graft_entry__.py"))]
+                  or c == "__graft_entry__.py")]
     return sorted(set(scannable) | set(CHANGED_DEPS)) if scannable \
         else []
 

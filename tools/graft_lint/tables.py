@@ -210,7 +210,14 @@ CAPABILITIES = {
 #: Extra thread-context entry points the syntax cannot see (dynamic
 #: dispatch, callables stored then spawned elsewhere).  Key:
 #: ``path::Scope.func``; value: why this runs on a thread.
-CTX_THREAD_ENTRY: dict[str, str] = {}
+_LANE_ENTRY = "called by attribute from the lane's pool run " \
+    "(ops/batch ``_run``: ``getattr(codec, lane.entry)``), so that a " \
+    "replacement on the instance is what a flush calls"
+CTX_THREAD_ENTRY: dict[str, str] = {
+    "glusterfs_tpu/ops/batch.py::BatchingCodec.encode": _LANE_ENTRY,
+    "glusterfs_tpu/ops/batch.py::BatchingCodec.encode_delta": _LANE_ENTRY,
+    "glusterfs_tpu/ops/batch.py::BatchingCodec.decode": _LANE_ENTRY,
+}
 
 #: Extra loop-context entry points (callables registered with a loop
 #: through an indirection ctxgraph cannot follow).
