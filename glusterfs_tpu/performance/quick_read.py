@@ -3,7 +3,8 @@
 Reference: xlators/performance/quick-read (1.8k LoC): content of files
 under ``max-file-size`` is cached whole so repeated small-file reads
 skip the data path (the reference piggybacks content on lookup; here it
-is filled on first read and invalidated on writes)."""
+is filled on first read and invalidated on writes).  A file found over
+the limit is remembered as such (docs/read_path.md "The size probe")."""
 
 from __future__ import annotations
 
@@ -13,6 +14,9 @@ import time
 from ..core.layer import FdObj, Layer, Loc, register
 from ..core.options import Option
 from . import cache_metrics
+
+#: most files remembered as too big; the oldest go first
+TOO_BIG_MAX = 4096
 
 
 @register("performance/quick-read")
@@ -32,7 +36,7 @@ class QuickReadLayer(Layer):
 
         if event is Event.UPCALL and isinstance(data, dict) and \
                 data.get("gfid") and self.opts["cache-invalidation"]:
-            self._invalidate(data["gfid"])
+            self._forget(data["gfid"])
         super().notify(event, source, data)
 
     CACHE_KIND = "quick-read"  # the gftpu_cache_* {cache=...} label
@@ -48,11 +52,19 @@ class QuickReadLayer(Layer):
         # held-lease registry (api/glfs HeldLeases): leased content
         # never times out — a recall drops it via the upcall path
         self._lease_reg = None
-        # gfids known to exceed max-file-size (TTL'd): a large file
-        # must not pay a size probe on EVERY read just to learn, again,
-        # that it doesn't qualify (the reference learns size from the
-        # lookup it piggybacks content on)
-        self._too_big: dict[bytes, float] = {}
+        # gfids found over max-file-size, oldest first: a large file
+        # must not pay a size probe (an fstat through every layer
+        # below, a lookup wave on a disperse volume) to learn, again,
+        # that it doesn't qualify.  A hint, and it needs no clock and
+        # survives writes: all it ever does is send a read on to the
+        # child, which is always a right answer.  A stale one costs a
+        # file that shrank behind this mount its place in this cache
+        # until something seen here says it may be small: a truncate
+        # or an upcall (_forget), or a forwarded read that met EOF
+        # inside the limit (readv).  Never a wrong byte.
+        self._too_big: dict[bytes, None] = {}
+        self.size_probes = 0
+        self.forwarded_too_big = 0
         cache_metrics.track(self)
 
     def set_lease_registry(self, reg) -> None:
@@ -61,11 +73,23 @@ class QuickReadLayer(Layer):
     def _leased(self, gfid) -> bool:
         return self._lease_reg is not None and self._lease_reg.held(gfid)
 
-    def _invalidate(self, gfid: bytes) -> None:
+    def _drop_content(self, gfid: bytes) -> None:
         ent = self._files.pop(gfid, None)
         if ent is not None:
             self._bytes -= len(ent[1])
+
+    def _forget(self, gfid: bytes) -> None:
+        """The file may have become small: content and hint go."""
+        self._drop_content(gfid)
         self._too_big.pop(gfid, None)
+
+    def _store(self, gfid: bytes, content: bytes) -> None:
+        self._drop_content(gfid)  # replace, don't double-count
+        self._files[gfid] = (time.monotonic(), content)
+        self._bytes += len(content)
+        while self._bytes > self.opts["cache-size"] and self._files:
+            _, (_, old) = self._files.popitem(last=False)
+            self._bytes -= len(old)
 
     async def readv(self, fd: FdObj, size: int, offset: int,
                     xdata: dict | None = None):
@@ -80,10 +104,13 @@ class QuickReadLayer(Layer):
             self.hit_bytes += len(out)
             return out
         self.misses += 1
-        big = self._too_big.get(fd.gfid)
-        if big is not None and \
-                time.monotonic() - big < self.opts["cache-timeout"]:
-            return await self.children[0].readv(fd, size, offset, xdata)
+        if fd.gfid in self._too_big:
+            self.forwarded_too_big += 1
+            data = await self.children[0].readv(fd, size, offset, xdata)
+            if len(data) < size and offset + len(data) <= maxsz:
+                # EOF inside the limit: it shrank and nobody said so
+                self._too_big.pop(fd.gfid, None)
+            return data
         if size > maxsz:
             # a request larger than any qualifying file needs no size
             # probe — but it says nothing about the FILE's size (the
@@ -92,56 +119,55 @@ class QuickReadLayer(Layer):
             # turns out to BE a whole small file, cache it in passing.
             data = await self.children[0].readv(fd, size, offset, xdata)
             if offset == 0 and len(data) <= maxsz:
-                content = bytes(data)
-                self._invalidate(fd.gfid)  # replace, don't double-count
-                self._files[fd.gfid] = (time.monotonic(), content)
-                self._bytes += len(content)
-                while self._bytes > self.opts["cache-size"] \
-                        and self._files:
-                    _, (_, old) = self._files.popitem(last=False)
-                    self._bytes -= len(old)
+                self._store(fd.gfid, bytes(data))
             return data
+        self.size_probes += 1
         ia = await self.children[0].fstat(fd)
         if ia.size > maxsz:
-            self._too_big[fd.gfid] = time.monotonic()
-        if ia.size <= maxsz:
-            # bytes() copy: a memoryview off the wire blob lane would
-            # pin its whole RPC frame for the cache's lifetime
-            content = bytes(
-                await self.children[0].readv(fd, maxsz + 1, 0))
-            self._invalidate(fd.gfid)  # replace, don't double-count
-            self._files[fd.gfid] = (time.monotonic(), content)
-            self._bytes += len(content)
-            while self._bytes > self.opts["cache-size"] and self._files:
-                _, (_, old) = self._files.popitem(last=False)
-                self._bytes -= len(old)
-            return content[offset: offset + size]
-        return await self.children[0].readv(fd, size, offset, xdata)
+            self._too_big[fd.gfid] = None
+            if len(self._too_big) > TOO_BIG_MAX:
+                del self._too_big[next(iter(self._too_big))]
+            return await self.children[0].readv(fd, size, offset, xdata)
+        # bytes() copy: a memoryview off the wire blob lane would
+        # pin its whole RPC frame for the cache's lifetime
+        content = bytes(await self.children[0].readv(fd, maxsz + 1, 0))
+        self._store(fd.gfid, content)
+        return content[offset: offset + size]
 
     async def writev(self, fd: FdObj, data, offset: int,
                      xdata: dict | None = None):
-        self._invalidate(fd.gfid)
+        # content only: a write cannot make a file small
+        self._drop_content(fd.gfid)
         return await self.children[0].writev(fd, data, offset, xdata)
 
     async def ftruncate(self, fd: FdObj, size: int,
                         xdata: dict | None = None):
-        self._invalidate(fd.gfid)
-        return await self.children[0].ftruncate(fd, size, xdata)
+        self._drop_content(fd.gfid)
+        ia = await self.children[0].ftruncate(fd, size, xdata)
+        # after it, as truncate does: a probe beside it saw the old size
+        self._too_big.pop(fd.gfid, None)
+        return ia
 
     async def truncate(self, loc: Loc, size: int, xdata: dict | None = None):
         ia = await self.children[0].truncate(loc, size, xdata)
-        self._invalidate(ia.gfid)
+        self._forget(ia.gfid)
         return ia
 
     async def compound(self, links, xdata: dict | None = None) -> list:
         """Forward chains intact; replay the whole-file-cache
-        invalidation the per-fop write overrides would have done."""
+        invalidation the per-fop write overrides would have done.  The
+        replay does not say which fop a link was, so a chain's writev
+        drops the too-big hint as its truncate must: one probe more
+        after a chain, where no mount sends chains by default."""
         from ..rpc import compound as cfop
 
         replies = await self.children[0].compound(links, xdata)
-        cfop.replay_write_invalidation(links, replies, self._invalidate)
+        cfop.replay_write_invalidation(links, replies, self._forget)
         return replies
 
     def dump_private(self) -> dict:
         return {"files": len(self._files), "bytes": self._bytes,
-                "hits": self.hits, "misses": self.misses}
+                "hits": self.hits, "misses": self.misses,
+                "size_probes": self.size_probes,
+                "forwarded_too_big": self.forwarded_too_big,
+                "too_big_entries": len(self._too_big)}

@@ -23,6 +23,7 @@ full read-modify-write (ec-inode-write.c:2141 analog).  Pins:
 
 import asyncio
 import errno
+import gc
 import os
 
 import numpy as np
@@ -74,6 +75,10 @@ def test_sub_stripe_write_fop_counts_and_family(tmp_path):
         c.write_file("/f", data)
 
         def fam():
+            # every test's disperse layer is named "disp" and the
+            # registry holds layers weakly: free an earlier test's
+            # before reading, or it can stand in for the live one
+            gc.collect()
             snap = REGISTRY.snapshot()
             return {s[0]["layer"]: s[1]
                     for s in snap["gftpu_ec_delta_writes_total"]["samples"]}

@@ -170,6 +170,144 @@ def test_quick_read(tmp_path):
     c.close()
 
 
+def _fop_count(layer, fop: str) -> int:
+    st = layer.stats.get(fop)
+    return st.count if st is not None else 0
+
+
+@pytest.mark.parametrize("case", [
+    "survives_writev", "survives_cache_timeout", "ftruncate_drops",
+    "truncate_drops", "upcall_drops", "short_read_inside_limit_drops",
+    "short_read_past_limit_keeps", "capped", "grown_by_writes_is_probed",
+    "small_file_write_read_back"])
+def test_quick_read_too_big_hint(tmp_path, monkeypatch, case):
+    """What quick-read remembers about a file over max-file-size, and
+    what makes it forget: not a write and not the clock, but a
+    truncate, an upcall, or EOF seen inside the limit."""
+    import time
+
+    from glusterfs_tpu.core.layer import Event, FdObj
+    from glusterfs_tpu.performance import quick_read
+
+    c = _client(tmp_path, ("performance/quick-read",
+                           {"max-file-size": "1KB",
+                            "cache-timeout": "0.01"
+                            if case == "survives_cache_timeout" else "60"}))
+    qr = c.graph.top
+    posix = c.graph.by_name["posix"]
+    big = bytes(range(256)) * 20  # 5120 bytes, five times the limit
+
+    def probes() -> int:
+        # the layer's own count and the child's fstat calls agree
+        assert qr.dump_private()["size_probes"] == _fop_count(posix, "fstat")
+        return qr.dump_private()["size_probes"]
+
+    def small_again(f, content: bytes) -> None:
+        """The next small read probes and caches, the one after it is
+        served with no readv below."""
+        n = probes()
+        assert f.read(100, 0) == content[:100]
+        assert probes() == n + 1
+        assert qr.dump_private()["too_big_entries"] == 0
+        reads, hits = _fop_count(posix, "readv"), qr.hits
+        assert f.read(100, 10) == content[10:110]
+        assert _fop_count(posix, "readv") == reads
+        assert qr.hits == hits + 1 and probes() == n + 1
+
+    if case == "small_file_write_read_back":
+        c.write_file("/s", b"old bytes")
+        f = c.open("/s")
+        assert f.read(100, 0) == b"old bytes"
+        assert f.read(100, 0) == b"old bytes" and qr.hits == 1
+        f.write(b"NEW", 0)  # content invalidation on writev still there
+        assert f.read(100, 0) == b"NEW bytes"
+        assert qr.dump_private()["too_big_entries"] == 0
+        f.close()
+        c.close()
+        return
+    if case == "grown_by_writes_is_probed":
+        c.write_file("/g", b"tiny")
+        f = c.open("/g")
+        assert f.read(100, 0) == b"tiny" and probes() == 1
+        f.write(big, 0)
+        assert f.read(100, 0) == big[:100]  # probed anew, found too big
+        assert probes() == 2
+        assert qr.dump_private()["too_big_entries"] == 1
+        f.close()
+        c.close()
+        return
+    if case == "capped":
+        monkeypatch.setattr(quick_read, "TOO_BIG_MAX", 3)
+        files = []
+        for i in range(5):
+            c.write_file(f"/big{i}", big)
+            files.append(c.open(f"/big{i}"))
+            assert files[i].read(100, 0) == big[:100]
+        assert probes() == 5
+        assert qr.dump_private()["too_big_entries"] == 3
+        assert files[4].read(100, 0) == big[:100]  # a young one: kept
+        assert probes() == 5
+        assert files[0].read(100, 0) == big[:100]  # the oldest went
+        assert probes() == 6
+        assert qr.dump_private()["too_big_entries"] == 3
+        for f in files:
+            f.close()
+        c.close()
+        return
+
+    c.write_file("/big", big)
+    f = c.open("/big")
+    assert f.read(100, 0) == big[:100]
+    assert probes() == 1
+    assert f.read(100, 200) == big[200:300]
+    d = qr.dump_private()
+    assert (probes(), d["forwarded_too_big"], d["too_big_entries"]) == \
+        (1, 1, 1)
+
+    if case == "survives_writev":
+        f.write(b"x" * 10, 0)
+        assert f.read(100, 0) == b"x" * 10 + big[10:100]
+        assert probes() == 1
+        assert qr.dump_private()["forwarded_too_big"] == 2
+    elif case == "survives_cache_timeout":
+        time.sleep(0.05)
+        assert f.read(100, 0) == big[:100]
+        assert probes() == 1
+        assert qr.dump_private()["forwarded_too_big"] == 2
+    elif case == "ftruncate_drops":
+        f.ftruncate(300)
+        assert qr.dump_private()["too_big_entries"] == 0
+        small_again(f, big[:300])
+    elif case == "truncate_drops":
+        c.truncate("/big", 300)
+        assert qr.dump_private()["too_big_entries"] == 0
+        small_again(f, big[:300])
+    elif case == "upcall_drops":
+        qr.notify(Event.UPCALL, None, {"gfid": f.fd.gfid})
+        assert qr.dump_private()["too_big_entries"] == 0
+        assert f.read(100, 0) == big[:100]  # still big: probed, kept out
+        assert probes() == 2
+        assert qr.dump_private()["too_big_entries"] == 1
+    elif case == "short_read_inside_limit_drops":
+        # shrunk BEHIND the mount (another client, no upcall): straight
+        # through posix, where this layer sees nothing
+        anon = FdObj(f.fd.gfid, path="/big", anonymous=True)
+        c._run(posix.ftruncate(anon, 300))
+        assert f.read(100, 0) == big[:100]  # a full answer says nothing
+        assert qr.dump_private()["too_big_entries"] == 1
+        assert f.read(400, 0) == big[:300]  # EOF at 300, under the limit
+        assert probes() == 1
+        assert qr.dump_private()["too_big_entries"] == 0
+        small_again(f, big[:300])
+    elif case == "short_read_past_limit_keeps":
+        assert f.read(400, 5000) == big[5000:]  # EOF at 5120: still big
+        assert f.read(100, 6000) == b""
+        assert probes() == 1
+        assert qr.dump_private()["too_big_entries"] == 1
+    f.close()
+    c.close()
+
+
 def test_open_behind(tmp_path):
     c = _client(tmp_path, ("performance/open-behind", {}))
     posix = c.graph.by_name["posix"]
