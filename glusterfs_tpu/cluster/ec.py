@@ -868,10 +868,11 @@ class DisperseLayer(Layer):
         exception}.  argfn(i) -> (args, kwargs) per child.  One
         ``ec.fanout`` span: building every child's arguments (a write's
         ``tobytes()``) and the gather; the children's fop spans are its
-        children.  ``meta`` is more metadata of that span (the ``part``
-        of a wave sent in two)."""
+        children.  ``width`` on the span is the children called in this
+        part; ``meta`` is more metadata of it (the ``part`` of a wave
+        sent in two)."""
         with _tracing.phase(self.name, "ec.fanout", self.phases, op=op,
-                            **meta):
+                            width=len(idxs), **meta):
             return await self._dispatch_multi(
                 {i: (op, *argfn(i)) for i in idxs}, order=idxs)
 
@@ -1751,7 +1752,7 @@ class DisperseLayer(Layer):
             res = {}
             try:
                 with _tracing.phase(self.name, "ec.fanout", self.phases,
-                                    op="delta"):
+                                    op="delta", width=len(wave)):
                     res = await self._dispatch_multi(wave)
                 unsupported = {i for i, r in res.items()
                                if isinstance(r, FopError)

@@ -53,6 +53,12 @@ _FUSED_TS = 256  # stripes per grid step (measured best on v5e)
 # working set needs ts=128 (256 exceeded scoped VMEM).  That sweep ran
 # under an earlier libtpu and is not repeated on the local chip; with
 # these tiles every program compiles there (jax 0.9.0, libtpu 0.0.34).
+# What the local chip has measured for k=16 is the served launch (PR 30,
+# PERF.md section 5 N): a 1 MiB write of a 16+4 volume is 128 stripes,
+# one tile and one grid step of gf256_parity, 2.4 us of device time a
+# launch (1.25 MiB moved: two thirds of the HBM peak for the kernel
+# alone) with 5.0 us of layout copies beside it, parity_roofline 21.7%
+# for the launch whole.
 
 
 def _enc_ts(k: int) -> int:

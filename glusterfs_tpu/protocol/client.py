@@ -273,6 +273,9 @@ class ClientLayer(Layer):
         self.bytes_tx = 0
         self.bytes_rx = 0
         self.connects = 0
+        # count, seconds, slowest of the wire.send phase
+        # (tracing.phase_sums), shown by dump_private()
+        self.phases: dict = {}
         # circuit breaker (client.circuit-breaker): closed -> open on
         # consecutive transport failures -> half-open probe -> closed
         self._cb_state = "closed"
@@ -794,40 +797,47 @@ class ClientLayer(Layer):
         self._pending[xid] = fut
         lane = None
         try:
-            body = [fop, list(args), kwargs or {}]
-            if self._peer_trace and tracing.ENABLED and \
-                    self.opts["trace-fops"]:
-                # trailing trace-id element (the wire twin of the
-                # reference's frame->root): the server re-arms it so
-                # brick-graph spans carry THIS request's trace id.
-                # Handshake/ping frames predate _peer_trace or carry no
-                # fop context worth attributing.
-                tid = tracing.current_id()
-                if tid is not None:
-                    body.append(tid)
-            if self.opts["compression"]:
-                buf = wire.pack_z(
-                    xid, wire.MT_CALL, body,
-                    int(self.opts["compression-min-size"]),
-                    self.opts["compression-level"])
-                self.bytes_tx += len(buf)
-                writer.write(buf)
-            else:
-                # payload blobs ride out-of-band and writelines hands
-                # the ORIGINAL buffers to the transport — a writev
-                # payload is never copied on this side (iobref submit).
-                # With the shm lane armed (and the option still on —
-                # read per-call, so a live volume-set downgrades
-                # instantly), blobs land in the shared arena and only
-                # descriptors cross the socket
-                if self._peer_shm and self._shm_tx is not None \
-                        and not self._shm_tx.dead \
-                        and self.opts["shm-transport"]:
-                    lane = self._shm_tx
-                frames = wire.pack_frames(xid, wire.MT_CALL, body, lane)
-                self.bytes_tx += sum(len(f) for f in frames)
-                writer.writelines(frames)
-            await writer.drain()
+            # what this loop does for one call before it waits for the
+            # answer: the span of "the loop's CPU per wire call"
+            with tracing.phase(self.name, "wire.send", self.phases,
+                               fop=fop):
+                body = [fop, list(args), kwargs or {}]
+                if self._peer_trace and tracing.ENABLED and \
+                        self.opts["trace-fops"]:
+                    # trailing trace-id element (the wire twin of the
+                    # reference's frame->root): the server re-arms it so
+                    # brick-graph spans carry THIS request's trace id.
+                    # Handshake/ping frames predate _peer_trace or carry
+                    # no fop context worth attributing.
+                    tid = tracing.current_id()
+                    if tid is not None:
+                        body.append(tid)
+                if self.opts["compression"]:
+                    buf = wire.pack_z(
+                        xid, wire.MT_CALL, body,
+                        int(self.opts["compression-min-size"]),
+                        self.opts["compression-level"])
+                    sent = len(buf)
+                    writer.write(buf)
+                else:
+                    # payload blobs ride out-of-band and writelines hands
+                    # the ORIGINAL buffers to the transport — a writev
+                    # payload is never copied on this side (iobref
+                    # submit).  With the shm lane armed (and the option
+                    # still on — read per-call, so a live volume-set
+                    # downgrades instantly), blobs land in the shared
+                    # arena and only descriptors cross the socket
+                    if self._peer_shm and self._shm_tx is not None \
+                            and not self._shm_tx.dead \
+                            and self.opts["shm-transport"]:
+                        lane = self._shm_tx
+                    frames = wire.pack_frames(xid, wire.MT_CALL, body,
+                                              lane)
+                    sent = sum(len(f) for f in frames)
+                    writer.writelines(frames)
+                self.bytes_tx += sent
+                tracing.tag(bytes=sent)
+                await writer.drain()
         except (ConnectionError, RuntimeError):
             self._pending.pop(xid, None)
             await self._drop_connection()
@@ -1251,6 +1261,7 @@ class ClientLayer(Layer):
                 "bytes_rx": self.bytes_rx,
                 "connects": self.connects,
                 "rpc_roundtrips": self.rpc_roundtrips,
+                "phases": tracing.phase_sums(self.phases),
                 "shm": {"armed": self._peer_shm,
                         "refused": self._shm_refused,
                         "tx_used": (self._shm_tx.used()

@@ -655,6 +655,9 @@ class _Recorder:
     def __enter__(self):
         return self
 
+    def set_metadata(self, **meta):
+        self.meta.update(meta)
+
     def __exit__(self, *exc):
         import threading
 
@@ -910,6 +913,62 @@ def test_one_mib_through_4p2_yields_every_phase_once(tmp_path, recorder,
     # every span names its trace, itself and its parent
     assert all({"trace", "span", "parent"} <= set(m)
                for _n, m, _t in recorder.log)
+
+
+@pytest.mark.parametrize("compression", ["off", "on"])
+def test_wire_send_is_a_phase_of_every_call(tmp_path, recorder, monkeypatch,
+                                            compression):
+    """``protocol/client._call`` opens one ``wire.send`` per call, under
+    the ``protocol/client.<fop>`` span that made it, with the fop and
+    the bytes it put on the socket (on both framings); the layer's
+    ``dump_private()`` sums them, also with span work off."""
+    vf = CLIENT_VOLFILE.replace(
+        "end-volume", f"    option compression {compression}\n"
+                      "    option shm-transport off\nend-volume")
+
+    def count(dump):
+        return dump["phases"]["wire.send"]["count"]
+
+    async def run():
+        server = await serve_brick(BRICK_VOLFILE.format(dir=tmp_path / "b"))
+        c, g = await _connect(server.port, vf)
+        top = g.top
+        try:
+            f = await c.create("/x", os.O_RDWR)
+            before = top.dump_private()
+            tracing.ANNOTATE = recorder
+            recorder.log.clear()
+            await f.write(os.urandom(65536), 0)
+            assert bytes(await f.read(4096, 0))
+            tracing.ANNOTATE = None
+            after = top.dump_private()
+            sends = [m for n, m, _t in recorder.log
+                     if n == "gftpu:wire.send"]
+            calls = after["rpc_roundtrips"] - before["rpc_roundtrips"]
+            assert calls >= 2 and len(sends) == calls
+            assert {"writev", "readv"} <= {m["fop"] for m in sends}
+            assert sum(m["bytes"] for m in sends) == \
+                after["bytes_tx"] - before["bytes_tx"]
+            wrote, = [m for m in sends if m["fop"] == "writev"]
+            assert wrote["bytes"] > 65536 or compression == "on"
+            fops = {m["span"]: n for n, m, _t in recorder.log
+                    if n.startswith("gftpu:protocol/client.")}
+            assert all(fops[m["parent"]] ==
+                       "gftpu:protocol/client." + m["fop"] for m in sends)
+            assert count(after) - count(before) == calls
+            assert after["phases"]["wire.send"]["seconds"] > 0
+            # dark: the sums count on, the ring gets nothing
+            monkeypatch.setattr(tracing, "ENABLED", False)
+            tracing.SPANS.clear()
+            await f.write(b"dark", 0)
+            assert count(top.dump_private()) == count(after) + 1
+            assert not tracing.SPANS
+            await f.close()
+        finally:
+            await c.unmount()
+            await server.stop()
+
+    asyncio.run(run())
 
 
 def test_core_tracing_imports_no_jax():
