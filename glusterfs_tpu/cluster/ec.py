@@ -32,6 +32,7 @@ import asyncio
 import errno
 import struct
 from collections import Counter
+from typing import NamedTuple
 
 import numpy as np
 
@@ -45,7 +46,9 @@ from ..core import metrics as _metrics
 _LIVE_EC_LAYERS = _metrics.REGISTRY.register_objects(
     "gftpu_ec_read_fanout_total", "counter",
     "EC readv fan-outs by mode (fast = zero-staging systematic "
-    "reassembly, staged = decode through the frags array)",
+    "reassembly, staged = decode through the frags array; overlapped "
+    "= reads begun while another read of the inode was in flight, "
+    "each counted under its mode too)",
     lambda l: [({"layer": l.name, "mode": m}, v)
                for m, v in l.read_fanout.items()])
 _metrics.REGISTRY.register_objects(
@@ -130,6 +133,17 @@ class ECFdCtx:
         self.flags = flags
 
 
+class _Range(NamedTuple):
+    """A wave in flight over the stripes [off, end) of an eager
+    window: a write's (exclusive) or a read's (``shared``); ``done``
+    resolves when it has left."""
+
+    off: int
+    end: int
+    shared: bool
+    done: asyncio.Future
+
+
 class _EagerState:
     """One held eager transaction window (the ec_lock_t analog,
     ec-common.c:2176 eager-lock reuse + delayed post-op): the cluster
@@ -155,33 +169,43 @@ class _EagerState:
         self.timer = None             # deferred-release handle
         self.opened = opened          # loop time: bounds total hold
         # parallel-writes state (ec_is_range_conflict, ec-common.c:185):
-        # non-conflicting write waves run outside the local gfid lock
+        # non-conflicting waves run outside the local gfid lock.  A
+        # range is a write's (exclusive) or a read's (shared): reads
+        # never conflict with each other (EC_FLAG_LOCK_SHARED,
+        # ec_lock_assign_owner), a read and a write over the same
+        # stripes do, both ways
         self.inflight = 0             # write-class waves mid-dispatch
         self.idle = asyncio.Event()   # set while inflight == 0
         self.idle.set()
         self.pre_landed = asyncio.Event()  # dirty+1 is ON the bricks
-        self.ranges: dict[int, tuple[int, int, asyncio.Future]] = {}
+        self.ranges: dict[int, _Range] = {}
         self.rseq = 0
 
-    def conflict(self, a_off: int, a_end: int) -> "asyncio.Future | None":
-        """Completion future of an overlapping in-flight write, if any."""
-        for off, end, fut in self.ranges.values():
-            if off < a_end and a_off < end:
-                return fut
+    def conflict(self, a_off: int, a_end: int,
+                 shared: bool = False) -> "asyncio.Future | None":
+        """Completion future of an in-flight range that [a_off, a_end)
+        may not run beside, if any: an overlapping write's, and for a
+        write (``shared`` false) an overlapping read's too."""
+        for r in self.ranges.values():
+            if r.off < a_end and a_off < r.end and \
+                    not (shared and r.shared):
+                return r.done
         return None
 
-    def add_range(self, a_off: int, a_end: int) -> int:
+    def add_range(self, a_off: int, a_end: int,
+                  shared: bool = False) -> int:
         self.rseq += 1
-        fut = asyncio.get_running_loop().create_future()
-        self.ranges[self.rseq] = (a_off, a_end, fut)
+        self.ranges[self.rseq] = _Range(
+            a_off, a_end, shared,
+            asyncio.get_running_loop().create_future())
         return self.rseq
 
     def del_range(self, token: int) -> None:
         """Lock-free on purpose: waiters may hold the gfid lock while
         they wait for us (quiesce), so removal must not need it."""
         ent = self.ranges.pop(token, None)
-        if ent is not None and not ent[2].done():
-            ent[2].set_result(None)
+        if ent is not None and not ent.done.done():
+            ent.done.set_result(None)
 
 
 @register("cluster/disperse")
@@ -335,7 +359,7 @@ class DisperseLayer(Layer):
         # read fan-out accounting (ISSUE 3): "fast" = healthy systematic
         # reassembly straight from fragment buffers (no staging copy),
         # "staged" = the decode path through the frags array
-        self.read_fanout = {"fast": 0, "staged": 0}
+        self.read_fanout = {"fast": 0, "staged": 0, "overlapped": 0}
         # fragment-readv coalescing (ROADMAP item 7): adjacent readv
         # links of one compound chain merged into ONE ranged brick
         # read per fan-out
@@ -764,11 +788,13 @@ class DisperseLayer(Layer):
             await self._eager_flush(loc, gfid)
 
     async def _quiesce_writes(self, st: _EagerState) -> None:
-        """Wait out in-flight parallel write waves.  Callers hold the
+        """Wait out the in-flight parallel waves, writes and reads
+        alike (a read's range is shared, and what settles the window
+        may change the bytes or the size under it).  Callers hold the
         local gfid lock, so no NEW wave can register while we wait
         (registration needs that lock); completion is lock-free."""
         while st.ranges:
-            await next(iter(st.ranges.values()))[2]
+            await next(iter(st.ranges.values())).done
         while st.inflight:
             await st.idle.wait()
 
@@ -783,9 +809,11 @@ class DisperseLayer(Layer):
         if st.timer is not None:
             st.timer.cancel()
             st.timer = None
-        # quiesce parallel-writes waves first: the post-op must describe
-        # a settled window.  New waves can't start — registration needs
-        # the gfid lock we hold; removal is lock-free so they can drain.
+        # quiesce the parallel waves first: the post-op must describe
+        # a settled window, and the unlock must not leave a read's
+        # fan-out without the inodelk.  New waves can't start —
+        # registration needs the gfid lock we hold; removal is
+        # lock-free so they can drain.
         await self._quiesce_writes(st)
         self._eager.pop(gfid, None)
         # commit gfid-addressed, NOT by the window-open path: a rename
@@ -1422,32 +1450,53 @@ class DisperseLayer(Layer):
 
     async def readv(self, fd: FdObj, size: int, offset: int,
                     xdata: dict | None = None):
+        """Read under the eager window (disperse.other-eager-lock):
+        the first read on an inode pays one lock-and-fetch wave,
+        consecutive reads pay ONLY the fragment wave — without this
+        every kernel-readahead chunk through the mount costs lock +
+        meta + unlock waves of pure latency.
+
+        Reads of one inode run side by side (ISSUE 31), in
+        :meth:`writev`'s shape: the local gfid lock covers only the
+        window's bookkeeping — join the window, register the stripe
+        range as *shared* — and the fan-out and the decode run outside
+        it.  A shared range conflicts with no other read
+        (EC_FLAG_LOCK_SHARED, ec-common.c ec_lock_assign_owner) and
+        with every write over its stripes, both ways: the read waits
+        for a write in flight there, a write waits for the read, so no
+        read decodes a torn stripe (half old, half new fragments).
+        Whatever settles the window (its close and post-op, truncate,
+        the allocation fops, ``parallel-writes off``) waits for the
+        reads in flight as for the writes.  The non-eager path
+        (``_Txn``) serializes same-inode reads on the local lock as
+        it did."""
         loc = Loc(fd.path, gfid=fd.gfid)
         if self.opts["eager-lock"] and self.opts["other-eager-lock"]:
-            # reads share the eager window (disperse.other-eager-lock):
-            # the first read on an inode pays one lock-and-fetch wave,
-            # consecutive reads pay ONLY the fragment wave — without
-            # this every kernel-readahead chunk through the mount costs
-            # lock + meta + unlock waves of pure latency.  Same-inode
-            # ops serialize on the local gfid lock (the reference
-            # chains same-inode fops on the lock owner too).
+            a_off = offset // self.stripe * self.stripe
+            a_end = (offset + size + self.stripe - 1) \
+                // self.stripe * self.stripe
             while True:
                 async with self._LockedWindow(self, loc, fd.gfid) as st:
-                    # a parallel write mid-dispatch over our range could
-                    # hand us a torn stripe (half old, half new
-                    # fragments) — wait it out like a conflicting write
-                    a_off = offset // self.stripe * self.stripe
-                    a_end = (offset + size + self.stripe - 1) \
-                        // self.stripe * self.stripe
-                    blocker = st.conflict(a_off, a_end)
+                    blocker = st.conflict(a_off, a_end, shared=True)
                     if blocker is None:
-                        try:
-                            return await self._readv_window(
-                                fd, size, offset, st.candidates, st.size)
-                        finally:
-                            await self._eager_end(loc, fd.gfid)
+                        if any(r.shared for r in st.ranges.values()):
+                            self.read_fanout["overlapped"] += 1
+                        token = st.add_range(a_off, a_end, shared=True)
+                        # the window's view when the range was taken:
+                        # a disjoint write beside us may grow the size
+                        # or drop a brick, neither under our stripes
+                        candidates, true_size = st.candidates, st.size
+                        break
+                # a write mid-dispatch over our stripes: wait it out
                 with _tracing.phase(self.name, "ec.lock", self.phases):
                     await blocker
+            try:
+                return await self._readv_window(fd, size, offset,
+                                                candidates, true_size)
+            finally:
+                st.del_range(token)  # lock-free: wakes conflict waiters
+                async with self._lock(fd.gfid):
+                    await self._eager_end(loc, fd.gfid)
         async with self._Txn(self, loc, fd.gfid, "rd",
                              fetch=True) as txn:
             candidates, true_size = await self._txn_meta(txn)
@@ -1932,20 +1981,21 @@ class DisperseLayer(Layer):
         a_end = (end + self.stripe - 1) // self.stripe * self.stripe
         while True:
             async with self._LockedWindow(self, loc, fd.gfid) as st:
-                if not st.pre_landed.is_set():
+                blocker = st.conflict(a_off, a_end)
+                if blocker is None and not st.pre_landed.is_set():
                     # the window's first write runs solo under the lock:
                     # it carries the compound pre-op, and dirty+1 must
                     # be ON the bricks before any concurrent data wave
+                    # (reads over other stripes may be in flight)
                     try:
                         return await self._writev_in_window(
                             fd, loc, st, data, offset)
                     finally:
                         await self._eager_end(loc, fd.gfid)
-                blocker = st.conflict(a_off, a_end)
                 if blocker is None:
                     token = st.add_range(a_off, a_end)
                     break
-            # overlapping write in flight: wait, retry
+            # overlapping write or read in flight: wait, retry
             with _tracing.phase(self.name, "ec.lock", self.phases):
                 await blocker
         try:

@@ -1,4 +1,5 @@
-"""performance/read-ahead: which pages an fd keeps (ISSUE 27).
+"""performance/read-ahead: which pages an fd keeps (ISSUE 27) and how
+many fetches a stream has in flight (ISSUE 31).
 
 One parametrised test over a stub child that logs every ``readv`` as
 ``(size, offset)``: no brick, no wire, nothing timed.  The layer is
@@ -21,7 +22,8 @@ class StubChild(Layer):
     """A file in memory.  A ``readv`` whose offset is in ``held`` takes
     its bytes at once, as a brick would, and answers only when
     ``gate`` is set: a fetch in flight across whatever the case does
-    meanwhile."""
+    meanwhile.  With ``slow`` every ``readv`` waits in ``pending``
+    until the case answers it (:func:`_drive`)."""
 
     def __init__(self, *args, **kw):
         super().__init__(*args, **kw)
@@ -29,11 +31,16 @@ class StubChild(Layer):
         self.log: list[tuple[int, int]] = []
         self.held: set[int] = set()
         self.gate = asyncio.Event()
+        self.slow = False
+        self.pending: list[asyncio.Future] = []
 
     async def readv(self, fd, size, offset, xdata=None):
         self.log.append((size, offset))
         data = bytes(self.data[offset:offset + size])
-        if offset in self.held:
+        if self.slow:
+            self.pending.append(asyncio.get_running_loop().create_future())
+            await self.pending[-1]
+        elif offset in self.held:
             await self.gate.wait()
         else:
             await asyncio.sleep(0)
@@ -68,10 +75,19 @@ class Rig:
         return self.fd.ctx_get(self.ra)
 
     async def settle(self):
-        """Let a fetch in flight land (not a held one)."""
-        task = self.ctx.task
-        if task is not None and not self.stub.held:
-            await asyncio.wait_for(asyncio.shield(task), 5)
+        """Let the fetches in flight land (not held ones)."""
+        if not self.stub.held and not self.stub.slow:
+            for f in list(self.ctx.fetches):
+                await asyncio.wait_for(asyncio.shield(f.task), 5)
+
+    def ahead(self) -> int:
+        """Pages held or on their way beyond the read in hand."""
+        nxt = -(-self.ctx.next_offset // PSZ)
+        pages = set(self.ctx.pages)
+        for f in self.ctx.fetches:
+            if f.live:
+                pages.update(range(f.first, f.first + f.pages))
+        return sum(1 for i in pages if i >= nxt)
 
     async def read(self, size: int, offset: int, settle: bool = True):
         got = bytes(await asyncio.wait_for(
@@ -80,7 +96,7 @@ class Rig:
         self.door_bytes += len(got)
         if settle:
             await self.settle()
-        held = len(self.ctx.pages)
+        held = len(self.ctx.pages) if self.ctx else 0  # released under it
         assert held <= COUNT + -(-size // PSZ), (held, size, offset)
         return got
 
@@ -157,7 +173,7 @@ async def _stale_fetch(rig: Rig, then: str):
     await rig.stream(WINDOW, 0, 5 * WINDOW)
     rig.stub.held.add(6 * WINDOW)
     await rig.read(WINDOW, 5 * WINDOW)  # starts the fetch of window 6
-    task = rig.ctx.task
+    task = rig.ctx.fetches[-1].task
     await asyncio.sleep(0)  # the child has taken the bytes it will answer
     assert not task.done() and rig.stub.log[-1] == (WINDOW, 6 * WINDOW)
     if then == "write":
@@ -224,6 +240,143 @@ async def _page_bound(rig: Rig):
     assert rig.ra.dump_private()["hits"] > 0
 
 
+async def _drive(rig: Rig, body, each=lambda: None) -> int:
+    """Run ``body`` over a child that answers one ``readv`` at a time
+    and only when nothing else can move: every fetch stays in flight
+    as long as the stream lets it.  ``each`` runs before every answer.
+    Returns the most fetches the fd had in flight at once, after
+    holding every moment to the bound: never more than one window held
+    or on its way beyond the read in hand."""
+    rig.stub.slow = True
+    task = asyncio.create_task(body)
+    most = 0
+    while not task.done():
+        for _ in range(8):
+            await asyncio.sleep(0)
+        ctx = rig.ctx
+        if ctx is not None:
+            most = max(most, len(ctx.fetches))
+            assert rig.ahead() <= COUNT, (rig.ahead(), ctx.next_offset)
+        each()
+        if rig.stub.pending:
+            rig.stub.pending.pop(0).set_result(None)
+    await task
+    rig.stub.slow = False
+    return most
+
+
+def _asked_twice(log, start: int = 0) -> list:
+    """The child reads from ``start`` on that ask for bytes an earlier
+    one of them asked for."""
+    seen: list[tuple[int, int]] = []
+    twice = []
+    for size, off in log:
+        if off < start:
+            continue
+        if any(off < e and o < off + size for o, e in seen):
+            twice.append((size, off))
+        seen.append((off, off + size))
+    return twice
+
+
+async def _two_in_flight(rig: Rig, ramp: int):
+    """(k), (l) a sequential stream of one window a read over a slow
+    child: two fetches in flight (the one the read is parked on and
+    the one ahead), never three; past the ramp's ``ramp`` windows no
+    range is asked of the child twice and the ranges tile the file."""
+    size = len(rig.stub.data)
+    most = await _drive(rig, rig.stream(WINDOW, 0, size))
+    assert most == 2
+    st = rig.ra.dump_private()
+    assert st["fetches_overlapped"] >= size // WINDOW - ramp - 2
+    assert st["phases"]["ra.wait"]["count"] == st["waited_on_prefetch"] \
+        >= size // WINDOW - ramp - 1
+    log = rig.stub.log
+    assert not _asked_twice(log, ramp * WINDOW)
+    tiles = sorted((off, off + sz) for sz, off in log
+                   if off >= ramp * WINDOW)
+    assert tiles[0][0] == ramp * WINDOW and all(
+        a[1] == b[0] for a, b in zip(tiles, tiles[1:]))
+    assert tiles[-1][1] >= size
+    assert rig.dropped("seek", "stale_fetch", "passed") <= 7  # the ramp
+
+
+async def _small_reads_slow_child(rig: Rig):
+    """(m) a stream of one-page reads over a slow child: pages on
+    their way count as ahead, so the child is still asked once per
+    half window, and no range twice."""
+    size = len(rig.stub.data)
+    most = await _drive(rig, rig.stream(PSZ, 0, size))
+    assert most <= 2
+    assert len(rig.stub.log) <= size // PSZ // (COUNT // 2) + 4
+    assert not _asked_twice(rig.stub.log, 4 * WINDOW)
+
+
+async def _park_with_one_ahead(rig: Rig) -> asyncio.Task:
+    """A reader of window 5 parked on its fetch, the fetch of window 6
+    in flight beside it, the child answering neither."""
+    await rig.stream(WINDOW, 0, 4 * WINDOW)
+    rig.stub.slow = True
+    await rig.read(WINDOW, 4 * WINDOW)  # served; starts the fetch of 5
+    reader = asyncio.create_task(rig.read(WINDOW, 5 * WINDOW,
+                                          settle=False))
+    for _ in range(8):
+        await asyncio.sleep(0)
+    assert not reader.done()
+    return reader
+
+
+async def _two_stale(rig: Rig, then: str):
+    """(n) a seek, a write or a truncate while two fetches fly: both
+    land, both are discarded, no page of theirs is left behind."""
+    reader = await _park_with_one_ahead(rig)
+    # parked on the fetch of window 5, the fetch of window 6 beside it
+    assert [(f.first, f.pages) for f in rig.ctx.fetches] == [
+        (5 * COUNT, COUNT), (6 * COUNT, COUNT)]
+    tasks = [f.task for f in rig.ctx.fetches]
+    before = rig.dropped("stale_fetch")
+    if then == "seek":
+        other = asyncio.create_task(rig.read(WINDOW, 20 * WINDOW,
+                                             settle=False))
+    elif then == "write":
+        other = asyncio.create_task(
+            rig.ra.writev(rig.fd, b"\xee" * WINDOW, 6 * WINDOW))
+    else:
+        other = asyncio.create_task(
+            rig.ra.ftruncate(rig.fd, 6 * WINDOW + 5))
+    for _ in range(8):
+        await asyncio.sleep(0)
+    assert not any(f.live for f in rig.ctx.fetches)
+    rig.stub.slow = False
+    for fut in rig.stub.pending:
+        fut.set_result(None)
+    rig.stub.pending.clear()
+    await asyncio.gather(reader, other, *tasks)
+    assert rig.dropped("stale_fetch") == before + 2 * COUNT
+    # the reader that was parked was answered by a demand of its own;
+    # nothing the two fetches brought is held
+    assert not rig.ctx.fetches
+    assert all(i >= 21 * COUNT for i in rig.ctx.pages) if then == "seek" \
+        else not rig.ctx.pages
+    if then == "write":
+        assert await rig.read(WINDOW, 6 * WINDOW) == b"\xee" * WINDOW
+    elif then == "truncate":
+        assert len(await rig.read(WINDOW, 6 * WINDOW)) == 5
+
+
+async def _release_cancels(rig: Rig):
+    """(o) ``release`` cancels every fetch in flight."""
+    reader = await _park_with_one_ahead(rig)
+    tasks = [f.task for f in rig.ctx.fetches]
+    assert len(tasks) == 2
+    ctx = rig.ctx
+    await rig.ra.release(rig.fd)
+    rig.stub.slow = False
+    await asyncio.wait_for(reader, 5)  # answered by a demand of its own
+    assert all(t.cancelled() for t in tasks) and not ctx.fetches
+    rig.fd.ctx_del(rig.ra)  # the late demand's
+
+
 CASES = {
     "a-wrap": (_wrap, 40 * WINDOW, {}),
     "b-seek-back-one-window": (lambda r: _seek(r, 7 * WINDOW),
@@ -241,6 +394,23 @@ CASES = {
                               {"compound-fops": "on"}),
     "j-wrap-fixed-window": (_wrap, 40 * WINDOW,
                             {"adaptive-window": "off"}),
+    "k-two-in-flight-fixed-window": (
+        lambda r: _two_in_flight(r, 0), 40 * WINDOW,
+        {"adaptive-window": "off"}),
+    "l-two-in-flight-after-ramp": (
+        lambda r: _two_in_flight(r, 5), 40 * WINDOW, {}),
+    "l-two-in-flight-compound": (
+        lambda r: _two_in_flight(r, 5), 40 * WINDOW,
+        {"compound-fops": "on"}),
+    "m-small-reads-slow-child": (_small_reads_slow_child,
+                                 24 * WINDOW, {}),
+    "n-two-in-flight-across-seek": (
+        lambda r: _two_stale(r, "seek"), 40 * WINDOW, {}),
+    "n-two-in-flight-across-write": (
+        lambda r: _two_stale(r, "write"), 40 * WINDOW, {}),
+    "n-two-in-flight-across-truncate": (
+        lambda r: _two_stale(r, "truncate"), 40 * WINDOW, {}),
+    "o-release-cancels-every-fetch": (_release_cancels, 40 * WINDOW, {}),
 }
 
 

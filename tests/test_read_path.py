@@ -243,6 +243,12 @@ end-volume
             for i in range(16):
                 got = await g.top.readv(f.fd, 4096, i * 4096)
                 out += bytes(got)
+            # the look-ahead the last read decided on counts on both
+            # sides, whether it had left before that read was served
+            # (the read parked) or not
+            await asyncio.sleep(0)
+            while f.fd.ctx_get(g.top).fetches:
+                await asyncio.sleep(0.01)
             rts = cl.rpc_roundtrips - base
             await f.close()
             await c.unmount()
