@@ -360,6 +360,9 @@ class DisperseLayer(Layer):
         # reassembly straight from fragment buffers (no staging copy),
         # "staged" = the decode path through the frags array
         self.read_fanout = {"fast": 0, "staged": 0, "overlapped": 0}
+        # data rows the staged reads had the codec rebuild (a statedump's
+        # number, beside the modes and not one of them)
+        self.rows_rebuilt = 0
         # fragment-readv coalescing (ROADMAP item 7): adjacent readv
         # links of one compound chain merged into ONE ranged brick
         # read per fan-out
@@ -1399,7 +1402,8 @@ class DisperseLayer(Layer):
             rows = self._read_children(avail, fd.gfid, mask=mask)
             res = await self._dispatch(
                 rows, "readv",
-                lambda i: ((self._child_fd(fd, i), f_len, f_off), {}))
+                lambda i: ((self._child_fd(fd, i), f_len, f_off), {}),
+                parity=sum(1 for i in rows if i >= self.k))
             good = {i: r for i, r in res.items()
                     if not isinstance(r, BaseException)}
             if len(good) < self.k:
@@ -1424,6 +1428,7 @@ class DisperseLayer(Layer):
                     self.read_fanout["fast"] += 1
                     return fast
                 self.read_fanout["staged"] += 1
+                self.rows_rebuilt += self.codec.rebuilt_rows(rows_sorted)
                 frags = np.zeros((self.k, f_len), dtype=np.uint8)
                 for j, buf in enumerate(bufs):
                     arr = np.frombuffer(buf, dtype=np.uint8)
@@ -2406,7 +2411,8 @@ class DisperseLayer(Layer):
             "stripe_size": self.stripe,
             "backend": self.codec.backend,
             "up": self.up, "up_count": sum(self.up),
-            "read_fanout": dict(self.read_fanout),
+            "read_fanout": dict(self.read_fanout,
+                                rows_rebuilt=self.rows_rebuilt),
             "read_coalesced": dict(self.read_coalesced),
             "write_path": dict(self.write_path),
             "delta_saved": dict(self.delta_saved),

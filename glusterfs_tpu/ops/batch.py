@@ -681,6 +681,9 @@ class BatchingCodec(Codec):
             meta = {"op": lane.op, "route": kind, "fops": len(batch),
                     "bytes": total, "stripes": stripes,
                     "bucket_stripes": bucket}
+            if key:  # a decode: the rows its launch reads and rebuilds
+                meta["rows_in"] = len(key[0])
+                meta["rows_out"] = self.rebuilt_rows(key[0])
             others = [str(q.origin[2]) for *_, q in batch[1:] if q.origin]
             if others:
                 meta["others"] = ",".join(others)

@@ -476,6 +476,15 @@ class Codec:
             return _interleave(self.k, zip(rows, frags))
         return self._impl.decode(frags, rows, self.systematic)
 
+    def rebuilt_rows(self, rows) -> int:
+        """How many of the k data rows a decode from the fragments
+        ``rows`` computes: on the systematic code those that are not
+        among them, on the reference's all k (none of its fragments is
+        the stripe's own bytes)."""
+        if not self.systematic:
+            return self.k
+        return self.k - sum(1 for r in rows if r < self.k)
+
     def encode_delta(self, delta: np.ndarray) -> np.ndarray:
         """Parity-fragment deltas ((n-k), len/k) of a stripe-aligned
         XOR delta — the sub-stripe write primitive (parity-delta /

@@ -141,6 +141,20 @@ def test_small_writers_fill_flushes_on_both_codecs(tmp_path, recorder,
     assert sum(st["padded_stripes"] for st in stats) == \
         sum(m["bucket_stripes"] for m in flushes + decoded)
     assert all(st["padded_stripes"] >= st["stripes"] for st in stats)
+    # a decode flush says what its one mask hands in and rebuilds: four
+    # survivors, and the data rows that are not among them; every fop
+    # of it is one staged read, which the layer's dump sums, and whose
+    # wave called as many parity bricks as rows were missing
+    assert all(m["rows_in"] == K and 1 <= m["rows_out"] <= R
+               for m in decoded), decoded[:3]
+    rebuilt = [ec.dump_private()["read_fanout"] for ec in ecs]
+    assert sum(d["rows_rebuilt"] for d in rebuilt) == \
+        sum(m["rows_out"] * m["fops"] for m in decoded)
+    waves = [m for name, m in recorder.log
+             if name == "gftpu:ec.fanout" and m["op"] == "readv"]
+    assert waves and all(m["width"] == K for m in waves)
+    assert sum(m["parity"] for m in waves) == \
+        sum(d["rows_rebuilt"] for d in rebuilt)
 
     # cluster/dht: the dump's count per subvolume is the spans' count
     routed = dht.dump_private()["routed"]

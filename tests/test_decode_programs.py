@@ -10,7 +10,8 @@ the bench sweep and a sampled set of surviving-fragment masks, across
 the program-consuming backends (NumPy program walk, native
 gf_decode_prog, XLA xor unroll, Pallas fused interpret), plus the
 systematic ``reconstruct`` partial decode with 1 and 2 missing data
-rows, and LRU eviction/recompile behavior.
+rows (3 and 4 at the wide geometries: what a server down costs an 8+4
+volume), and LRU eviction/recompile behavior.
 """
 
 import itertools
@@ -234,6 +235,42 @@ def test_pallas_reconstruct_partial_decode(k, r, n_missing):
         assert np.array_equal(
             rec[i], np.ascontiguousarray(full[:, j, :]).reshape(-1)), \
             f"row {j}"
+
+
+@pytest.mark.parametrize("k,r", [(8, 4), (16, 4)])
+@pytest.mark.parametrize("n_missing", [3, 4])
+@pytest.mark.parametrize("via", ["program", "pallas"])
+def test_reconstruct_of_three_and_four_rows(k, r, n_missing, via):
+    """The wide rebuilds: redundancy-many (and one fewer) data rows
+    lost from the middle of the stripe, parity rows standing in.  At
+    (8, 4) with four missing the survivors are (0, 1, 2, 3, 8, 9, 10,
+    11): the one mask of ``ec-8p4-tpu`` with a server down, whose
+    program the benchmark's cells launch."""
+    from glusterfs_tpu.ops import gf256_pallas
+
+    n = k + r
+    data = _data(k, stripes=3, seed=19 * k + r + n_missing)
+    frags = gf256.ref_encode(data, k, n, systematic=True)
+    missing = tuple(range(k // 2, k // 2 + n_missing))
+    rows = tuple(x for x in range(n) if x not in missing)[:k]
+    assert sum(1 for x in rows if x >= k) == n_missing
+    s = data.size // (k * gf256.CHUNK_SIZE)
+    want = np.ascontiguousarray(
+        data.reshape(s, k, gf256.CHUNK_SIZE).transpose(1, 0, 2)
+    ).reshape(k, -1)[list(missing)]
+    assert np.array_equal(want, frags[list(missing)])
+    if via == "pallas":
+        rec = gf256_pallas.reconstruct(frags[list(rows)], rows, missing, k,
+                                       interpret=True)
+        assert np.array_equal(rec, want)
+        return
+    prog = gf256.reconstruct_program(k, rows, missing)
+    assert prog.n_inputs == k * 8 and len(prog.outs) == n_missing * 8
+    got = gf256.run_xor_program(
+        prog, gf256.frags_to_planes(frags[list(rows)], k))
+    for i in range(n_missing):
+        assert np.array_equal(
+            got[:, i * 8:(i + 1) * 8, :].reshape(-1), want[i]), missing[i]
 
 
 @pytest.mark.skipif(not native.available(), reason="no native toolchain")

@@ -140,13 +140,13 @@ def _spy_dispatch(ec, widen=0.05):
     state = {"active": 0, "max": 0}
     orig = ec._dispatch
 
-    async def spy(idxs, op, argfn):
+    async def spy(idxs, op, argfn, **meta):
         if op == "writev":
             state["active"] += 1
             state["max"] = max(state["max"], state["active"])
             await asyncio.sleep(widen)
         try:
-            return await orig(idxs, op, argfn)
+            return await orig(idxs, op, argfn, **meta)
         finally:
             if op == "writev":
                 state["active"] -= 1

@@ -491,6 +491,9 @@ def test_ec_systematic_degraded_read_mask_identical(tmp_path):
         degraded = await c.read_file("/d")
         assert degraded == healthy == payload
         assert ec.read_fanout["staged"] > 0  # reconstruction ran
+        # data rows 0 and 3 rebuilt in every staged read
+        assert ec.dump_private()["read_fanout"]["rows_rebuilt"] == \
+            2 * ec.read_fanout["staged"]
         ec._read_mask = None
         await c.unmount()
 
@@ -510,6 +513,9 @@ def test_ec_nonsystematic_stays_staged(tmp_path):
         assert await c.read_file("/n") == payload
         assert ec.read_fanout["fast"] == 0
         assert ec.read_fanout["staged"] > 0
+        # no row of the upstream format is the stripe's own bytes
+        assert ec.dump_private()["read_fanout"]["rows_rebuilt"] == \
+            ec.k * ec.read_fanout["staged"]
         await c.unmount()
 
     asyncio.run(run())
