@@ -539,7 +539,7 @@ def test_wb_stripe_cut_points_unit(tmp_path):
         fd = FdObj(b"\0" * 16)
         ctx = wb._ctx(fd)
         wb._absorb(ctx, b"a" * (2 * STRIPE + 300), 0)
-        await wb._drain(fd, ctx, partial=True)
+        await wb._send(fd, ctx, wb._cut(ctx, partial=True))
         assert rec.writes == [(0, 2 * STRIPE)], rec.writes
         assert ctx.chunks == [(2 * STRIPE, bytearray(b"a" * 300))]
         assert ctx.bytes == 300
@@ -550,7 +550,7 @@ def test_wb_stripe_cut_points_unit(tmp_path):
         assert ctx.chunks == []
         # all-sub-stripe window: partial drain must still flush
         wb._absorb(ctx, b"c" * 100, 0)
-        await wb._drain(fd, ctx, partial=True)
+        await wb._send(fd, ctx, wb._cut(ctx, partial=True))
         assert rec.writes[-1] == (0, 100)
         assert ctx.chunks == []
         assert wb.window_bytes == 0
