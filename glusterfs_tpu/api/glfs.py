@@ -26,6 +26,7 @@ import os
 import threading
 from typing import Any
 
+from ..core import tracing
 from ..core.fops import FopError
 from ..core.graph import Graph
 from ..core.iatt import Iatt, ROOT_GFID
@@ -311,6 +312,9 @@ class Client:
         # api-consumer upcall hooks: callbacks fired as (gfid) on every
         # server-pushed invalidation (gateway ETag memo, embedders)
         self.on_invalidate: list = []
+        # the meter of the loop this client is mounted on (mount to
+        # unmount; None on a loop that polls through no selector)
+        self.loop_meter: tracing.LoopMeter | None = None
         # QoS traffic attribution (features/qos): set BEFORE mount()
         # so the first handshake already carries it; "" = ordinary
         # client, "rebalance" rides the brick's paced lane
@@ -344,9 +348,15 @@ class Client:
         if self.upcall_sink not in self.graph.top.parents:
             self.graph.top.parents.append(self.upcall_sink)
         self._wire_lease_registry(self.graph.top)
+        if self.loop_meter is None:
+            self.loop_meter = tracing.LoopMeter.install(
+                asyncio.get_running_loop())
         self.mounted = True
 
     async def unmount(self) -> None:
+        if self.loop_meter is not None:
+            self.loop_meter.remove()
+            self.loop_meter = None
         # cancel AND await the watchers: a mid-flight reload() must
         # finish its cleanup before we fini the graph under it
         for t in self.watchers:
@@ -966,6 +976,8 @@ class Client:
     def statedump(self) -> dict:
         d = self.graph.statedump()
         d["itable"] = self.itable.dump()
+        d["loop"] = self.loop_meter.dump() if self.loop_meter is not None \
+            else {"metered": False}
         return d
 
 

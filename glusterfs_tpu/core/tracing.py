@@ -45,6 +45,13 @@ told from nested calls and self time can be computed, and a start on
   starts ``jax.profiler``; then the program's spans sit on the host
   planes of the trace that also holds the device ops (whose planes
   have a clock of their own: docs/observability.md).
+
+A span that is open is not a thread that is running: tasks interleave
+on one loop, and the loop shares the interpreter with the codec's pool
+thread.  :class:`LoopMeter` counts what the loop's own thread does with
+its time (in ``select``, in callbacks, on a CPU), and a pool-thread
+phase opened with ``cpu=True`` reads its thread's CPU clock; both reach
+the profiler's trace only (docs/observability.md "The loop's clock").
 """
 
 from __future__ import annotations
@@ -244,16 +251,23 @@ class phase:
     of a phase of another thread, for work whose cause is not this
     thread's context.  A phase that begins on one thread and ends on
     another is opened with ``start(push=False)`` and closed with
-    ``stop()``: nothing nests under it by context.  Keyword arguments
-    are metadata of the profiler annotation."""
+    ``stop()``: nothing nests under it by context.  ``cpu`` is for a
+    phase that begins and ends on one pool thread with no ``await``
+    between: where its span has a profiler annotation, the thread's CPU
+    clock is read at both ends and the difference put on it as
+    ``cpu_ns``, so that the span's time less that is what the thread
+    spent off a CPU.  A phase on the loop crosses ``await`` (a thread
+    clock over it would count other tasks) and never has it.  Other
+    keyword arguments are metadata of the profiler annotation."""
 
-    __slots__ = ("layer", "name", "sums", "parent", "meta", "_span", "_t0")
+    __slots__ = ("layer", "name", "sums", "parent", "meta", "cpu", "_span",
+                 "_t0", "_c0")
 
     def __init__(self, layer: str | None, name: str,
                  sums: dict | None = None, parent: tuple | None = None,
-                 **meta):
+                 cpu: bool = False, **meta):
         self.layer, self.name, self.sums = layer, name, sums
-        self.parent, self.meta = parent, meta
+        self.parent, self.meta, self.cpu = parent, meta, cpu
         self._span = None
 
     def start(self, push: bool = True) -> "phase":
@@ -268,6 +282,8 @@ class phase:
             self._span = enter(self.layer, self.name, None, self.parent,
                                push, False, self.meta)
             self._t0 = self._span[6]
+            if self.cpu and self._span[9] is not None:
+                self._c0 = time.thread_time_ns()
         else:
             self._t0 = time.perf_counter_ns()
         return self
@@ -294,6 +310,9 @@ class phase:
             if dt > s[2]:
                 s[2] = dt
         if self._span is not None:
+            if self.cpu and self._span[9] is not None:
+                self._span[9].set_metadata(
+                    cpu_ns=time.thread_time_ns() - self._c0)
             exit_span(self._span, dt, et is not None)
         return False
 
@@ -318,6 +337,169 @@ def phase_sums(sums: dict) -> dict[str, dict]:
     return {name: {"count": c, "seconds": round(secs, 6),
                    "max_ms": round(mx * 1e3, 3)}
             for name, (c, secs, mx) in sorted(out.items())}
+
+
+#: seconds between two samples of a metered loop: a constant, not an
+#: option (ten a second cost nothing, and a reader of the trace gets
+#: 300 of them beside a 30 s window's device ops)
+SAMPLE_PERIOD = 0.1
+
+#: the sample's annotation; it carries no ``span`` key, so it enters no
+#: span tree (an always-open span would own every idle gap)
+SAMPLE = "gftpu:loop.sample"
+
+#: what a reading holds, and a sample's metadata beside
+#: ``slowest_pass_ns``: each the period's delta
+SAMPLE_KEYS = ("passes", "busy_ns", "select_ns", "busy_sq", "cpu_ns",
+               "polls", "poll_ns")
+
+
+class LoopMeter:
+    """What one event loop's thread does with its time: counters,
+    always on, updated once a PASS of the loop by a wrapper around its
+    selector's ``select`` (two ``perf_counter_ns`` reads, integer adds).
+
+    A pass is what the loop does between two calls of ``select``: it
+    runs every callback that was ready.  ``select_ns`` is time inside
+    ``select`` (nothing runnable, or a poll), ``busy_ns`` the rest (the
+    loop running callbacks, or wanting to).  ``busy_sq`` sums each
+    pass's length squared, so ``busy_sq / busy_ns`` is the
+    length-weighted mean pass: the pass that an answer arriving at a
+    random moment lands in, and half of it is what the answer waits
+    before the loop looks at its socket.  ``polls`` and ``poll_ns`` are
+    the part of ``passes`` and ``select_ns`` in which ``select`` was
+    called with no time to wait (callbacks were ready): a loop whose
+    ``select_ns`` is all polls has no spare time, whatever its share
+    of a CPU says, and only ``select_ns - poll_ns`` is the loop with
+    nothing to do.  The thread's CPU clock is a system call and is not
+    read per pass: the loop reads it for each sample (below), a dump
+    reads it when asked, so ``busy_ns`` less ``cpu_ns`` is the loop in
+    a callback and off a CPU (waiting for the interpreter a pool thread
+    holds, or the whole process frozen).
+
+    Every :data:`SAMPLE_PERIOD` a timer on the loop asks whether a
+    profiler session runs (``ANNOTATE.is_enabled()``), and does no more
+    while none does.  While one runs every tick closes one
+    :data:`SAMPLE` annotation, open since the tick before, with the
+    period's deltas as its metadata, and opens the next: the loop's
+    state lies on the host plane of the trace that holds the device ops
+    and the program's spans.
+
+    One meter a loop, found again through the wrapper itself
+    (:meth:`install`); each user takes it off again (:meth:`remove`) and
+    the last one restores ``select`` and cancels the timer.  Only the
+    loop's thread writes the counters."""
+
+    __slots__ = ("loop", "selector", "thread", "users", "passes", "busy_ns",
+                 "select_ns", "busy_sq", "polls", "poll_ns",
+                 "slowest_pass_ns", "_select", "_since", "_slow_ns",
+                 "_cpu0", "_last", "_ann", "_timer")
+
+    @classmethod
+    def install(cls, loop) -> "LoopMeter | None":
+        """The meter of ``loop`` (the running loop: the caller is on its
+        thread), made on first use.  ``None`` for a loop that polls
+        through no selector of its own: it stays unmetered."""
+        selector = getattr(loop, "_selector", None)
+        select = getattr(selector, "select", None)
+        if select is None:
+            return None
+        meter = getattr(select, "__self__", None)
+        if not isinstance(meter, cls):
+            meter = cls(loop, selector, select)
+        meter.users += 1
+        return meter
+
+    def __init__(self, loop, selector, select):
+        self.loop, self.selector, self._select = loop, selector, select
+        self.thread = threading.get_ident()
+        self.users = 0
+        self.passes = self.busy_ns = self.select_ns = self.busy_sq = 0
+        self.polls = self.poll_ns = 0
+        self.slowest_pass_ns = self._slow_ns = 0
+        self._ann = self._last = None
+        self._cpu0 = time.thread_time_ns()
+        self._since = time.perf_counter_ns()
+        selector.select = self._pass
+        self._timer = loop.call_later(SAMPLE_PERIOD, self._tick)
+
+    def remove(self) -> None:
+        """One user less; the last leaves nothing on the loop."""
+        self.users -= 1
+        if self.users > 0:
+            return
+        self._timer.cancel()
+        self._sample(False)
+        del self.selector.select
+
+    def _pass(self, timeout=None):
+        t0 = time.perf_counter_ns()
+        busy = t0 - self._since
+        self.passes += 1
+        self.busy_ns += busy
+        self.busy_sq += busy * busy
+        if busy > self._slow_ns:
+            self._slow_ns = busy
+        ready = self._select(timeout)
+        self._since = t1 = time.perf_counter_ns()
+        self.select_ns += t1 - t0
+        if timeout == 0:
+            self.polls += 1
+            self.poll_ns += t1 - t0
+        return ready
+
+    def _reading(self) -> tuple:
+        """:data:`SAMPLE_KEYS` as of now, on the loop's thread: the pass
+        under way counts as busy up to here, so ``busy_ns + select_ns``
+        of two readings differ by the time between them."""
+        return (self.passes,
+                self.busy_ns + time.perf_counter_ns() - self._since,
+                self.select_ns, self.busy_sq,
+                time.thread_time_ns() - self._cpu0,
+                self.polls, self.poll_ns)
+
+    def _tick(self) -> None:
+        self._timer = self.loop.call_later(SAMPLE_PERIOD, self._tick)
+        self._sample(ANNOTATE is not None and ANNOTATE.is_enabled())
+
+    def _sample(self, on: bool) -> None:
+        """The open sample (begun at the tick before) gets the deltas of
+        its period and that period's slowest pass, and ends; while
+        ``on`` the next one begins."""
+        ann, self._ann = self._ann, None
+        if ann is None and not on:
+            return
+        before, self._last = self._last, self._reading()
+        slow, self._slow_ns = self._slow_ns, 0
+        if slow > self.slowest_pass_ns:
+            self.slowest_pass_ns = slow
+        if ann is not None:
+            ann.set_metadata(slowest_pass_ns=slow, **{
+                k: b - a for k, a, b in zip(SAMPLE_KEYS, before,
+                                            self._last)})
+            ann.__exit__(None, None, None)
+        if on:
+            self._ann = ANNOTATE(SAMPLE)
+            self._ann.__enter__()
+
+    def dump(self) -> dict:
+        """The statedump's ``loop`` section: is this loop full
+        (``select_s`` less ``poll_s`` is all the time it had nothing to
+        do), and is it computing (``cpu_s`` near ``busy_s``) or waiting
+        for the interpreter.  Since the meter was installed, the
+        counters as of the loop's last call of ``select``; any thread
+        may ask (the CPU clock is read as the loop's thread's own)."""
+        cpu = time.clock_gettime_ns(
+            time.pthread_getcpuclockid(self.thread)) - self._cpu0
+        return {"metered": True, "passes": self.passes,
+                "busy_s": round(self.busy_ns * 1e-9, 6),
+                "select_s": round(self.select_ns * 1e-9, 6),
+                "polls": self.polls, "poll_s": round(self.poll_ns * 1e-9, 6),
+                "cpu_s": round(cpu * 1e-9, 6),
+                "weighted_pass_ms": round(
+                    self.busy_sq / max(self.busy_ns, 1) * 1e-6, 6),
+                "slowest_pass_ms": round(
+                    max(self.slowest_pass_ns, self._slow_ns) * 1e-6, 6)}
 
 
 def _flight():

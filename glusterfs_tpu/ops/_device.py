@@ -44,10 +44,10 @@ def device_call(fn, *host) -> np.ndarray:
     does not make them so: its device planes stood up to a millisecond
     off its host planes (PERF.md section 6, PR 24), so a host span
     cannot be laid against a device op."""
-    with tracing.phase(None, "codec.h2d"):
+    with tracing.phase(None, "codec.h2d", cpu=True):
         dev = [jnp.asarray(a) for a in host]
-    with tracing.phase(None, "codec.launch"):
+    with tracing.phase(None, "codec.launch", cpu=True):
         out = fn(*dev)
     launched()
-    with tracing.phase(None, "codec.d2h"):
+    with tracing.phase(None, "codec.d2h", cpu=True):
         return np.asarray(out)

@@ -688,7 +688,7 @@ class BatchingCodec(Codec):
             if others:
                 meta["others"] = ",".join(others)
             with _tracing.phase(self.name, "codec.flush", self.phases,
-                                batch[0][-1].origin, **meta):
+                                batch[0][-1].origin, cpu=True, **meta):
                 t0 = time.perf_counter()
                 cat = self._gather(batch, kind)
                 if kind == "device" and waiting:
@@ -737,7 +737,8 @@ class BatchingCodec(Codec):
             s = cat.shape[-1] // unit
             if kind != "device" or _bucket_stripes(s) == s:
                 return cat
-        with _tracing.phase(self.name, "codec.gather", self.phases):
+        with _tracing.phase(self.name, "codec.gather", self.phases,
+                            cpu=True):
             if len(batch) > 1:
                 cat = np.concatenate([d for d, *_ in batch], axis=-1)
             return self._pad_bucket(cat) if kind == "device" else cat
@@ -748,7 +749,8 @@ class BatchingCodec(Codec):
         it is, with no span."""
         if len(batch) == 1:
             return [out]
-        with _tracing.phase(self.name, "codec.scatter", self.phases):
+        with _tracing.phase(self.name, "codec.scatter", self.phases,
+                            cpu=True):
             results, off = [], 0
             for item, *_ in batch:
                 n = item.size // shrink

@@ -7,6 +7,7 @@ endpoint, the per-brick metrics_dump RPC)."""
 
 import asyncio
 import os
+import threading
 
 import pytest
 
@@ -676,9 +677,12 @@ def recorder(monkeypatch):
 
 
 def _names(log, skip=()):
+    """The spans' names, counted: the mounted loop's samples
+    (``gftpu:loop.sample``, ISSUE 34) are no span and carry no
+    ``span``."""
     from collections import Counter
 
-    return Counter(n for n, _m, _t in log if n not in skip)
+    return Counter(n for n, m, _t in log if n not in skip and "span" in m)
 
 
 # per fop inside a held eager window with the gfid lock free (one fop
@@ -910,9 +914,10 @@ def test_one_mib_through_4p2_yields_every_phase_once(tmp_path, recorder,
         p for p in WRITE_PHASES if p.startswith("codec.")}, codec_sums
     assert all(v["count"] >= 1 and v["seconds"] > 0 and v["max_ms"] > 0
                for v in {**ec_sums, **codec_sums}.values())
-    # every span names its trace, itself and its parent
+    # every span names its trace, itself and its parent (the mounted
+    # loop's samples are no span)
     assert all({"trace", "span", "parent"} <= set(m)
-               for _n, m, _t in recorder.log)
+               for n, m, _t in recorder.log if n != tracing.SAMPLE)
 
 
 @pytest.mark.parametrize("compression", ["off", "on"])
@@ -1044,3 +1049,405 @@ def test_a_phase_nests_under_the_phase_it_opens_in():
     assert {s[3]: s[2] for s in tracing.SPANS}["x.ownerless"] == "l"
     assert set(tracing.phase_sums(sums)) == {
         "x.outer", "x.handed", "x.inner", "x.sibling"}
+
+
+# -- the loop's clock and the pool thread's (ISSUE 34) ---------------------
+
+MS = 1_000_000
+
+
+class _Clock:
+    """Stands in for ``time`` inside ``core/tracing.py``: both clocks
+    stand still unless the test moves them."""
+
+    def __init__(self):
+        self.now = self.cpu = self.thread_reads = 0
+
+    def perf_counter_ns(self):
+        return self.now
+
+    def thread_time_ns(self):
+        self.thread_reads += 1
+        return self.cpu
+
+    # the same clock, as another thread asks for it (``dump``)
+    def pthread_getcpuclockid(self, ident):
+        return -1
+
+    def clock_gettime_ns(self, clock):
+        return self.thread_time_ns()
+
+
+class _Handle:
+    def __init__(self, delay, cb):
+        self.delay, self.cb, self.live = delay, cb, True
+
+    def cancel(self):
+        self.live = False
+
+
+class _Selector:
+    """``select`` takes ``idle`` ns of the clock and returns two
+    events."""
+
+    def __init__(self, clock):
+        self.clock, self.idle = clock, 0
+
+    def select(self, timeout=None):
+        self.clock.now += self.idle
+        return ["r", "w"]
+
+
+class _Loop:
+    def __init__(self, clock):
+        self._selector = _Selector(clock)
+        self.timers = []
+
+    def call_later(self, delay, cb):
+        self.timers.append(_Handle(delay, cb))
+        return self.timers[-1]
+
+    def passes(self, clock, *busy_ms, idle_ms=0):
+        """Callbacks of ``busy_ms`` each, a ``select`` after each."""
+        self._selector.idle = idle_ms * MS
+        for ms in busy_ms:
+            clock.now += ms * MS
+            self._selector.select(0)
+
+
+@pytest.fixture
+def metered(monkeypatch):
+    """(clock, loop, meter): a meter on a loop whose time the test
+    makes."""
+    clock = _Clock()
+    monkeypatch.setattr(tracing, "time", clock)
+    loop = _Loop(clock)
+    meter = tracing.LoopMeter.install(loop)
+    yield clock, loop, meter
+    tracing.ANNOTATE = None
+
+
+def test_the_weighted_pass_is_the_one_an_answer_lands_in(metered):
+    """One pass of 20 ms and twenty of 1 ms: the mean pass is 1.9 ms,
+    but an answer arriving at a random moment lands in the long one
+    half the time: ``busy_sq / busy_ns`` is (400 + 20) / 40 = 10.5."""
+    clock, loop, meter = metered
+    loop.passes(clock, 20, *[1] * 20, idle_ms=3)
+    assert meter.passes == 21
+    assert meter.busy_ns == 40 * MS and meter.select_ns == 63 * MS
+    assert meter.busy_sq / meter.busy_ns == 10.5 * MS
+    assert meter.dump()["weighted_pass_ms"] == 10.5
+    # no thread clock in a pass: one read when the meter was made, one
+    # for the dump
+    assert clock.thread_reads == 2
+    # every select so far was a poll (callbacks ready, no time to
+    # wait); one that may block is none
+    assert meter.polls == 21 and meter.poll_ns == meter.select_ns
+    loop._selector.select(1.5)
+    assert (meter.passes, meter.polls) == (22, 21)
+    assert meter.select_ns - meter.poll_ns == 3 * MS
+
+
+def test_the_slowest_pass_is_kept_across_the_periods(metered):
+    """A sample carries its own period's slowest pass; the dump shows
+    the slowest since the meter was installed, sampled or not."""
+    clock, loop, meter = metered
+    _Recorder.log = []
+    loop.passes(clock, 2, 20, 4, idle_ms=1)
+    assert meter.dump()["slowest_pass_ms"] == 20.0  # no profiler yet
+    tracing.ANNOTATE = _Recorder
+    loop.timers[-1].cb()  # the first sample opens
+    loop.passes(clock, 7, 3)
+    loop.timers[-1].cb()
+    loop.passes(clock, 5)
+    loop.timers[-1].cb()
+    assert [m["slowest_pass_ns"] for _n, m, _t in _Recorder.log] == \
+        [7 * MS, 5 * MS]
+    assert meter.slowest_pass_ns == 20 * MS
+    assert meter.dump()["slowest_pass_ms"] == 20.0
+
+
+def test_install_twice_is_one_wrapper_and_the_last_user_restores(metered):
+    clock, loop, meter = metered
+    sel = loop._selector
+    assert tracing.LoopMeter.install(loop) is meter and meter.users == 2
+    assert sel.select == meter._pass and sel.select.__self__ is meter
+    meter.remove()
+    assert sel.__dict__["select"] == meter._pass and loop.timers[-1].live
+    meter.remove()
+    assert "select" not in sel.__dict__
+    assert sel.select.__func__ is _Selector.select
+    assert not any(h.live for h in loop.timers)
+    # a loop that polls through no selector stays unmetered
+    assert tracing.LoopMeter.install(object()) is None
+
+
+def test_a_sample_a_period_with_the_periods_deltas_and_no_span(metered):
+    """While a profiler runs every tick closes one ``gftpu:loop.sample``
+    with its keys, each the period's own, and opens the next; it carries
+    no ``span`` (``benchmarks/harness/spans.py`` would hang an
+    always-open span over every idle gap)."""
+    clock, loop, meter = metered
+    _Recorder.log = []
+    tracing.ANNOTATE = _Recorder
+    loop.timers[-1].cb()  # opens the first sample
+    assert _Recorder.log == [] and loop.timers[-1].delay == \
+        tracing.SAMPLE_PERIOD == 0.1
+    for k, (busy, cpu) in enumerate([((5, 1, 1), 6), ((30,), 2)]):
+        loop.passes(clock, *busy, idle_ms=10)
+        clock.now += 2 * MS  # the tick's own pass, under way
+        clock.cpu += cpu * MS
+        loop.timers[-1].cb()
+        name, meta, _thread = _Recorder.log[k]
+        assert name == "gftpu:loop.sample" and "span" not in meta
+        assert set(meta) == set(tracing.SAMPLE_KEYS) | {"slowest_pass_ns"}
+    first, second = (m for _n, m, _t in _Recorder.log)
+    assert first == {
+        "passes": 3, "busy_ns": 9 * MS, "select_ns": 30 * MS,
+        "busy_sq": 27 * MS * MS, "cpu_ns": 6 * MS,
+        "slowest_pass_ns": 5 * MS, "polls": 3, "poll_ns": 30 * MS}
+    # the pass the first tick ran in ends in the second period (2 of
+    # its ms before the tick were the first's); busy and select add up
+    # to the time between the two ticks
+    assert second == {
+        "passes": 1, "busy_ns": 32 * MS, "select_ns": 10 * MS,
+        "busy_sq": 32 * 32 * MS * MS, "cpu_ns": 2 * MS,
+        "slowest_pass_ns": 32 * MS, "polls": 1, "poll_ns": 10 * MS}
+
+
+def test_no_sample_while_no_profiler_runs(metered, monkeypatch):
+    clock, loop, meter = metered
+    _Recorder.log = []
+    tracing.ANNOTATE = _Recorder
+    monkeypatch.setattr(_Recorder, "is_enabled", staticmethod(lambda: False))
+    reads = clock.thread_reads
+    for _ in range(3):
+        loop.passes(clock, 4, idle_ms=1)
+        loop.timers[-1].cb()
+    assert _Recorder.log == [] and meter._ann is None
+    # such a tick asks is_enabled() and reads no clock
+    assert clock.thread_reads == reads and meter._last is None
+    monkeypatch.setattr(_Recorder, "is_enabled", staticmethod(lambda: True))
+    loop.timers[-1].cb()
+    loop.passes(clock, 4, idle_ms=1)
+    # the session ends: the open sample is closed, none is opened
+    monkeypatch.setattr(_Recorder, "is_enabled", staticmethod(lambda: False))
+    loop.timers[-1].cb()
+    assert [m["passes"] for _n, m, _t in _Recorder.log] == [1]
+    assert meter._ann is None
+    loop.timers[-1].cb()
+    assert len(_Recorder.log) == 1
+    # and with no annotation class at all (a process without jax)
+    tracing.ANNOTATE = None
+    loop.timers[-1].cb()
+    assert len(_Recorder.log) == 1
+
+
+def test_removing_the_meter_closes_the_open_sample(metered):
+    clock, loop, meter = metered
+    _Recorder.log = []
+    tracing.ANNOTATE = _Recorder
+    loop.timers[-1].cb()
+    loop.passes(clock, 6, idle_ms=1)
+    meter.remove()
+    (name, meta, _t), = _Recorder.log
+    assert meta["busy_ns"] == 6 * MS and meter._ann is None
+
+
+@pytest.mark.parametrize("what", ["spin", "sleep", "idle"])
+def test_on_a_cpu_off_it_and_in_select_on_a_real_loop(what):
+    """On a private loop: a callback that spins 20 ms of CPU reads as
+    ``busy_ns`` and ``cpu_ns``, one that sleeps 20 ms as ``busy_ns``
+    without ``cpu_ns``, an idle 50 ms as ``select_ns``; busy and
+    select add up to the time between the two readings."""
+    import time
+
+    def spin():
+        end = time.thread_time_ns() + 20 * MS
+        while time.thread_time_ns() < end:
+            pass
+
+    async def run():
+        meter = tracing.LoopMeter.install(asyncio.get_running_loop())
+        await asyncio.sleep(0)
+        t0, a = time.perf_counter_ns(), meter._reading()
+        if what == "idle":
+            await asyncio.sleep(0.05)
+        else:
+            spin() if what == "spin" else time.sleep(0.02)
+            await asyncio.sleep(0)  # the pass ends
+        b, t1 = meter._reading(), time.perf_counter_ns()
+        meter.remove()
+        return dict(zip(tracing.SAMPLE_KEYS,
+                        (y - x for x, y in zip(a, b)))), t1 - t0, meter
+
+    d, elapsed, meter = asyncio.run(run())
+    assert abs(d["busy_ns"] + d["select_ns"] - elapsed) < 2 * MS
+    if what == "idle":
+        assert d["select_ns"] >= 0.6 * elapsed >= 30 * MS
+        assert d["cpu_ns"] < 10 * MS
+    else:
+        assert d["busy_ns"] >= 20 * MS > d["select_ns"]
+        assert meter.dump()["slowest_pass_ms"] >= 20
+        assert d["busy_sq"] >= (20 * MS) ** 2
+        if what == "spin":
+            assert 20 * MS <= d["cpu_ns"] <= d["busy_ns"] + MS
+        else:
+            assert d["cpu_ns"] < 10 * MS
+
+
+def _posix_client(tmp_path):
+    return Client(Graph.construct(BRICK_VOLFILE.format(dir=tmp_path)))
+
+
+def test_mount_meters_the_loop_and_unmount_leaves_nothing(tmp_path):
+    """``Client.mount`` installs the meter on the running loop, two
+    mounts on one loop share it, the statedump has the ``loop`` section,
+    and after the last ``unmount`` ``select`` is the selector's own and
+    no sample timer is pending."""
+    async def run():
+        loop = asyncio.get_running_loop()
+        sel = loop._selector
+        a, b = _posix_client(tmp_path / "a"), _posix_client(tmp_path / "b")
+        assert a.statedump()["loop"] == {"metered": False}
+        await a.mount()
+        await a.mount()  # mounted again: still one user
+        await b.mount()
+        meter = a.loop_meter
+        assert meter is b.loop_meter and meter.users == 2
+        assert sel.select == meter._pass
+        await asyncio.sleep(0.25)  # two ticks, no profiler
+        dump = a.statedump()["loop"]
+        assert set(dump) == {"metered", "passes", "busy_s", "select_s",
+                             "polls", "poll_s", "cpu_s",
+                             "weighted_pass_ms", "slowest_pass_ms"}
+        assert dump["metered"] is True and dump["passes"] >= 2
+        assert dump["select_s"] > 0.2 > dump["busy_s"] >= dump["cpu_s"] * 0.5
+        await a.unmount()
+        assert a.loop_meter is None and sel.select == meter._pass
+        assert a.statedump()["loop"] == {"metered": False}
+        await b.unmount()
+        assert "select" not in sel.__dict__
+        assert sel.select.__func__ is type(sel).select
+        assert meter._timer.cancelled()
+        assert not [h for h in loop._scheduled if not h.cancelled()
+                    and getattr(h._callback, "__self__", None) is meter]
+
+    asyncio.run(run())
+
+
+def test_another_threads_dump_reads_the_loops_threads_clock(tmp_path):
+    """``SyncClient.statedump()`` runs on the caller's thread: the
+    section's ``cpu_s`` is the loop's thread's CPU time all the same,
+    not the caller's."""
+    import time
+
+    from glusterfs_tpu.api.glfs import SyncClient
+
+    def spin():
+        end = time.thread_time_ns() + 30 * MS
+        while time.thread_time_ns() < end:
+            pass
+
+    c = SyncClient(Graph.construct(BRICK_VOLFILE.format(dir=tmp_path)))
+    try:
+        c.mount()
+        meter = c.loop_meter
+        assert meter.thread == c._thread.ident != threading.get_ident()
+        before = c.statedump()["loop"]
+        c._loop.call_soon_threadsafe(spin)
+        time.sleep(0.25)
+        dump = c.statedump()["loop"]
+        assert dump["metered"] and dump["passes"] >= before["passes"] + 2
+        assert dump["cpu_s"] - before["cpu_s"] >= 0.03
+        assert dump["busy_s"] - before["busy_s"] >= 0.03
+        assert dump["slowest_pass_ms"] >= 30
+        assert dump["select_s"] - before["select_s"] > 0.15
+    finally:
+        c.close()
+    assert c.loop_meter is None
+    assert "select" not in c._loop._selector.__dict__
+
+
+def test_a_cpu_phase_reads_the_thread_clock_only_under_an_annotation(
+        monkeypatch):
+    """``phase(cpu=True)`` puts ``cpu_ns`` on its span's annotation;
+    with no annotation (no profiler) it reads no clock, and a phase
+    without the flag never does."""
+    clock = _Clock()
+    monkeypatch.setattr(tracing, "time", clock)
+    monkeypatch.setattr(tracing, "ENABLED", True)
+    _Recorder.log = []
+    sums: dict = {}
+    try:
+        with tracing.phase("l", "x.dark", sums, cpu=True):  # no ANNOTATE
+            clock.cpu += 5
+        assert clock.thread_reads == 0
+        tracing.ANNOTATE = _Recorder
+        with tracing.phase("l", "x.pool", sums, cpu=True, op="encode"):
+            clock.cpu += 7
+            clock.now += 10
+        assert clock.thread_reads == 2
+        with tracing.phase("l", "x.loop", sums):
+            clock.cpu += 9
+        assert clock.thread_reads == 2
+        monkeypatch.setattr(_Recorder, "is_enabled",
+                            staticmethod(lambda: False))
+        with tracing.phase("l", "x.off", sums, cpu=True):
+            pass
+        assert clock.thread_reads == 2
+        monkeypatch.setattr(tracing, "ENABLED", False)
+        with tracing.phase("l", "x.disabled", sums, cpu=True):
+            pass
+        assert clock.thread_reads == 2
+    finally:
+        tracing.ANNOTATE = None
+    meta = {n: m for n, m, _t in _Recorder.log}
+    assert set(meta) == {"gftpu:x.pool", "gftpu:x.loop"}
+    assert meta["gftpu:x.pool"]["cpu_ns"] == 7
+    assert meta["gftpu:x.pool"]["op"] == "encode"
+    assert "cpu" not in meta["gftpu:x.pool"]
+    assert "cpu_ns" not in meta["gftpu:x.loop"]
+    assert tracing.phase_sums(sums)["x.pool"]["count"] == 1
+
+
+def test_the_pool_threads_phases_carry_cpu_ns_and_the_loops_none(recorder):
+    """The six phases that begin and end on one pool thread carry their
+    thread's CPU time; ``codec.queue`` and ``codec.resume`` cross
+    threads, ``ec.codec_wait`` crosses ``await``: no thread clock says
+    anything about them."""
+    import numpy as np
+
+    from glusterfs_tpu.ops.batch import BatchingCodec
+
+    codec = BatchingCodec(4, 2, "xla", window=0.01, min_batch=0,
+                          systematic=True, name="batcher")
+    tracing.ANNOTATE = recorder
+
+    async def fop(i):
+        with tracing.phase("batcher", "ec.codec_wait"):
+            return await codec.encode_async(
+                np.full(4 * 2048, i, dtype=np.uint8))
+
+    async def run():
+        return await asyncio.gather(fop(1), fop(2))
+
+    asyncio.run(run())
+    codec.close()
+    by_name = {}
+    for name, meta, thread in recorder.log:
+        by_name.setdefault(name[len("gftpu:"):], []).append((meta, thread))
+    pool = {"codec.flush", "codec.gather", "codec.h2d", "codec.launch",
+            "codec.d2h", "codec.scatter"}
+    assert pool | {"codec.queue", "codec.resume", "ec.codec_wait"} \
+        == set(by_name)
+    for name, found in by_name.items():
+        for meta, thread in found:
+            assert ("cpu_ns" in meta) == (name in pool), name
+            if name in pool:
+                assert thread.startswith("ec-codec-")
+                assert isinstance(meta["cpu_ns"], int) and meta["cpu_ns"] >= 0
+    (flush, _t), = by_name["codec.flush"]
+    inner = sum(m["cpu_ns"] for n in pool - {"codec.flush"}
+                for m, _t in by_name[n])
+    assert flush["cpu_ns"] >= inner > 0
