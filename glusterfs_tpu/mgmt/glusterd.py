@@ -38,7 +38,7 @@ import uuid
 from typing import Any
 
 from .. import pin_cpu
-from ..core import gflog
+from ..core import flight, gflog
 from ..core.events import gf_event
 from .bitd import DEFAULT_SCRUB_THROTTLE
 from ..core.fops import FopError
@@ -195,8 +195,7 @@ class Glusterd:
         for s in self.state.get("snaps", {}).values():
             vi = s.get("volinfo")
             if vi:
-                for b in vi["bricks"]:
-                    await self._spawn_brick(vi, b)
+                await self._start_bricks(vi, vi["bricks"])
         self._quorum_task = asyncio.create_task(self._quorum_loop())
         # catch up on config txns committed while this node was down
         # (the restart side of the friend handshake)
@@ -230,13 +229,7 @@ class Glusterd:
         for name in list(self.bricks):
             self._kill_brick(name)
         if self._mux is not None:
-            proc = self._mux["proc"]
-            if proc.poll() is None:
-                proc.terminate()
-                try:
-                    await asyncio.to_thread(proc.wait, timeout=5)
-                except subprocess.TimeoutExpired:
-                    proc.kill()
+            await self._reap(self._mux["proc"])
             self._mux = None
         if self._server is not None:
             self._server.close()
@@ -586,10 +579,7 @@ class Glusterd:
                 # un-block only AFTER the respawn succeeds: a failed
                 # spawn must leave the name in the set so the next
                 # tick retries instead of stranding the bricks
-                for b in vol["bricks"]:
-                    if b["node"] == self.uuid and \
-                            b["name"] not in self.bricks:
-                        await self._spawn_brick(vol, b, port=b.get("port"))
+                await self._start_local_bricks(vol, reuse_ports=True)
                 self._quorum_blocked.discard(stale)
                 log.info(16, "quorum enforcement lifted: restarted "
                          "bricks of %s", stale)
@@ -609,12 +599,9 @@ class Glusterd:
                 gf_event("SERVER_QUORUM_LOST", volume=name,
                          alive=alive, total=total)
             elif met and name in self._quorum_blocked:
-                for b in vol["bricks"]:
-                    if b["node"] == self.uuid and \
-                            b["name"] not in self.bricks:
-                        # reuse the recorded port: fenced clients are
-                        # still retrying it
-                        await self._spawn_brick(vol, b, port=b.get("port"))
+                # reuse the recorded ports: fenced clients are still
+                # retrying them
+                await self._start_local_bricks(vol, reuse_ports=True)
                 # only now: a failed respawn keeps the volume blocked
                 # so the next tick retries
                 self._quorum_blocked.discard(name)
@@ -2065,9 +2052,8 @@ class Glusterd:
         self._bump(vol)
         self._save()
         if vol["status"] == "started":
-            for b in bricks:
-                if b["node"] == self.uuid:
-                    await self._spawn_brick(vol, b)
+            await self._start_bricks(
+                vol, [b for b in bricks if b["node"] == self.uuid])
             self._notify_subscribers(name)  # topology change: graph swap
         gf_event("VOLUME_ADD_BRICK", name=name,
                  bricks=[b["name"] for b in bricks])
@@ -2902,20 +2888,19 @@ class Glusterd:
         for k in ("changelog.changelog", "features.bitrot",
                   "features.quota"):
             opts.pop(k, None)
-        spawned = []
+        # a retry after partial failure finds some already serving
+        todo = [b for b in bricks
+                if self.bricks.get(b["name"]) is None
+                or self.bricks[b["name"]].poll() is not None]
         try:
-            for b in bricks:
-                proc = self.bricks.get(b["name"])
-                if proc is not None and proc.poll() is None:
-                    continue  # a retry after partial failure
-                await self._spawn_brick(vi, b)
-                spawned.append(b)
+            await self._start_bricks(vi, todo)
         except BaseException:
             # no half-activated snapshot: stop what we started (detach,
             # not kill, when multiplexed — the shared daemon serves
             # other volumes' bricks too)
-            for b_ in spawned:
-                await self._stop_brick(vi, b_)
+            for b_ in todo:
+                if b_["name"] in self.bricks:
+                    await self._stop_brick(vi, b_)
             raise
         snap["volinfo"] = vi
         self._save()
@@ -3557,11 +3542,66 @@ class Glusterd:
 
     # -- brick lifecycle (glusterd-utils.c runner + pmap) ------------------
 
-    async def _start_local_bricks(self, vol: dict) -> None:
-        for b in vol["bricks"]:
-            if b["node"] != self.uuid or b["name"] in self.bricks:
-                continue
-            await self._spawn_brick(vol, b)
+    async def _start_local_bricks(self, vol: dict,
+                                  reuse_ports: bool = False) -> None:
+        await self._start_bricks(
+            vol, [b for b in vol["bricks"]
+                  if b["node"] == self.uuid
+                  and b["name"] not in self.bricks], reuse_ports)
+
+    async def _start_bricks(self, vol: dict, bricks: list,
+                            reuse_ports: bool = False) -> None:
+        """Start ``bricks`` of ``vol`` side by side and wait for all of
+        them (glusterd_volume_start_glusterfs forks each brick with
+        runner_run_nowait and learns its port at pmap sign-in): every
+        process is forked before the first port file is awaited, so the
+        call takes about as long as its slowest brick.  Every brick is
+        tried; each one that came up is tracked and stored; the error
+        raised is that of the first brick in the given order that
+        failed.  A multiplexed volume attaches in brick order, one
+        ATTACH after another, and stops at the first refusal."""
+        if not bricks:
+            return
+        took: dict[str, float] = {}
+
+        async def boot(b: dict) -> None:
+            t = time.monotonic()
+            await self._boot_brick(
+                vol, b, b.get("port") if reuse_ports else None)
+            took[b["name"]] = time.monotonic() - t
+
+        t0 = time.monotonic()
+        tasks: list[asyncio.Task] = []
+        try:
+            if self._mux_enabled(vol):
+                for b in bricks:
+                    await boot(b)
+                return
+            tasks = [asyncio.ensure_future(boot(b)) for b in bricks]
+            for result in await asyncio.gather(*tasks,
+                                               return_exceptions=True):
+                if isinstance(result, BaseException):
+                    raise result  # the first failure, in brick order
+        except asyncio.CancelledError:
+            # the caller is gone, and gather has passed that on: each
+            # wait under way reaps its own process (_spawn_daemon), and
+            # this call outlives them all
+            await asyncio.gather(*tasks, return_exceptions=True)
+            raise
+        finally:
+            # the tables in brick order, whatever order the ports came in
+            for name in [b["name"] for b in bricks if b["name"] in took]:
+                self.bricks[name] = self.bricks.pop(name)
+                self.ports[name] = self.ports.pop(name)
+            if took:
+                self._save()
+            wall = time.monotonic() - t0
+            slowest = max(took.values(), default=0.0)
+            log.info(25, "bricks started: n=%d wall_s=%.2f slowest_s=%.2f",
+                     len(took), wall, slowest)
+            flight.record("bricks_started", volume=vol["name"],
+                          n=len(took), wall_s=round(wall, 3),
+                          slowest_s=round(slowest, 3))
 
     async def _broadcast_local_ports(self, vol: dict) -> None:
         """pmap sync for this node's live bricks: write their current
@@ -3643,25 +3683,34 @@ class Glusterd:
         # generous: a cold interpreter+jax import on a loaded host can
         # take the better part of a minute
         deadline = time.time() + 90
-        while time.time() < deadline:
-            if os.path.exists(portfile):
-                with open(portfile) as f:
-                    return proc, int(f.read())
-            if proc.poll() is not None:
-                with open(logfile, "rb") as f:
-                    err = f.read().decode(errors="replace")[-2000:]
-                raise MgmtError(f"{what} failed: {err}")
-            await asyncio.sleep(0.05)
-        # kill the straggler (terminate -> wait -> kill escalation): an
-        # orphan that binds its port AFTER we give up would serve a
-        # brick glusterd no longer tracks
+        try:
+            while time.time() < deadline:
+                if os.path.exists(portfile):
+                    with open(portfile) as f:
+                        return proc, int(f.read())
+                if proc.poll() is not None:
+                    with open(logfile, "rb") as f:
+                        err = f.read().decode(errors="replace")[-2000:]
+                    raise MgmtError(f"{what} failed: {err}")
+                await asyncio.sleep(0.05)
+            raise MgmtError(f"{what} did not start in time")
+        except BaseException:
+            # a straggler, or a caller that went away mid-wait
+            # (cancelled): no table will ever hold this process, and an
+            # orphan that binds its port AFTER we give up would serve a
+            # brick glusterd no longer tracks
+            await self._reap(proc)
+            raise
+
+    @staticmethod
+    async def _reap(proc: subprocess.Popen) -> None:
+        """terminate -> wait -> kill escalation, off the loop."""
         if proc.poll() is None:
             proc.terminate()
             try:
                 await asyncio.to_thread(proc.wait, timeout=5)
             except subprocess.TimeoutExpired:
                 proc.kill()
-        raise MgmtError(f"{what} did not start in time")
 
     async def _ensure_mux_proc(self) -> int:
         async with self._mux_lock:
@@ -3707,7 +3756,6 @@ class Glusterd:
         self.bricks[b["name"]] = self._mux["proc"]
         self.ports[b["name"]] = port
         b["port"] = port
-        self._save()
 
     async def _stop_brick(self, vol: dict, b: dict) -> None:
         """Stop serving one brick: detach from the shared daemon when
@@ -3732,8 +3780,8 @@ class Glusterd:
         coordinator on brick 0's node, ``num_processes`` = brick
         count, ``process_id`` = brick index.  The daemon's meshd glue
         (parallel/meshd.py) reads these and initializes in the
-        BACKGROUND, so brick startup (and glusterd's one-at-a-time
-        spawn loop) never blocks on ranks that aren't up yet."""
+        BACKGROUND, so brick startup (and glusterd's wait for the
+        port files) never blocks on ranks that aren't up yet."""
         opts = vol.get("options", {})
         if not volgen._bool(opts.get("cluster.mesh-distributed",
                                      "off")):
@@ -3764,6 +3812,15 @@ class Glusterd:
 
     async def _spawn_brick(self, vol: dict, b: dict,
                            port: int | None = None) -> None:
+        """Start ONE brick and store its port (several go through
+        :meth:`_start_bricks`)."""
+        await self._boot_brick(vol, b, port)
+        self._save()
+
+    async def _boot_brick(self, vol: dict, b: dict,
+                          port: int | None = None) -> None:
+        """Bring one brick up and enter it in the tables; the caller
+        stores the volinfo."""
         if self._mux_enabled(vol):
             await self._attach_brick(vol, b)
             return
@@ -3782,7 +3839,6 @@ class Glusterd:
         self.bricks[b["name"]] = proc
         self.ports[b["name"]] = bport
         b["port"] = bport
-        self._save()
 
     def _kill_brick(self, name: str) -> None:
         proc = self.bricks.pop(name, None)

@@ -147,3 +147,44 @@ def test_replace_brick_heals_replica(tmp_path):
             await d.stop()
 
     asyncio.run(run())
+
+
+def test_managed_4p2_volume_started_over_the_rpc_serves_bytes(tmp_path):
+    """The program's own spawner with real brick processes (ISSUE 35):
+    ``volume-start`` brings the six up side by side, each on a port of
+    its own and recorded in brick order, and the mounted volume gives
+    back what was written, also with two of the six stopped."""
+    data = os.urandom((1 << 20) + 12345)
+
+    async def run():
+        d = Glusterd(str(tmp_path / "gd"))
+        await d.start()
+        try:
+            async with MgmtClient(d.host, d.port) as c:
+                await c.call(
+                    "volume-create", name="mv", vtype="disperse",
+                    bricks=[{"path": str(tmp_path / f"b{i}")}
+                            for i in range(6)], redundancy=2)
+                await c.call("volume-start", name="mv")
+                status = await c.call("volume-status", name="mv")
+                assert all(b["online"] for b in status["bricks"])
+                names = [f"mv-brick-{i}" for i in range(6)]
+                bricks = d.state["volumes"]["mv"]["bricks"]
+                assert list(d.bricks) == names
+                assert [b["port"] for b in bricks] == \
+                    [d.ports[n] for n in names]
+                assert len({b["port"] for b in bricks}) == 6
+                client = await mount_volume(d.host, d.port, "mv")
+                try:
+                    await client.write_file("/f", data)
+                    assert await client.read_file("/f") == data
+                    for brick in names[1::3]:
+                        await c.call("volume-brick", name="mv",
+                                     brick=brick, action="stop")
+                    assert await client.read_file("/f") == data
+                finally:
+                    await client.unmount()
+        finally:
+            await d.stop()
+
+    asyncio.run(run())

@@ -241,6 +241,303 @@ def test_peer_volinfo_reconciliation(tmp_path):
     asyncio.run(run())
 
 
+# -- the brick spawner (ISSUE 35) ------------------------------------------
+# ``_spawn_daemon`` replaced by a stub that takes BOOT_S on the loop's
+# clock: what is held here is the order, the overlap, the tables and the
+# store, not an interpreter's boot.
+
+BOOT_S = 0.3
+
+
+class _FakeProc:
+    """What the tables hold of a brick: alive until told otherwise."""
+
+    def __init__(self):
+        self.rc = None
+
+    def poll(self):
+        return self.rc
+
+    def terminate(self):
+        self.rc = -15
+
+    kill = terminate
+
+    def wait(self, timeout=None):
+        return self.rc
+
+
+@pytest.fixture
+def stub_daemons(monkeypatch):
+    """Every ``_spawn_daemon`` of every Glusterd: (brick, port asked
+    for, start, end) in ``calls``; bricks named in ``failing`` raise
+    after the seconds given."""
+    calls, failing, procs = [], {}, []
+
+    async def spawn(self, volfile, text, portfile, logfile, top,
+                    port=None, what="brick", extra_env=None):
+        loop = asyncio.get_running_loop()
+        call = {"top": top, "port": port, "start": loop.time()}
+        calls.append(call)
+        if top in failing:
+            await asyncio.sleep(failing[top])
+            call["end"] = loop.time()
+            raise MgmtError(f"{what} failed: boom")
+        # the last one forked is the first to answer
+        await asyncio.sleep(BOOT_S - 0.01 * len(calls))
+        call["end"] = loop.time()
+        procs.append(_FakeProc())
+        return procs[-1], port or 5000 + len(procs)
+
+    monkeypatch.setattr(Glusterd, "_spawn_daemon", spawn)
+    monkeypatch.setattr(Glusterd, "_spawn_shd", lambda self, vol: None)
+    return calls, failing, procs
+
+
+def _sum_of_boots(calls):
+    return sum(c["end"] - c["start"] for c in calls)
+
+
+def _under_way_at_once(calls):
+    """Every one forked before the first has answered, and the lot in
+    under half the sum of their own times (in turn it is the sum;
+    measured against the stubs' own clock, so a stalled machine
+    stretches both)."""
+    first, last = (min(c["start"] for c in calls),
+                   max(c["end"] for c in calls))
+    return max(c["start"] for c in calls) < min(c["end"] for c in calls) \
+        and last - first < _sum_of_boots(calls) / 2
+
+
+async def _created(tmp_path, n=6, name="sv", workdir="gd"):
+    d = Glusterd(str(tmp_path / workdir))
+    await d.start()
+    async with MgmtClient(d.host, d.port) as c:
+        await c.call("volume-create", name=name, vtype="disperse",
+                     bricks=[{"path": str(tmp_path / f"b{i}")}
+                             for i in range(n)], redundancy=2)
+    return d
+
+
+def _stored_ports(d, name="sv"):
+    import json
+
+    with open(d._store) as f:
+        return [b.get("port")
+                for b in json.load(f)["volumes"][name]["bricks"]]
+
+
+def test_volume_start_spawns_local_bricks_side_by_side(tmp_path,
+                                                       stub_daemons):
+    calls, _, procs = stub_daemons
+
+    async def run():
+        d = await _created(tmp_path)
+        saves = []
+        save, start = d._save, d._start_bricks
+
+        async def counted(*a, **kw):
+            saves.clear()
+            await start(*a, **kw)
+            saves.append("returned")
+
+        d._save = lambda: (saves.append("save"), save())
+        d._start_bricks = counted
+        try:
+            loop = asyncio.get_running_loop()
+            t = loop.time()
+            async with MgmtClient(d.host, d.port) as c:
+                await c.call("volume-start", name="sv")
+            took = loop.time() - t
+            assert len(calls) == 6 and _under_way_at_once(calls)
+            # the whole RPC (lock, stage, hooks, commit) in less than
+            # the six boots in turn would take
+            assert took < _sum_of_boots(calls)
+            # one store for the six, after the last has settled
+            assert saves[:2] == ["save", "returned"]
+            names = [f"sv-brick-{i}" for i in range(6)]
+            vol = d.state["volumes"]["sv"]
+            assert [b["name"] for b in vol["bricks"]] == names
+            assert list(d.bricks) == names and list(d.ports) == names
+            ports = [d.ports[n] for n in names]
+            assert len(set(ports)) == 6
+            assert [b["port"] for b in vol["bricks"]] == ports
+            assert _stored_ports(d) == ports
+            # ... whatever order they answered in
+            assert [d.bricks[n] for n in names] == procs[::-1]
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("failing", [
+    {3: BOOT_S / 2},
+    # a later brick that fails sooner is not the one reported
+    {3: 1.5 * BOOT_S, 5: BOOT_S / 3}])
+def test_volume_start_reports_first_failed_brick_and_keeps_the_rest(
+        tmp_path, stub_daemons, failing):
+    calls, fails, procs = stub_daemons
+    fails.update({f"sv-brick-{i}-server": s for i, s in failing.items()})
+
+    async def run():
+        d = await _created(tmp_path)
+        try:
+            async with MgmtClient(d.host, d.port) as c:
+                with pytest.raises(Exception) as e:
+                    await c.call("volume-start", name="sv")
+            assert "brick sv-brick-3 failed" in str(e.value)
+            # every brick was tried, beside the one that failed
+            assert len(calls) == 6 and _under_way_at_once(calls)
+            up = [f"sv-brick-{i}" for i in range(6) if i not in failing]
+            assert list(d.bricks) == up and list(d.ports) == up
+            # no process the tables do not hold
+            assert sorted(map(id, d.bricks.values())) == \
+                sorted(map(id, procs))
+            assert _stored_ports(d) == [
+                None if i in failing else d.ports[f"sv-brick-{i}"]
+                for i in range(6)]
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
+
+
+def test_restart_resume_respawns_side_by_side(tmp_path, stub_daemons):
+    calls, _, _ = stub_daemons
+
+    async def run():
+        d = await _created(tmp_path)
+        async with MgmtClient(d.host, d.port) as c:
+            await c.call("volume-start", name="sv")
+        await d.stop()
+        del calls[:]
+        d = Glusterd(str(tmp_path / "gd"))
+        await d.start()
+        try:
+            assert [c["top"] for c in calls] == [
+                f"sv-brick-{i}-server" for i in range(6)]
+            assert _under_way_at_once(calls)
+            # a node that comes back binds fresh ports (and pushes
+            # them: _broadcast_local_ports), as before
+            assert [c["port"] for c in calls] == [None] * 6
+            assert _stored_ports(d) == [
+                d.ports[f"sv-brick-{i}"] for i in range(6)]
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
+
+
+def test_quorum_respawn_is_side_by_side_on_the_persisted_ports(
+        tmp_path, stub_daemons):
+    calls, _, _ = stub_daemons
+
+    async def run():
+        d = await _created(tmp_path)
+        try:
+            async with MgmtClient(d.host, d.port) as c:
+                await c.call("volume-start", name="sv")
+            vol = d.state["volumes"]["sv"]
+            ports = [b["port"] for b in vol["bricks"]]
+            # fenced by a lost quorum, then the peers are gone
+            # (detach): the next tick lifts the fence
+            for b in vol["bricks"]:
+                await d._stop_brick(vol, b)
+            d._quorum_blocked.add("sv")
+            del calls[:]
+            await d._check_server_quorum()
+            assert not d._quorum_blocked and len(d.bricks) == 6
+            assert _under_way_at_once(calls)
+            assert [c["port"] for c in calls] == ports
+            assert [d.ports[b["name"]] for b in vol["bricks"]] == ports
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
+
+
+def test_mux_volume_attaches_in_brick_order(tmp_path, stub_daemons,
+                                            monkeypatch):
+    calls, _, _ = stub_daemons
+    attaches = []
+
+    async def brick_call(vol, port, name, args, **kw):
+        loop = asyncio.get_running_loop()
+        attaches.append({"top": args[1], "start": loop.time()})
+        await asyncio.sleep(0.02)
+        attaches[-1]["end"] = loop.time()
+        return {"ok": True}
+
+    monkeypatch.setattr(Glusterd, "_brick_call", staticmethod(brick_call))
+
+    async def run():
+        d = await _created(tmp_path)
+        try:
+            async with MgmtClient(d.host, d.port) as c:
+                await c.call("volume-set", name="sv",
+                             key="cluster.brick-multiplex", value="on")
+                await c.call("volume-start", name="sv")
+            assert [c["top"] for c in calls] == ["mux-anchor-server"]
+            assert [a["top"] for a in attaches] == [
+                f"sv-brick-{i}-server" for i in range(6)]
+            assert all(a["end"] <= b["start"]
+                       for a, b in zip(attaches, attaches[1:]))
+            port = d._mux["port"]
+            assert _stored_ports(d) == [port] * 6
+            d._mux["bricks"].clear()  # no daemon to detach from
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
+
+
+def test_cancelled_volume_start_leaves_no_untracked_brick(tmp_path,
+                                                          monkeypatch):
+    """Real children that never write a port file: a ``volume-start``
+    cancelled while it waits for them takes every one of them along."""
+    import subprocess
+
+    from glusterfs_tpu.mgmt import glusterd as gd_mod
+
+    children = []
+
+    def popen(argv, **kw):
+        assert argv[1:3] == ["-m", "glusterfs_tpu.daemon"]
+        children.append(subprocess.Popen(["sleep", "60"], **kw))
+        return children[-1]
+
+    async def run():
+        d = await _created(tmp_path)
+        monkeypatch.setattr(
+            gd_mod, "subprocess", type("sub", (), {
+                "Popen": staticmethod(popen), "DEVNULL": subprocess.DEVNULL,
+                "TimeoutExpired": subprocess.TimeoutExpired}))
+        try:
+            task = asyncio.create_task(d.op_volume_start("sv"))
+            for _ in range(2000):
+                if len(children) == 6:
+                    break
+                await asyncio.sleep(0.01)
+            assert len(children) == 6
+            assert all(c.poll() is None for c in children)
+            task.cancel()
+            with pytest.raises(asyncio.CancelledError):
+                await asyncio.wait_for(task, 30)
+            # terminated AND waited for: none is left a zombie
+            assert [c.returncode for c in children] == [-15] * 6
+            assert not d.bricks and not d.ports
+            assert d._txn_holder is None
+        finally:
+            for c in children:
+                if c.poll() is None:
+                    c.kill()
+                    c.wait()
+            await d.stop()
+
+    asyncio.run(run())
+
+
 # -- CLI -------------------------------------------------------------------
 
 @pytest.mark.slow
