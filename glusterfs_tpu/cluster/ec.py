@@ -70,10 +70,15 @@ _metrics.REGISTRY.register_objects(
     live=_LIVE_EC_LAYERS)
 _metrics.REGISTRY.register_objects(
     "gftpu_ec_rmw_writes_total", "counter",
-    "unaligned writes that paid the full read-modify-write (degraded, "
-    "non-systematic, EOF-crossing, delta-writes off, or a peer "
-    "without xorv)",
-    lambda l: [({"layer": l.name}, l.write_path["rmw"])],
+    "unaligned writes that paid the full read-modify-write, by cause: "
+    "ineligible (degraded, non-systematic, EOF-crossing, delta-writes "
+    "off, a layer parked after a peer without xorv) or delta_fallback "
+    "(the parity-delta wave was tried and bailed: its old-bytes read "
+    "failed, or a brick refused xorv)",
+    lambda l: [({"layer": l.name, "cause": "ineligible"},
+                l.write_path["rmw"] - l.write_path["delta_fallback"]),
+               ({"layer": l.name, "cause": "delta_fallback"},
+                l.write_path["delta_fallback"])],
     live=_LIVE_EC_LAYERS)
 _metrics.REGISTRY.register_objects(
     "gftpu_ec_split_writes_total", "counter",
@@ -370,8 +375,11 @@ class DisperseLayer(Layer):
         # parity-delta write plane (ISSUE 10): path taken per unaligned
         # write + fragment bytes the delta path saved over full RMW
         # "split" counts the write waves that went out in two parts
-        # around the codec (_writev_in_window), whatever else they were
-        self.write_path = {"delta": 0, "rmw": 0, "split": 0}
+        # around the codec (_writev_in_window), whatever else they were;
+        # "delta_fallback" the delta waves that bailed into the full
+        # RMW (each is one of "rmw" too)
+        self.write_path = {"delta": 0, "rmw": 0, "split": 0,
+                           "delta_fallback": 0}
         # delta writes split by traffic_origin ("serve" vs "rebalance"
         # vs "heal"): write_path["delta"] stays the total; this dict
         # feeds the per-origin samples on the registry family so an
@@ -1750,14 +1758,15 @@ class DisperseLayer(Layer):
             if hi - lo != sum(uhi - ulo for _f, ulo, uhi in ps):
                 raise _DeltaFallback()  # non-contiguous (cannot happen)
             intervals[j] = (lo, hi)
-        with _tracing.phase(self.name, "delta-write", self.phases):
+        with _tracing.phase(self.name, "ec.delta_write", self.phases):
             # old bytes: one ranged readv per touched data fragment —
             # internal write reads, never subject to the read mask
-            res = await self._dispatch(
-                sorted(intervals), "readv",
-                lambda i: ((self._child_fd(fd, i),
-                            intervals[i][1] - intervals[i][0],
-                            intervals[i][0]), {}))
+            with _tracing.phase(self.name, "ec.delta_read", self.phases):
+                res = await self._dispatch(
+                    sorted(intervals), "readv",
+                    lambda i: ((self._child_fd(fd, i),
+                                intervals[i][1] - intervals[i][0],
+                                intervals[i][0]), {}))
             if any(isinstance(r, BaseException) for r in res.values()):
                 raise _DeltaFallback()  # read trouble: RMW sorts it out
             newbuf = np.zeros(a_len, dtype=np.uint8)
@@ -1881,7 +1890,8 @@ class DisperseLayer(Layer):
                 return await self._writev_delta(fd, loc, st, data,
                                                 offset)
             except _DeltaFallback:
-                pass  # downgraded peer / read trouble: full RMW below
+                # downgraded peer / read trouble: full RMW below
+                self.write_path["delta_fallback"] += 1
         true_size = st.size
         end = offset + len(data)
         a_off = offset // self.stripe * self.stripe

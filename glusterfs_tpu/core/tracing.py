@@ -198,8 +198,8 @@ def tag(**meta) -> None:
 
 def _phase_annot(layer_name: str, name: str) -> str:
     """A dotted name is a phase of the table in docs/observability.md
-    and stands alone; the two older labels (``mesh-codec`` + op, layer
-    + ``delta-write``) keep theirs."""
+    and stands alone; the one older label (``mesh-codec`` + op) keeps
+    its layer's name in front."""
     return "gftpu:" + name if "." in name \
         else f"gftpu:{layer_name}.{name}"
 

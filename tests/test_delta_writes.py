@@ -24,6 +24,7 @@ full read-modify-write (ec-inode-write.c:2141 analog).  Pins:
 import asyncio
 import errno
 import gc
+import json
 import os
 
 import numpy as np
@@ -128,6 +129,210 @@ def test_delta_fragments_match_oracle(tmp_path):
         frag = open(os.path.join(str(tmp_path), f"brick{i}", "f"),
                     "rb").read()
         assert frag == oracle[i].tobytes(), f"brick {i}"
+
+
+@pytest.mark.parametrize("seed", [11, 12])
+@pytest.mark.parametrize("k, r", [(4, 2), (8, 4), (16, 4)])
+def test_delta_sequences_match_the_plain_reference(tmp_path, k, r, seed):
+    """A seeded sequence of overwrites inside the file, none of them
+    stripe-aligned at both ends (inside one chunk, across chunks, half
+    a stripe, across a stripe boundary, across several), through a
+    served disperse volume of each supported width: every one takes
+    the delta wave and none falls back, and afterwards every brick's
+    fragment file is the plain reference's encoding of the model
+    (``benchmarks/harness/reference.py``, which shares no arithmetic
+    with the program; ``gf256.ref_encode`` agrees with it) and the door
+    reads the model back."""
+    from benchmarks.harness import reference
+
+    n, stripe = k + r, k * 512
+    g = Graph.construct(ec_volfile(
+        str(tmp_path), n, r, options={"systematic": "on",
+                                      "delta-writes": "on"}))
+    c = SyncClient(g)
+    c.mount()
+    ec = g.top
+    rng = np.random.default_rng([seed, k, r])
+    size = 6 * stripe
+    model = rng.integers(0, 256, size, dtype=np.uint8)
+    shapes = [(1, 511),                      # inside one chunk
+              (513, 3 * 512),                # across chunks
+              (stripe // 2, stripe // 2),    # a database's page at 16+4
+              (stripe - 700, stripe - 1),    # across a stripe boundary
+              (2 * stripe + 1, 3 * stripe)]  # across several
+    try:
+        c.write_file("/f", model.tobytes())
+        f = c.open("/f")
+        count = 0
+        for _round in range(4):
+            for lo, hi in shapes:
+                ln = int(rng.integers(lo, hi + 1))
+                off = int(rng.integers(0, size - ln + 1))
+                if off % stripe == 0 and (off + ln) % stripe == 0:
+                    off += 1 if off + ln < size else -1
+                data = rng.integers(0, 256, ln, dtype=np.uint8)
+                f.write(data.tobytes(), off)
+                model[off:off + ln] = data
+                count += 1
+        f.close()
+        assert ec.write_path["delta"] == count, ec.write_path
+        assert ec.write_path["rmw"] == 0
+        assert ec.write_path["delta_fallback"] == 0
+        assert c.read_file("/f") == model.tobytes()
+    finally:
+        c.close()
+    want = reference.encode(model, k, n)
+    assert np.array_equal(want, gf256.ref_encode(model, k, n,
+                                                 systematic=True))
+    for i in range(n):
+        frag = open(os.path.join(str(tmp_path), f"brick{i}", "f"),
+                    "rb").read()
+        assert frag == want[i].tobytes(), f"brick {i} of {k}+{r}"
+
+
+def test_delta_wave_spans_and_fallback_count(tmp_path):
+    """The wave is one ``ec.delta_write`` span under the write; its
+    children are ``ec.delta_read`` (over the old bytes' fan-out),
+    ``ec.codec_wait``, the mixed wave's ``ec.fanout`` and, where the
+    write opens its window, the pre-op's ``ec.xattrop``; both phases are in ``dump_private()["phases"]``.  A
+    wave that bails is counted in ``write_path["delta_fallback"]`` and
+    as ``cause="delta_fallback"`` of the registry's RMW family."""
+    from glusterfs_tpu.core import tracing
+
+    c, ec = _mount(tmp_path)
+    try:
+        c.write_file("/s", _rand(2 * STRIPE, seed=9).tobytes())
+        f = c.open("/s")
+        tracing.SPANS.clear()
+        f.write(b"S" * 700, 1000)
+        spans = [s for s in tracing.SPANS if s[2] == ec.name]
+        wave = [s for s in spans if s[3] == "ec.delta_write"]
+        assert len(wave) == 1, [s[3] for s in spans]
+        root = next(s for s in spans if s[3] == "writev")
+        assert wave[0][8] == root[7]  # hangs under the write
+        kids = {s[3]: s for s in spans if s[8] == wave[0][7]}
+        # (the pre-op's ec.xattrop is a fourth where the write opens
+        # its window; here the layout's window is still held)
+        assert set(kids) - {"ec.xattrop"} == {
+            "ec.delta_read", "ec.codec_wait", "ec.fanout"}
+        reads = [s for s in spans if s[8] == kids["ec.delta_read"][7]]
+        assert [s[3] for s in reads] == ["ec.fanout"]
+        assert not [s for s in spans if s[3] == "delta-write"]
+        phases = ec.dump_private()["phases"]
+        assert phases["ec.delta_write"]["count"] == 1
+        assert phases["ec.delta_read"]["count"] == 1
+        assert ec.dump_private()["write_path"]["delta_fallback"] == 0
+
+        async def refuse(*a, **kw):
+            raise FopError(errno.EOPNOTSUPP, "no xorv here")
+
+        ec.children[5].xorv = refuse
+        f.write(b"T" * 700, 1000)
+        f.close()
+        path = ec.dump_private()["write_path"]
+        assert (path["delta"], path["rmw"], path["delta_fallback"]) == \
+            (1, 1, 1)
+        gc.collect()
+        fam = REGISTRY.snapshot()["gftpu_ec_rmw_writes_total"]["samples"]
+        mine = {s[0]["cause"]: s[1] for s in fam
+                if s[0]["layer"] == ec.name}
+        assert mine == {"ineligible": 0, "delta_fallback": 1}
+    finally:
+        c.close()
+
+
+def test_group_db_workload_graph_answers_after_the_wave(tmp_path):
+    """A managed volume with the twelve keys of ``group db-workload``
+    set: the client graph volgen builds has no write-behind, io-cache,
+    read-ahead, quick-read, md-cache or readdir-ahead, keeps
+    open-behind and gains client-side io-threads; a sub-stripe write
+    through it takes the delta wave, and its answer at the door comes
+    after ``cluster/ec``'s (nothing above acknowledges it early)."""
+    from glusterfs_tpu.core.layer import walk
+    from glusterfs_tpu.mgmt.glusterd import (Glusterd, MgmtClient,
+                                             mount_volume)
+
+    # the twelve keys as the deployment's configuration file has them
+    # (beside its two pins, which are cluster.* and disperse.*)
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "benchmarks", "configs",
+                           "ec-16p4-db-tpu.json")) as f:
+        group = {key: value for key, value in json.load(f)["options"].items()
+                 if key.startswith(("performance.", "server.", "client."))}
+    assert len(group) == 12
+    data = _rand(4 * STRIPE, seed=41).tobytes()
+
+    async def run():
+        d = Glusterd(str(tmp_path / "gd"))
+        await d.start()
+        try:
+            async with MgmtClient(d.host, d.port) as c:
+                await c.call("volume-create", name="db",
+                             vtype="disperse", redundancy=2,
+                             bricks=[{"path": str(tmp_path / f"b{i}")}
+                                     for i in range(6)])
+                for key, value in group.items():
+                    res = await c.call("volume-set", name="db", key=key,
+                                       value=value)
+                    assert res["ok"], (key, res)
+                await c.call("volume-start", name="db")
+                info = await c.call("volume-info", name="db")
+                assert group.items() <= \
+                    info["db"]["options"].items()
+            cl = await mount_volume(d.host, d.port, "db")
+            try:
+                layers = list(walk(cl.graph.top))
+                types = [layer.type_name for layer in layers]
+                assert not {"performance/write-behind",
+                            "performance/io-cache",
+                            "performance/read-ahead",
+                            "performance/quick-read",
+                            "performance/md-cache",
+                            "performance/readdir-ahead"} & set(types)
+                assert {"performance/open-behind",
+                        "performance/io-threads"} <= set(types)
+                # io-threads stands above cluster/ec, open-behind
+                # between them
+                assert types.index("performance/io-threads") < \
+                    types.index("performance/open-behind") < \
+                    types.index("cluster/disperse")
+                ec = next(l for l in layers
+                          if l.type_name == "cluster/disperse")
+                iot = next(l for l in layers
+                           if l.type_name == "performance/io-threads")
+                client = next(l for l in layers
+                              if l.type_name == "protocol/client")
+                assert client.opts["event-threads"] == 4
+                await cl.write_file("/x", data)
+                order = []
+                real = ec.writev
+
+                async def writev(*args, **kwargs):
+                    try:
+                        return await real(*args, **kwargs)
+                    finally:
+                        order.append("cluster/ec answered")
+
+                ec.writev = writev
+                f = await cl.open("/x")
+                before = iot.executed[1]
+                await f.write(b"Q" * 700, 1000)
+                order.append("the door answered")
+                assert order == ["cluster/ec answered",
+                                 "the door answered"]
+                assert iot.executed[1] == before + 1  # through its gate
+                assert ec.write_path["delta"] == 1, ec.write_path
+                assert ec.write_path["rmw"] == 0
+                await f.close()
+                exp = bytearray(data)
+                exp[1000:1700] = b"Q" * 700
+                assert bytes(await cl.read_file("/x")) == bytes(exp)
+            finally:
+                await cl.unmount()
+        finally:
+            await d.stop()
+
+    asyncio.run(run())
 
 
 # -- the property test -------------------------------------------------

@@ -1356,8 +1356,16 @@ def test_another_threads_dump_reads_the_loops_threads_clock(tmp_path):
         assert meter.thread == c._thread.ident != threading.get_ident()
         before = c.statedump()["loop"]
         c._loop.call_soon_threadsafe(spin)
-        time.sleep(0.25)
-        dump = c.statedump()["loop"]
+        # on a busy machine 30 ms of the thread's CPU can take most of
+        # a quarter second: wait for the idle time to show, not a fixed
+        # sleep
+        deadline = time.monotonic() + 10
+        while True:
+            time.sleep(0.25)
+            dump = c.statedump()["loop"]
+            if dump["select_s"] - before["select_s"] > 0.15 \
+                    or time.monotonic() > deadline:
+                break
         assert dump["metered"] and dump["passes"] >= before["passes"] + 2
         assert dump["cpu_s"] - before["cpu_s"] >= 0.03
         assert dump["busy_s"] - before["busy_s"] >= 0.03

@@ -416,7 +416,7 @@ def test_data_fragments_go_out_while_parity_is_held(tmp_path):
 
         c._run(drive())
         assert ec.dump_private()["write_path"] == {
-            "delta": 0, "rmw": 0, "split": 1}
+            "delta": 0, "rmw": 0, "split": 1, "delta_fallback": 0}
         assert c.read_file("/a") == data
     finally:
         c.close()
