@@ -24,6 +24,15 @@ to finish the mix's longest operation many times over); the tests under
     a data brick's fragment files is flipped on the brick directory.
     Breaks "an acknowledged, fsynced write is readable byte-exact
     through the door" for a healthy read, which never touches the codec.
+    In a heal cell the heal reads brick 0 and carries the altered bytes
+    onto the returning brick, and the door reads them from brick 0.
+``heal_leaves_one_file`` the heal sweep passes over one file, drawn by
+    the seed, and reports it as if there was nothing to heal.  Breaks
+    "from any 4 of a group's 6 bricks" once the returning brick is back
+    and the sweep has returned.
+
+In a heal cell ``codec_answer_altered`` bites through the decode: the
+heal rebuilds the returning data brick's rows from the rebuilt bytes.
 """
 
 from __future__ import annotations
@@ -81,9 +90,23 @@ def brick_fragment_altered(run) -> None:
                     f.write(data)
 
 
+def heal_leaves_one_file(run) -> None:
+    t = run.traffic
+    skipped = t.names[t.seed % t.jobs]
+    for ec in run.volume.ecs:
+
+        async def heal_file(path, real=ec.heal_file):
+            if path == skipped:
+                return {"healed": [], "skipped": True}
+            return await real(path)
+
+        ec.heal_file = heal_file
+
+
 FAULTS = {f.__name__: f for f in (codec_answer_altered,
                                   acked_write_half_stored,
-                                  brick_fragment_altered)}
+                                  brick_fragment_altered,
+                                  heal_leaves_one_file)}
 
 
 def main(argv=None) -> int:

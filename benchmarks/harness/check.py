@@ -9,14 +9,19 @@ Every number compared is exact, so every limit is 0:
                           files (the acknowledged writes, replayed) and
                           what the door returns: the answers the window
                           itself got, a sample drawn from the seed, and,
-                          where the window wrote, a read-back of the
-                          files after it closed, through the same mount;
+                          where the window wrote or healed, a read-back
+                          of the files after it closed, through the same
+                          mount (after a heal, of every file whole);
 ``fragment_bad_bytes``    bytes on the brick directories that differ
                           from the reference encoding of the model, over
                           extents drawn from the seed plus each file's
-                          last written one: data fragments and parity;
+                          last written one (after a heal, its first one
+                          too): data fragments and parity, on every
+                          brick of the file's group, the returning ones
+                          included;
 ``bricks_wrongly_down``   bricks not online that the mix did not stop;
-``heal_pending``          entries pending heal, where no brick is down;
+``heal_pending``          entries pending heal, where no brick is down
+                          (after a heal, none may be left);
 ``flushes_off_device``    codec flushes of the window that did not
                           launch on the device, or went to the CPU
                           ladder: the configuration pins the device;
@@ -80,10 +85,11 @@ async def door_readback(traffic: Traffic, j: int, buf: np.ndarray,
         *(lane(offsets[i::depth]) for i in range(depth))))
 
 
-def fragment_extents(traffic: Traffic, j: int, count: int,
-                     stripe: int) -> list[tuple[int, int]]:
+def fragment_extents(traffic: Traffic, j: int, count: int, stripe: int,
+                     first: bool = False) -> list[tuple[int, int]]:
     """Extents of file j to hold against the bricks: ``count`` drawn
-    from the seed, and the one its last write touched."""
+    from the seed, the one its last write touched and, with ``first``,
+    the file's first."""
     rng = np.random.default_rng([traffic.seed, 2, j])
     span = min(MIB, traffic.file_bytes)
     starts = set(int(x) * span for x in rng.integers(
@@ -92,6 +98,8 @@ def fragment_extents(traffic: Traffic, j: int, count: int,
                  if op[2] == WRITE), None)
     if last is not None:
         starts.add(last[3] // span * span)
+    if first:
+        starts.add(0)
     return [(s // stripe * stripe, span) for s in sorted(starts)]
 
 
@@ -135,6 +143,8 @@ async def compare(run) -> dict[str, list]:
     rng = np.random.default_rng([t.seed, 3])
     readback = set(rng.permutation(t.jobs)[:int(mix.get(
         "verify_files", t.jobs))].tolist()) if wrote else set()
+    if t.heal:  # the returning bricks are read through the door too
+        readback = set(range(t.jobs))
     for j in range(t.jobs):
         layout_ops = run.layout_ops[j]
         buf = t.contents(j, upto=layout_ops)
@@ -152,7 +162,7 @@ async def compare(run) -> dict[str, list]:
             compared, bad = await asyncio.to_thread(
                 fragments_on_bricks, bricks, t.names[j], k, n, buf,
                 fragment_extents(t, j, int(mix.get("verify_extents", 2)),
-                                 k * reference.CHUNK))
+                                 k * reference.CHUNK, first=t.heal))
             seen["fragments"] += compared
             frag_bad += bad
     if not (seen["answers"] or seen["readback"]) or not seen["fragments"]:

@@ -11,6 +11,13 @@ a fixed multiset of (read | write) x block size in the mix's shares,
 shuffled by the seed, so that every seed does the same work in another
 order.  Payloads are slices of one pool made from the seed in set-up.
 
+Two keys make a heal mix; a mix without them never meets them.
+``bricks_returning`` lists bricks that are stopped once the files are
+created and before a byte is written (so they come back holding every
+file empty, with none of its data), and started again after the
+layout.  ``window: "heal"`` puts one heal sweep in the window in place
+of the jobs' loop (``benchmarks/run.py``).
+
 Each operation is timed at the door from call to return and recorded
 as one tuple; that record is the model of the files' contents, the
 source of every rate and percentile, and nothing heavier happens per
@@ -81,6 +88,8 @@ class Traffic:
         self.layout_block = int(mix.get("layout_block_KiB", 1024) * KIB)
         self.layout_jobs = int(mix.get("layout_jobs", 4))
         self.names = [f"/job{j}" for j in range(self.jobs)]
+        self.returning = list(mix.get("bricks_returning", []))
+        self.heal = mix.get("window") == "heal"
         rng = np.random.default_rng([seed, 0])
         pool_bytes = int(mix.get("pool_MiB", 64) * MIB)
         big = max(self.blocks + [self.layout_block])
@@ -164,15 +173,23 @@ class Traffic:
 
     # -- set-up ------------------------------------------------------------
 
+    async def create(self, client) -> None:
+        """Create every file, empty (a heal mix, before its returning
+        bricks stop)."""
+        for j, name in enumerate(self.names):
+            self.files[j] = await client.create(name, os.O_RDWR | os.O_EXCL)
+
     async def layout(self, client) -> None:
-        """Create every file, write it once end to end and fsync it."""
+        """Create every file not yet created, write it once end to end
+        and fsync it."""
         flight = InFlight()
         sem = asyncio.Semaphore(self.layout_jobs)
 
         async def one(j: int) -> None:
             async with sem:
-                self.files[j] = await client.create(
-                    self.names[j], os.O_RDWR | os.O_EXCL)
+                if self.files[j] is None:
+                    self.files[j] = await client.create(
+                        self.names[j], os.O_RDWR | os.O_EXCL)
                 for off in range(0, self.file_bytes, self.layout_block):
                     size = min(self.layout_block, self.file_bytes - off)
                     if not await self._op(j, WRITE, size, off, flight):

@@ -101,15 +101,24 @@ class Volume:
             return await c.call(method, name=VOLUME, **kwargs)
 
     async def stop_brick(self, index: int) -> None:
+        await self._brick(index, "stop", up=False)
+
+    async def start_brick(self, index: int) -> None:
+        """The brick's daemon again, on the port it had (``volume start
+        force`` upstream): what a brick that comes back does, once the mounted
+        ``cluster/ec`` has reconnected to it."""
+        await self._brick(index, "start", up=True)
+
+    async def _brick(self, index: int, action: str, up: bool) -> None:
         await self.rpc("volume-brick", brick=f"{VOLUME}-brick-{index}",
-                       action="stop")
+                       action=action)
         n = self.config["geometry"]["data"] + \
             self.config["geometry"]["redundancy"]
         ec = self.ecs[index // n]
         deadline = time.monotonic() + 60
-        while ec.up[index % n]:
+        while ec.up[index % n] != up:
             if time.monotonic() > deadline:
-                raise VolumeError(f"brick {index} did not go down")
+                raise VolumeError(f"brick {index} did not {action}")
             await asyncio.sleep(0.05)
 
     def log_tail(self, n: int = 3000) -> str:

@@ -50,6 +50,16 @@ def reconstruct_bytes(user_bytes: int, k: int, missing: int) -> int:
     return s * k * CHUNK + s * missing * CHUNK
 
 
+def heal_bytes(user_bytes: int, k: int, returning: int) -> int:
+    """Heal of S stripes onto ``returning`` bricks that hold none of them:
+    the k chunks of each stripe read from the bricks that kept it go in,
+    one chunk for each returning brick comes out, data or parity alike.
+    A decode of the lost rows and a second launch that encodes the
+    whole stripe again move more than this, and that is waste."""
+    s = stripes(user_bytes, k)
+    return s * k * CHUNK + s * returning * CHUNK
+
+
 def roofline_share(bytes_moved: int, device_kind: str,
                    device_seconds: float) -> float | None:
     """Per cent of the memory roofline: least time over measured time.

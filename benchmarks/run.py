@@ -9,7 +9,10 @@ backend start-up beside volume create/start, mount, payload pool, file
 layout, bricks stopped, every coding shape warmed, profiler start) is
 timed as ``setup_s``; then the window holds the steady loop and nothing
 else; then the counters are read, the trace is reduced, and what the
-window left behind is compared with the plain reference.  The last line
+window left behind is compared with the plain reference.  A heal mix
+(``harness/traffic.py``) creates its files, stops its returning bricks,
+lays the files out, starts the bricks again in set-up, and holds one
+heal sweep in the window in place of the loop.  The last line
 of stdout is the result; earlier lines are for the reader.  Without an
 accelerator, or beside nothing else of the repo, it exits non-zero and
 prints no result.
@@ -78,6 +81,10 @@ class Run:
         self.layout_ops: list[int] = []
         self.trace: dict | None = None
         self.compiles = {"requests": 0, "hits": 0, "secs": 0.0}
+        #: a heal window's: user bytes brought back to full redundancy
+        #: inside it, and the sweep's report once it has ended
+        self.rebuilt_bytes: int | None = None
+        self.heal_report: dict | None = None
         self.fault = None
         self._log_mark = f"benchmark run {time.monotonic_ns()} begins"
 
@@ -96,9 +103,18 @@ class Run:
         return self.traffic.ops(*self.window)
 
     @property
+    def attempted(self) -> int:
+        """Operations of the window; in a heal window, files swept."""
+        if self.heal_report is not None:
+            return sum(len(self.heal_report[key])
+                       for key in ("healed", "skipped", "failed"))
+        return len(self.ops)
+
+    @property
     def failed(self) -> int:
-        return sum(1 for log in self.traffic.log for op in log
-                   if op[0] >= self.window[0] and not op[6])
+        heals = len(self.heal_report["failed"]) if self.heal_report else 0
+        return heals + sum(1 for log in self.traffic.log for op in log
+                           if op[0] >= self.window[0] and not op[6])
 
     def user_bytes(self, kind: int) -> int:
         return sum(op[4] for op in self.ops if op[2] == kind and op[6])
@@ -167,9 +183,12 @@ class Run:
         synchronous entry: each power-of-two stripe bucket from the
         mix's ``warm_stripes_min`` to its ``warm_stripes_max``, for the encode where the mix writes
         and for every k-subset of the surviving fragments where bricks
-        are down.  The warm loop through the door follows; this makes
-        sure that a bucket it happened not to hit is compiled, or loaded
-        from the cache, before the window."""
+        are down.  A heal re-encodes what it decodes from the first k
+        bricks that kept the file (``cluster/ec heal_file``), so where
+        bricks return the encode and that one mask are warmed.  The warm
+        loop through the door follows; this makes sure that a bucket it
+        happened not to hit is compiled, or loaded from the cache,
+        before the window."""
         import numpy as np
 
         g = self.config["geometry"]
@@ -179,14 +198,17 @@ class Run:
         buckets = [b for b in (16 << i for i in range(16)) if lo <= b <= top]
         down = {d % n for d in self.mix.get("bricks_down", [])}
         up = [i for i in range(n) if i not in down]
+        masks = list(itertools.combinations(up, k)) if down else []
+        back = {d % n for d in self.traffic.returning}
+        if back:
+            masks.append(tuple(i for i in range(n) if i not in back)[:k])
         for ec in self.volume.ecs:
             for b in buckets:
-                if self.traffic.read_share < 1.0:
+                if self.traffic.read_share < 1.0 or back:
                     ec.codec.encode(np.zeros(b * k * 512, dtype=np.uint8))
-                if down:
-                    for rows in itertools.combinations(up, k):
-                        ec.codec.decode(np.zeros((k, b * 512),
-                                                 dtype=np.uint8), rows)
+                for rows in masks:
+                    ec.codec.decode(np.zeros((k, b * 512), dtype=np.uint8),
+                                    rows)
 
     def check_pinned(self) -> None:
         from glusterfs_tpu.ops import batch
@@ -218,9 +240,15 @@ class Run:
         t_started = time.monotonic()
         await self.volume.mount()
         self.check_pinned()
+        if self.traffic.returning:
+            await self.traffic.create(self.volume.client)
+            for index in self.traffic.returning:
+                await self.volume.stop_brick(index)
         await self.traffic.layout(self.volume.client)
         self.layout_ops = [len(log) for log in self.traffic.log]
         t_laid = time.monotonic()
+        for index in self.traffic.returning:
+            await self.volume.start_brick(index)
         if self.fault is not None:
             self.fault(self)
         for index in self.mix.get("bricks_down", []):
@@ -252,11 +280,22 @@ class Run:
         self.setup_s = time.monotonic() - T0
         with devtrace.annotate()(devtrace.WINDOW) if tracing \
                 else contextlib.nullcontext():
-            self.window = await self.traffic.run(self.args.seconds, flight)
+            if self.traffic.heal:
+                sweep = await self.heal(flight)
+            else:
+                self.window = await self.traffic.run(self.args.seconds,
+                                                     flight)
         requests = self.compiles["requests"] - compiles["requests"]
         path = devtrace.stop(trace_dir) if tracing else None
         self.counters.append(snapshot.counters(self.volume))
         self.profile.append(await snapshot.brick_profile(self.volume))
+        if self.traffic.heal:  # a sweep the cap cut ends here, untimed
+            self.heal_report = await sweep
+            self.note("heal", rebuilt_bytes=self.rebuilt_bytes,
+                      ended_s=time.perf_counter() - self.window[0],
+                      **{key: len(self.heal_report[key])
+                         for key in ("healed", "skipped", "failed")},
+                      first_failure=self.heal_report["failed"][:1])
         self.note("window_end", host=snapshot.host_state(self.volume.gd.pid),
                   calibration=self.counters[1]["calibration"],
                   compiles_in_window=requests,
@@ -280,6 +319,47 @@ class Run:
                           custom_call_s=self.trace["custom_call_s"])
                 self.device["busy_s"] = self.trace["busy_s"]
                 self.device["window_s"] = self.trace["window_s"]
+
+    async def heal(self, flight: InFlight) -> asyncio.Future:
+        """The heal window: one full sweep (``mgmt/shd.full_crawl``,
+        what ``volume heal VOL full`` and glustershd run) on this
+        process's mount, ``disperse.shd-max-threads`` files at a time
+        (upstream's default, 1, where the configuration sets none), from
+        its call to its return or to ``--seconds``, whichever comes
+        first.  What it rebuilt is read off the returning bricks at both
+        edges.  Returns the sweep, which runs on where the cap came
+        first."""
+        from glusterfs_tpu.mgmt import shd
+
+        max_heals = int(self.config["options"].get(
+            "disperse.shd-max-threads", 1))
+        before = self.returned_bytes()
+        flight.enter("heal")
+        start = time.perf_counter()
+        sweep = asyncio.ensure_future(
+            shd.full_crawl(self.volume.client, max_heals))
+        await asyncio.wait([sweep], timeout=self.args.seconds)
+        end = time.perf_counter()
+        self.rebuilt_bytes = self.returned_bytes() - before
+        flight.exit("heal")
+        self.window = (start, end)
+        return sweep
+
+    def returned_bytes(self) -> int:
+        """User bytes whose stripes the returning bricks hold again: the
+        sizes of the mix's files on those bricks, times k, over the
+        number of bricks returning.  A heal writes a file's fragments
+        from offset 0 up, one window at a time, to every returning brick
+        at once, so this is exact to a window with no counter of the
+        program's."""
+        k = self.config["geometry"]["data"]
+        total = 0
+        for index in self.traffic.returning:
+            for name in self.traffic.names:
+                with contextlib.suppress(FileNotFoundError):
+                    total += os.path.getsize(os.path.join(
+                        self.volume.bricks[index], name.lstrip("/")))
+        return total * k // len(self.traffic.returning)
 
     def kernel_alone(self, metrics: dict) -> None:
         """For the reader, never a metric: the share the hand-written
@@ -334,7 +414,7 @@ async def run_cell(args, manifest: Manifest | None = None,
     try:
         await run.set_up()
         await run.measure()
-        result = {"correct": False, "attempted": len(run.ops),
+        result = {"correct": False, "attempted": run.attempted,
                   "failed": run.failed, "metrics": run.metrics(),
                   "device": run.device}
         if run.trace:

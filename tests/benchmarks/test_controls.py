@@ -22,6 +22,13 @@ from tests.benchmarks.test_rehearsal import CELLS, rehearse
      "door_bad_bytes"),
     (CELLS["sequential-read-degraded"], "brick_fragment_altered",
      "door_bad_bytes"),
+    (CELLS["sequential-write-four"], "codec_answer_altered",
+     "fragment_bad_bytes"),
+    (CELLS["sequential-write-four"], "acked_write_half_stored",
+     "door_bad_bytes"),
+    (CELLS["heal-full"], "codec_answer_altered", "fragment_bad_bytes"),
+    (CELLS["heal-full"], "brick_fragment_altered", "door_bad_bytes"),
+    (CELLS["heal-full"], "heal_leaves_one_file", "fragment_bad_bytes"),
 ])
 def test_a_broken_guarantee_is_not_correct(tmp_path, cell, fault, number):
     _m, result = rehearse(tmp_path, cell, fault=control.FAULTS[fault])

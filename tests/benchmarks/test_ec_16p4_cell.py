@@ -82,7 +82,7 @@ def test_the_manifest_has_the_cell_as_the_issue_cut_it():
     # what W reports per layer, the new cell reports too
     assert [x["name"] for x in m.cell_metrics(CELL, "per_layer")] == \
         [x["name"] for x in m.cell_metrics(W, "per_layer")]
-    assert m.metrics["write_wire_send_ms"]["workloads"] == [CELL, W]
+    assert {CELL, W} <= set(m.metrics["write_wire_send_ms"]["workloads"])
 
 
 def test_run_end_to_end(tmp_path, monkeypatch):

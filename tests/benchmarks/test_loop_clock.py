@@ -18,6 +18,12 @@ NEW = {"write_loop_cpu_share": "cpu", "read_loop_cpu_share": "cpu",
        "write_loop_offcpu_share": "offcpu",
        "read_loop_offcpu_share": "offcpu",
        "write_loop_pass_ms": "pass", "read_loop_pass_ms": "pass"}
+EIGHT = list(NEW) + ["write_flush_offcpu_ms", "read_flush_offcpu_ms"]
+#: how many of the eight a cell reports where that is not four: B's
+#: window begins no encode flush (its delta lane has a metric of its
+#: own), and a heal window has no door operation to divide by
+REPORTS = {"ec-16p4-db-tpu.randwrite-4k-c64": 3,
+           "ec-4p2-tpu.heal-full-1brick": 0}
 
 
 def sample(start_ms, busy_ms, cpu_ms, passes=10, slowest_ms=None,
@@ -168,13 +174,15 @@ def test_the_manifest_holds_the_eight_metrics():
         assert m.metrics[name]["source"] == "program_counter"
     assert _metric("write_flush_offcpu_ms") == {"op": "encode"}
     assert _metric("read_flush_offcpu_ms") == {"op": "decode"}
-    new = [x for x in m.doc["per_layer"]
-           if x["layer"] == "client loop and interpreter"]
-    assert len(new) == 8 and m.doc["per_layer"][-8:] == new
+    # the eight are the layer's, wherever they stand in the list: later
+    # PRs append metrics of this layer and of others
+    layer = {x["name"] for x in m.doc["per_layer"]
+             if x["layer"] == "client loop and interpreter"}
+    assert set(EIGHT) <= layer
     for cell in m.cells:
         mine = [x["name"] for x in m.cell_metrics(cell, "per_layer")
-                if x in [dict(n, **m.metric_file(n["name"])) for n in new]]
-        assert len(mine) == 4, (cell, mine)
+                if x["name"] in EIGHT]
+        assert len(mine) == REPORTS.get(cell, 4), (cell, mine)
 
 
 @pytest.mark.parametrize("cell, kind", [

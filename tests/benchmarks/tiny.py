@@ -79,7 +79,8 @@ def tiny_root(tmp: str, jobs: int = 3, extra: bool = False) -> str:
         mix = _load(os.path.join(tdir, name))
         mix.update(SHRINK, jobs=min(mix["jobs"], jobs))
         mix["block_KiB"] = [min(b, 64) for b in mix["block_KiB"]]
-        mix["warm_stripes_max"] = min(mix["warm_stripes_max"], 64)
+        if mix.get("window") != "heal":  # a heal window is 1 MiB still
+            mix["warm_stripes_max"] = min(mix["warm_stripes_max"], 64)
         mix["verify_files"] = mix["jobs"]
         mix["sample_reads_every"] = 2
         _dump(mix, os.path.join(tdir, name))
