@@ -18,12 +18,61 @@ NEW = {"write_loop_cpu_share": "cpu", "read_loop_cpu_share": "cpu",
        "write_loop_offcpu_share": "offcpu",
        "read_loop_offcpu_share": "offcpu",
        "write_loop_pass_ms": "pass", "read_loop_pass_ms": "pass"}
-EIGHT = list(NEW) + ["write_flush_offcpu_ms", "read_flush_offcpu_ms"]
-#: how many of the eight a cell reports where that is not four: B's
-#: window begins no encode flush (its delta lane has a metric of its
-#: own), and a heal window has no door operation to divide by
-REPORTS = {"ec-16p4-db-tpu.randwrite-4k-c64": 3,
-           "ec-4p2-tpu.heal-full-1brick": 0}
+FLUSH = {"write": "write_flush_offcpu_ms", "read": "read_flush_offcpu_ms"}
+EIGHT = list(NEW) + list(FLUSH.values())
+SHARES = ("loop_cpu_share", "loop_offcpu_share", "loop_pass_ms")
+#: each kind's flush metrics: the write kind's delta lane (B's) flushes
+#: apart from the encode flush and has a metric of its own
+FLUSHES = {"write": (FLUSH["write"], "write_delta_flush_offcpu_ms"),
+           "read": (FLUSH["read"],)}
+
+
+def door_kinds(mix: dict) -> set:
+    """The kinds of door operation a mix does: none in a heal window."""
+    if mix.get("window") == "heal":
+        return set()
+    share = float(mix["read_share"])
+    return {kind for kind, does in (("write", share < 1), ("read", share > 0))
+            if does}
+
+
+def loop_metric_problems(m: Manifest) -> list:
+    """What each cell reports of the eight, by a rule on its mix and
+    never by its name.  A heal window has no door operation to divide
+    by: none of the eight.  A cell with door operations: the three loop
+    metrics of exactly one kind that its mix does (one loop, one
+    process, told once), and no other kind's; and at least one flush
+    metric, each only where its list names the cell (a window may begin
+    no flush of a kind, as B's begins no encode flush) and only of a
+    kind the mix does."""
+    bad = []
+    for flush in FLUSH.values():
+        if "workloads" not in m.metrics[flush]:
+            bad.append(f"{flush} has no list: every later cell would owe it")
+    for name, cell in m.cells.items():
+        kinds = door_kinds(m.traffic(cell))
+        names = {x["name"] for x in m.cell_metrics(name, "per_layer")}
+        got = names & set(EIGHT)
+        flushes = {f for fs in FLUSHES.values() for f in fs} & names
+        if not kinds:
+            if got | flushes:
+                bad.append(f"{name}: a heal window reports "
+                           f"{sorted(got | flushes)}")
+            continue
+        told = [k for k in ("write", "read")
+                if any(f"{k}_{s}" in got for s in SHARES)]
+        if len(told) != 1 or told[0] not in kinds or not all(
+                f"{told[0]}_{s}" in got for s in SHARES):
+            bad.append(f"{name}: loop metrics {sorted(got)} for door "
+                       f"operations {sorted(kinds)}")
+        if not flushes:
+            bad.append(f"{name}: no flush metric for door operations "
+                       f"{sorted(kinds)}")
+        for kind, fs in FLUSHES.items():
+            for flush in flushes & set(fs):
+                if kind not in kinds:
+                    bad.append(f"{name}: {flush} without a {kind}")
+    return bad
 
 
 def sample(start_ms, busy_ms, cpu_ms, passes=10, slowest_ms=None,
@@ -179,10 +228,7 @@ def test_the_manifest_holds_the_eight_metrics():
     layer = {x["name"] for x in m.doc["per_layer"]
              if x["layer"] == "client loop and interpreter"}
     assert set(EIGHT) <= layer
-    for cell in m.cells:
-        mine = [x["name"] for x in m.cell_metrics(cell, "per_layer")
-                if x["name"] in EIGHT]
-        assert len(mine) == REPORTS.get(cell, 4), (cell, mine)
+    assert loop_metric_problems(m) == []
 
 
 @pytest.mark.parametrize("cell, kind", [

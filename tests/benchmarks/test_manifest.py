@@ -55,8 +55,8 @@ def test_every_file_under_paths_has_a_contract_name():
 def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     """A later PR's whole diff for a new cell: one configuration file,
     one mix, metric files over readers that are there, and entries
-    (``tiny.add_extra``; ``test_rehearsal`` runs that cell)."""
-    root = tiny.tiny_root(str(tmp_path), extra=True)
+    (``data/extra``; ``test_rehearsal`` runs that cell)."""
+    root = tiny.tiny_root(str(tmp_path), add=("extra",))
     m = Manifest(root, os.path.join(root, "benchmarks"))
     assert m.problems() == []
     cell = m.cell(tiny.EXTRA_CELL)

@@ -1,7 +1,7 @@
 """One rehearsal of a whole run per kind of traffic, on the CPU at tiny
 size (the ``xla`` backend stands in for the chip, as in
 ``tests/test_chip_smoke.py``).  The random engine has no committed cell
-yet; it runs the throw-away extra cell of ``tiny.add_extra``."""
+yet; it runs the throw-away extra cell of ``data/extra``."""
 
 import asyncio
 import json
@@ -27,7 +27,8 @@ KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 def rehearse(tmp_path, cell, trace=0, fault=None, seed=3000000019,
              seconds=1.0):
-    root = tiny.tiny_root(str(tmp_path), extra=cell == tiny.EXTRA_CELL)
+    add = ("extra",) if cell == tiny.EXTRA_CELL else ()
+    root = tiny.tiny_root(str(tmp_path), add=add)
     tmp = os.path.join(root, "tmp")
     os.makedirs(tmp)
     m = Manifest(root, os.path.join(root, "benchmarks"))
