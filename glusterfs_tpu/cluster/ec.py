@@ -403,6 +403,9 @@ class DisperseLayer(Layer):
         # operator can see migration I/O riding the delta plane
         self.delta_origin = {"serve": 0}
         self.delta_saved = {"read": 0, "write": 0}
+        # heal windows by how their bad fragments were made: sliced
+        # from the decoded data, or encoded again (heal_file)
+        self.heal_windows = {"from_decode": 0, "reencoded": 0}
         # sink one of this layer's phases (core/tracing.py ``phase``):
         # count, seconds, slowest of ec.lock, ec.fanout, ... as
         # dump_private()["phases"] shows them
@@ -2364,7 +2367,16 @@ class DisperseLayer(Layer):
         healed), so regions the heal already rebuilt stay current; if
         the version moved while healing (a write landed), dirty is left
         set so the next shd pass re-verifies instead of force-clearing
-        counters under a concurrent writer."""
+        counters under a concurrent writer.
+
+        Each window reads k good fragments and decodes them, which
+        rebuilds every missing data row.  Where every bad brick is a
+        data brick of a systematic code, its fragment is that row of the
+        decoded window, sliced on the host: one codec launch a window.
+        Where a bad brick holds parity, or every fragment is a codeword
+        (the non-systematic code), the window is encoded again from the
+        decoded data: two launches.  ``heal_windows`` in
+        ``dump_private()`` counts the windows of each kind."""
         loc = Loc(path)
         info = await self.heal_info(loc)
         good, bad = info["good"], info["bad"]
@@ -2408,6 +2420,8 @@ class DisperseLayer(Layer):
         a_total = self._frag_len(true_size) * self.k
         rows = good[: self.k]
         rows_sorted = sorted(rows)
+        # the data rows of a systematic code are the decode's own output
+        from_decode = self.codec.systematic and all(i < self.k for i in bad)
         from ..features.bit_rot_stub import HEAL_WRITE
 
         off = 0
@@ -2434,7 +2448,16 @@ class DisperseLayer(Layer):
                 # MULTICHIP dryrun) can tell shd re-encode from serving
                 data = await self._codec_decode(frags_in, rows_sorted,
                                                 origin="heal")
-                frags_out = await self._codec_encode(data, origin="heal")
+                if from_decode:
+                    # chunk i of every stripe; the writev's tobytes()
+                    # below makes it contiguous
+                    stripes = data.reshape(-1, self.k, CHUNK)
+                    frags_out = {i: stripes[:, i, :] for i in bad}
+                    self.heal_windows["from_decode"] += 1
+                else:
+                    frags_out = await self._codec_encode(data,
+                                                         origin="heal")
+                    self.heal_windows["reencoded"] += 1
                 await self._dispatch(
                     bad, "writev",
                     lambda i: ((self._child_fd(fd, i),
@@ -2522,6 +2545,7 @@ class DisperseLayer(Layer):
             "read_coalesced": dict(self.read_coalesced),
             "write_path": dict(self.write_path),
             "delta_saved": dict(self.delta_saved),
+            "heal_windows": dict(self.heal_windows),
             "xorv_ok": self._xorv_ok,
             "eager_windows": len(self._eager),
             # per phase of a transaction: count, seconds, slowest (the

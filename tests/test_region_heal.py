@@ -9,10 +9,12 @@ import asyncio
 import os
 
 import numpy as np
+import pytest
 
 from glusterfs_tpu.api.glfs import Client
 from glusterfs_tpu.core.graph import Graph
 from glusterfs_tpu.core.layer import Loc
+from glusterfs_tpu.ops import gf256
 from glusterfs_tpu.utils.volspec import ec_volfile
 
 K, R = 4, 2
@@ -120,6 +122,81 @@ def test_heal_window_lock_ranges_unwound(tmp_path):
         # a fresh full-range write txn must acquire instantly — stray
         # heal range locks would deadlock it until timeout
         await asyncio.wait_for(c.write_file("/f", b"y" * STRIPE), 5)
+        await c.unmount()
+
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("systematic,bad,stripes,tail,window_stripes", [
+    (True, [1], 2, 0, 8),
+    (True, [4], 2, 0, 8),
+    (True, [1, 5], 2, 0, 8),
+    (False, [1], 2, 0, 8),
+    (True, [1], 5, 300, 2),
+], ids=["sys-data", "sys-parity", "sys-data-parity", "nonsys-data",
+        "sys-data-short-last-window"])
+def test_heal_takes_data_rows_from_the_decode(tmp_path, systematic, bad,
+                                              stripes, tail,
+                                              window_stripes):
+    """A heal whose bad bricks are all data bricks of a systematic code
+    writes them the rows of the window's decode and encodes nothing; a
+    parity brick among them, or the non-systematic code, re-encodes
+    each window.  Either way the healed fragments are the reference
+    encoding's bytes, and the file reads back through the healed
+    bricks."""
+
+    async def run():
+        spec = ec_volfile(tmp_path, N, R, options={
+            "cpu-extensions": "ref",
+            "systematic": "on" if systematic else "off",
+            "self-heal-window-size": str(window_stripes * STRIPE)})
+        g = Graph.construct(spec)
+        c = Client(g)
+        await c.mount()
+        ec = g.top
+        size = stripes * STRIPE + tail
+        await c.write_file("/f", _rand(size, seed=4).tobytes())
+        for i in bad:
+            ec.set_child_up(i, False)
+        data = _rand(size, seed=5).tobytes()
+        f = await c.open("/f", os.O_RDWR)
+        await f.write(data, 0)
+        await f.close()
+        for i in bad:
+            ec.set_child_up(i, True)
+        assert (await ec.heal_info(Loc("/f")))["bad"] == bad
+
+        encodes = []
+        real = ec._codec_encode
+
+        async def counted(*a, **kw):
+            encodes.append(kw.get("origin"))
+            return await real(*a, **kw)
+
+        ec._codec_encode = counted
+        res = await ec.heal_file("/f")
+        assert res["healed"] == bad and res["stable"]
+        windows = -(-(stripes + (tail > 0)) // window_stripes)
+        data_only = systematic and all(i < K for i in bad)
+        assert ec.dump_private()["heal_windows"] == {
+            "from_decode": windows if data_only else 0,
+            "reencoded": 0 if data_only else windows}
+        assert encodes == ([] if data_only else ["heal"] * windows)
+
+        padded = np.zeros(-(-size // STRIPE) * STRIPE, dtype=np.uint8)
+        padded[:size] = np.frombuffer(data, dtype=np.uint8)
+        oracle = gf256.ref_encode(padded, K, N, systematic=systematic)
+        for i in range(N):
+            frag = open(os.path.join(str(tmp_path), f"brick{i}", "f"),
+                        "rb").read()
+            assert frag == oracle[i].tobytes(), f"brick {i}"
+        # the healed bricks carry the read: r others are down
+        down = [i for i in range(N) if i not in bad][:R]
+        for i in down:
+            ec.set_child_up(i, False)
+        assert await c.read_file("/f") == data
+        for i in down:
+            ec.set_child_up(i, True)
         await c.unmount()
 
     asyncio.run(run())
